@@ -13,11 +13,11 @@ bf16, honest host-fetch fence):
    {packed, concat} at batch 512/1024/2048 — packed preallocates the
    block buffer and dynamic_update_slices each layer's 32 channels;
    concat is the re-materializing baseline the MFU attribution blamed.
-3. Fused-kernel channel-tile microsweep: the stem/block depthwise
-   shapes at `channel_tile` in {None, 32, 16, 8} — `None` (whole-C per
-   grid cell; every 50x50-scale activation fits VMEM) is the recorded
-   default, frozen as ops/fused_conv.DEFAULT_CHANNEL_TILE. Re-run this
-   sweep before changing it.
+3. The fused kernel standalone at the stem/block depthwise shapes,
+   whole-C per grid cell. There is no channel-tile sweep at these
+   widths: Mosaic takes a channel block only if it is all of C or a
+   multiple of 128 lanes, and 32/96/144 have no such divisor (every
+   50x50-scale activation fits VMEM whole anyway).
 
 Usage (results are only perf-meaningful on the chip; on CPU the Pallas
 rows run the interpreter and measure correctness, not speed):
@@ -92,8 +92,7 @@ def measure_dense(batch: int, impl: str):
 def measure_tile(*, batch=256, size=25, c=96, stride=1,
                  channel_tile=None):
     """One fused depthwise+BN+relu6 call at a MobileNetV2 activation
-    shape, timed standalone — the channel-tile layout sweep that chose
-    ops/fused_conv.DEFAULT_CHANNEL_TILE."""
+    shape, timed standalone."""
     import jax
     import jax.numpy as jnp
 
@@ -142,13 +141,9 @@ EXPERIMENTS = {
     **{f"dense_{impl}_{b}": partial(measure_dense, b, impl)
        for b in (512, 1024, 2048)
        for impl in ("packed", "concat")},
-    # ---- sweep 3: channel-tile layout at the hot fused shapes ----
-    **{f"tile_25x96_{t if t else 'none'}":
-       partial(measure_tile, size=25, c=96, channel_tile=t)
-       for t in (None, 32, 16, 8)},
-    **{f"tile_13x144_{t if t else 'none'}":
-       partial(measure_tile, size=13, c=144, stride=2, channel_tile=t)
-       for t in (None, 48, 16)},
+    # ---- sweep 3: the kernel standalone at the hot fused shapes ----
+    "tile_25x96_none": partial(measure_tile, size=25, c=96),
+    "tile_13x144_none": partial(measure_tile, size=13, c=144, stride=2),
     "tile_25x32_stem": partial(measure_tile, size=25, c=32),
 }
 

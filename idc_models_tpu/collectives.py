@@ -82,12 +82,7 @@ def axis_index(axis_name: str):
 
 
 def axis_size(axis_name: str):
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    # jax <= 0.4.x: psum of the python scalar 1 is evaluated at trace
-    # time against the axis env and returns the CONCRETE size — the
-    # canonical pre-axis_size idiom, safe to drive python-unrolled loops
-    return lax.psum(1, axis_name)
+    return lax.axis_size(axis_name)
 
 
 def ring_perm(n: int, shift: int = 1) -> list[tuple[int, int]]:

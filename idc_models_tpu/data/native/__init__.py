@@ -1,7 +1,7 @@
 """ctypes binding for the native (C++/libpng) image loader.
 
-Lazily builds `loader.cpp` into `_native_loader.so` beside this file the
-first time it is needed (and whenever the source is newer), then exposes
+Lazily builds `loader.cpp` into `_native_loader.<source digest>.so`
+beside this file the first time it is needed, then exposes
 
     decode_batch(paths, size, threads=0) -> np.ndarray [n, size, size, 3]
 
@@ -14,6 +14,7 @@ the reference gets this from tf.data's C++ runtime (SURVEY.md §2c).
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -23,7 +24,6 @@ import numpy as np
 
 _DIR = Path(__file__).parent
 _SRC = _DIR / "loader.cpp"
-_SO = _DIR / "_native_loader.so"
 _ABI = 2
 
 _lock = threading.Lock()
@@ -31,24 +31,33 @@ _lib: ctypes.CDLL | None = None
 _build_error: str | None = None
 
 
-def _build() -> None:
+def _so_path() -> Path:
+    """The binary's name carries its source's digest, so a .so built
+    from any other loader.cpp — an older checkout's, copied along with
+    the tree; git does not track it — has a different name and is never
+    loaded. (File times cannot tell: a copy resets them.)"""
+    digest = hashlib.sha256(_SRC.read_bytes()).hexdigest()[:12]
+    return _DIR / f"_native_loader.{digest}.so"
+
+
+def _build(so: Path) -> None:
     """Compile to a per-process temp file and atomically rename into
     place — never truncate a .so another process may have mapped, and
     concurrent builders (e.g. multi-host workers sharing a checkout)
     cannot corrupt each other's half-written output."""
-    tmp = _SO.with_name(f"{_SO.name}.{os.getpid()}.tmp")
+    tmp = so.with_name(f"{so.name}.{os.getpid()}.tmp")
     try:
         subprocess.run(
             ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", str(_SRC),
              "-lpng", "-lz", "-lpthread", "-o", str(tmp)],
             check=True, capture_output=True, text=True)
-        os.replace(tmp, _SO)
+        os.replace(tmp, so)
     finally:
         tmp.unlink(missing_ok=True)
 
 
-def _open_checked() -> ctypes.CDLL:
-    lib = ctypes.CDLL(str(_SO))
+def _open_checked(so: Path) -> ctypes.CDLL:
+    lib = ctypes.CDLL(str(so))
     try:
         abi = lib.idc_loader_abi_version()
     except AttributeError:
@@ -77,17 +86,18 @@ def _load() -> ctypes.CDLL | None:
         if _lib is not None or _build_error is not None:
             return _lib
         try:
-            if not _SO.exists() or _SO.stat().st_mtime < _SRC.stat().st_mtime:
-                _build()
+            so = _so_path()
+            if not so.exists():
+                _build(so)
             try:
-                lib = _open_checked()
+                lib = _open_checked(so)
             except (OSError, AttributeError):
-                # a stale binary that escaped the mtime test (coarse
-                # filesystem timestamps, copied checkouts, pre-ABI-export
-                # builds raising AttributeError): rebuild from the source
-                # sitting right next to it rather than giving up
-                _build()
-                lib = _open_checked()
+                # a binary under the right name that still cannot load
+                # (torn file, a build against another ABI constant):
+                # rebuild from the source sitting right next to it
+                # rather than giving up
+                _build(so)
+                lib = _open_checked(so)
             lib.idc_decode_batch.argtypes = [
                 ctypes.POINTER(ctypes.c_char_p), ctypes.c_int, ctypes.c_int,
                 ctypes.POINTER(ctypes.c_float), ctypes.c_int,

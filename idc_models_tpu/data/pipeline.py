@@ -258,11 +258,16 @@ class FileStream(_EpochSchedule):
 
 
 def _decode_worker_init():
-    """Decode workers never touch an accelerator: pin any jax that gets
-    transitively imported to CPU before it can claim the chip."""
+    """Decode workers never touch an accelerator, and a chip belongs to
+    one process: the parent's. Spawning a worker imports this module —
+    and with it jax, which reads JAX_PLATFORMS at import — BEFORE this
+    runs, so the environment alone is too late. Pin the platform
+    through jax.config as well (a fresh worker has no backend yet), so
+    nothing a worker touches can claim the chip."""
     import os
 
-    os.environ["JAX_PLATFORMS"] = "cpu"
+    os.environ["JAX_PLATFORMS"] = "cpu"     # for anything it starts
+    jax.config.update("jax_platforms", "cpu")
 
 
 def prefetch_to_mesh(batches: Iterator, mesh: Mesh, *, axis=meshlib.DATA_AXIS,

@@ -40,10 +40,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from idc_models_tpu.compat import shard_map
 
 from idc_models_tpu import collectives
 from idc_models_tpu import mesh as meshlib

@@ -35,10 +35,9 @@ halo, and Pallas BlockSpecs cannot express overlapping blocks, so the
 per-cell block is the full padded image. Channels, by contrast, are
 fully independent in a depthwise conv, so the channel axis is the free
 tiling axis that bounds VMEM: `channel_tile` splits C when the full
-image does not fit (it must divide C; `_pick_channel_tile` records the
-sweep's choice — see experiments/fused_backbone.py). At the paper's
-50x50 patches every activation fits untiled (largest: 25x25x96 f32 =
-240 KB/image), which is the recorded default.
+image does not fit (`_pick_channel_tile`: it must divide C and, for
+Mosaic, be a multiple of 128 lanes). At the paper's 50x50 patches
+every activation fits untiled (largest: 25x25x96 f32 = 240 KB/image).
 
 Gradients: `_fused` carries a custom_vjp whose backward differentiates
 the pure-jnp reference at the saved inputs — the flash_block_kernel
@@ -50,10 +49,11 @@ forward is where the unfused chain paid.
 Testing contract: `interpret=True` runs the SAME kernel body under the
 Pallas interpreter on CPU, so tier-1 parity tests exercise the real
 code path, not a stand-in; `interpret=None` (the default) resolves to
-the interpreter automatically off-TPU. XLA's `cost_analysis` cannot
-see inside a Pallas custom call, so `depthwise_chain_cost` provides
-the analytic FLOPs/bytes the profile verb merges into its ProgramCost
-(observe/profile.py `augment_cost` / `register_cost`).
+the interpreter off-TPU and to Mosaic on TPU (`mesh.pallas_interpret`).
+XLA's `cost_analysis` cannot see inside a Pallas custom call, so
+`depthwise_chain_cost` provides the analytic FLOPs/bytes the profile
+verb merges into its ProgramCost (observe/profile.py `augment_cost` /
+`register_cost`).
 """
 
 from __future__ import annotations
@@ -65,15 +65,17 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
-# VMEM budget one image's padded activation + output may occupy before
-# the kernel insists on channel tiling (v5e has 128 MB VMEM per core;
-# staying well under leaves room for double-buffering).
-VMEM_BUDGET_BYTES = 8 * 1024 * 1024
+from idc_models_tpu import mesh as meshlib
 
-# Chosen by the experiments/fused_backbone.py sweep at the paper's
-# shapes (50x50 patches, batch 8..4096): every MobileNetV2 activation
-# fits VMEM whole, so the recorded default is "no channel tiling".
-DEFAULT_CHANNEL_TILE = None
+# What ONE copy of a cell's input + output blocks may occupy in VMEM
+# before the kernel insists on channel tiling. The v5e core has 128 MiB
+# of VMEM (jax's pallas tpu_info), but Mosaic gives a kernel 16 MiB of
+# scoped VMEM unless `vmem_limit_bytes` raises it — nothing here does —
+# and the BlockSpec pipeline double-buffers every block. Compiled for
+# v5e, a cell of 6.7 MiB fits (12.6 MiB allocated) and one of 10.0 MiB
+# is refused ("Scoped allocation with size 18.88M and limit 16.00M").
+VMEM_BUDGET_BYTES = 6 * 1024 * 1024
+_LANES = 128
 
 
 def fold_bn(scale, bias, mean, var, eps):
@@ -119,46 +121,80 @@ def reference_impl(x, w, mul, add, *, stride=1, clamp6=True):
     return y.astype(x.dtype)
 
 
-def _kernel(xp_ref, w_ref, mul_ref, add_ref, out_ref, *,
+def _phase_split(xp, sh, sw, hh, wh):
+    """Space-to-depth of the padded input for a strided conv: plane
+    (p, q) holds ``xp[:, p::sh, q::sw, :]``, so tap (i, j) of a
+    stride-(sh, sw) conv is a UNIT-stride window of plane
+    (i % sh, j % sw) at offset (i // sh, j // sw). Mosaic can then load
+    every tap plainly: it refuses a strided slice of a loaded value
+    (`vector.extract_strided_slice` takes stride 1 only), and its
+    strided loads from a ref need 32-bit data in exactly 128 lanes.
+    Returns [N, sh*sw, hh, wh, C]; at stride 1 this is a reshape."""
+    n, h_p, w_p, c = xp.shape
+    xp = jnp.pad(xp, ((0, 0), (0, hh * sh - h_p), (0, wh * sw - w_p),
+                      (0, 0)))
+    planes = xp.reshape(n, hh, sh, wh, sw, c).transpose(0, 2, 4, 1, 3, 5)
+    return planes.reshape(n, sh * sw, hh, wh, c)
+
+
+def _kernel(xph_ref, w_ref, mul_ref, add_ref, out_ref, *,
             kh, kw, sh, sw, h_out, w_out, clamp6):
     """One (image, channel-tile) cell: taps MAC + affine + clamp, all
-    on the VMEM-resident tile."""
-    x = xp_ref[...].astype(jnp.float32)          # (1, Hp, Wp, ct)
-    ct = x.shape[-1]
+    on the VMEM-resident tile; each tap is a unit-stride window load of
+    one phase plane (`_phase_split`)."""
     acc = None
     for i in range(kh):
         for j in range(kw):
-            xs = lax.slice(x, (0, i, j, 0),
-                           (1, i + (h_out - 1) * sh + 1,
-                            j + (w_out - 1) * sw + 1, ct),
-                           (1, sh, sw, 1))
-            t = xs * w_ref[i * kw + j, :]
+            xs = xph_ref[0, (i % sh) * sw + j % sw,
+                         pl.ds(i // sh, h_out), pl.ds(j // sw, w_out), :]
+            t = xs.astype(jnp.float32) * w_ref[i * kw + j, :]
             acc = t if acc is None else acc + t
     y = acc * mul_ref[0] + add_ref[0]
     if clamp6:
         y = jnp.clip(y, 0.0, 6.0)
-    out_ref[...] = y.astype(out_ref.dtype)
+    out_ref[0] = y.astype(out_ref.dtype)
 
 
-def _pick_channel_tile(h_p, w_p, h_out, w_out, c, itemsize,
+def _vmem_bytes(rows, w, c, itemsize):
+    """Bytes a [rows, w, c] block occupies in VMEM's tiled layout: the
+    last dim pads to 128 lanes, the one before it to a whole sublane
+    tile (8 rows of 32-bit data; narrower dtypes pack more rows)."""
+    sublanes = 8 * max(4 // itemsize, 1)
+    return (rows * -(-w // sublanes) * sublanes
+            * -(-c // _LANES) * _LANES * itemsize)
+
+
+def _pick_channel_tile(in_rows, w_in, h_out, w_out, c, itemsize,
                        channel_tile):
-    """Resolve the channel-tile size: an explicit request must divide C;
-    `None` means whole-C unless the per-cell VMEM footprint (padded
-    input + output tile, f32 accumulate) busts the budget, in which
-    case the largest budget-fitting divisor of C is chosen."""
+    """Resolve the channel-tile size: an explicit request must divide C
+    (Pallas itself refuses, on TPU, one that is neither C nor a
+    multiple of 128 lanes); `None` means whole-C unless the cell's
+    input + output blocks bust the VMEM budget, in which case the
+    largest budget-fitting divisor of C that is a multiple of 128 is
+    chosen — and a shape with no such divisor is refused."""
     if channel_tile is not None:
         if c % channel_tile:
             raise ValueError(f"channel_tile {channel_tile} must divide "
                              f"channel count {c}")
         return channel_tile
-    per_chan = (h_p * w_p + h_out * w_out) * max(itemsize, 4)
-    if per_chan * c <= VMEM_BUDGET_BYTES:
+
+    def cell_bytes(ct):
+        return (_vmem_bytes(in_rows, w_in, ct, itemsize)
+                + _vmem_bytes(h_out, w_out, ct, itemsize))
+
+    if cell_bytes(c) <= VMEM_BUDGET_BYTES:
         return c
-    best = 1
-    for d in range(1, c + 1):
-        if c % d == 0 and per_chan * d <= VMEM_BUDGET_BYTES:
-            best = d
-    return best
+    fits = [d for d in range(_LANES, c, _LANES)
+            if c % d == 0 and cell_bytes(d) <= VMEM_BUDGET_BYTES]
+    if not fits:
+        raise ValueError(
+            f"fused depthwise kernel: one image's [{in_rows}, {w_in}, "
+            f"{c}] input + [{h_out}, {w_out}, {c}] output blocks need "
+            f"{cell_bytes(c)} bytes of VMEM (budget "
+            f"{VMEM_BUDGET_BYTES}) and {c} channels have no divisor "
+            f"that is a multiple of {_LANES} lanes and fits; use the "
+            f"grouped depthwise lowering at this resolution")
+    return max(fits)
 
 
 def _pallas_impl(x, w, mul, add, *, stride, clamp6, interpret,
@@ -167,8 +203,11 @@ def _pallas_impl(x, w, mul, add, *, stride, clamp6, interpret,
     sh, sw = stride
     n, _, _, c = x.shape
     xp, h_out, w_out = _same_pad(x, kh, kw, sh, sw)
-    _, h_p, w_p, _ = xp.shape
-    ct = _pick_channel_tile(h_p, w_p, h_out, w_out, c,
+    # rows/cols one phase plane must offer: the largest tap offset plus
+    # the output extent
+    hh, wh = h_out + (kh - 1) // sh, w_out + (kw - 1) // sw
+    xph = _phase_split(xp, sh, sw, hh, wh)
+    ct = _pick_channel_tile(sh * sw * hh, wh, h_out, w_out, c,
                             jnp.dtype(x.dtype).itemsize, channel_tile)
     wf = w.reshape(kh * kw, c).astype(jnp.float32)
     mul2 = mul.reshape(1, c).astype(jnp.float32)
@@ -179,7 +218,8 @@ def _pallas_impl(x, w, mul, add, *, stride, clamp6, interpret,
         kern,
         grid=(n, c // ct),
         in_specs=[
-            pl.BlockSpec((1, h_p, w_p, ct), lambda i, j: (i, 0, 0, j)),
+            pl.BlockSpec((1, sh * sw, hh, wh, ct),
+                         lambda i, j: (i, 0, 0, 0, j)),
             pl.BlockSpec((kh * kw, ct), lambda i, j: (0, j)),
             pl.BlockSpec((1, ct), lambda i, j: (0, j)),
             pl.BlockSpec((1, ct), lambda i, j: (0, j)),
@@ -188,7 +228,7 @@ def _pallas_impl(x, w, mul, add, *, stride, clamp6, interpret,
                                lambda i, j: (i, 0, 0, j)),
         out_shape=jax.ShapeDtypeStruct((n, h_out, w_out, c), x.dtype),
         interpret=interpret,
-    )(xp, wf, mul2, add2)
+    )(xph, wf, mul2, add2)
 
 
 @functools.lru_cache(maxsize=None)
@@ -218,13 +258,6 @@ def _make_fused(stride, clamp6, interpret, channel_tile):
     return fused
 
 
-def default_interpret() -> bool:
-    """The `interpret=None` resolution: real Mosaic lowering on TPU,
-    the Pallas interpreter (same kernel body) everywhere else — the
-    tier-1-on-CPU testing contract."""
-    return jax.default_backend() != "tpu"
-
-
 def fused_depthwise_affine(x, w, mul, add, *, stride=1, clamp6=True,
                            interpret=None, channel_tile=None):
     """Fused `clamp6(dwconv(x) * mul + add)` (TF-SAME padding).
@@ -232,13 +265,15 @@ def fused_depthwise_affine(x, w, mul, add, *, stride=1, clamp6=True,
     x: [N, H, W, C]; w: [kh, kw, 1, C] (the core.depthwise_conv2d param
     layout); mul/add: [C] folded-BN affine (identity: ones/zeros).
     Differentiable in all four array arguments via the reference-vjp
-    backward.
+    backward. `interpret=None` follows the one platform rule
+    (`mesh.pallas_interpret`): Mosaic on TPU devices, the Pallas
+    interpreter (same kernel body) everywhere else — the
+    tier-1-on-CPU testing contract. No mesh is in scope inside a
+    model's apply, so the devices are the process's default ones.
     """
     if interpret is None:
-        interpret = default_interpret()
+        interpret = meshlib.pallas_interpret()
     strides = (stride, stride) if isinstance(stride, int) else stride
-    if channel_tile is None:
-        channel_tile = DEFAULT_CHANNEL_TILE
     return _make_fused(tuple(strides), bool(clamp6), bool(interpret),
                        channel_tile)(x, w, mul, add)
 

@@ -44,13 +44,11 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from idc_models_tpu import collectives
 from idc_models_tpu import mesh as meshlib
-
-from idc_models_tpu.compat import shard_map
 
 _MASKED = -1e30  # same finite sentinel as ring_attention._MASKED
 

@@ -6,9 +6,7 @@ from idc_models_tpu.serve.cluster import (  # noqa: F401
     AutoscaleConfig, Autoscaler, ClusterTelemetry, ClusterWatchdog,
     PrefixRegistry, Replica, Router, WatchdogConfig, build_replica,
 )
-from idc_models_tpu.serve.compile_cache import (  # noqa: F401
-    CompileCache, enable_persistent_xla_cache,
-)
+from idc_models_tpu.serve.compile_cache import CompileCache  # noqa: F401
 from idc_models_tpu.serve.engine import SlotEngine  # noqa: F401
 from idc_models_tpu.serve.faults import (  # noqa: F401
     InjectedEngineCrash, InjectedPrefillError, ServeFault,

@@ -30,10 +30,11 @@ deliberate swallow in this module (documented in the static-scan
 allowlist): a corrupt best-effort cache must never be able to take a
 replica down.
 
-`enable_persistent_xla_cache` additionally arms jax's own
-compilation-cache knob under a sibling directory — that layer caches
-XLA IR→binary for EVERY jit in the process (training steps included),
-complementing the executable store, which skips tracing/lowering too.
+jax's own persistent compilation cache is a separate layer, placed
+once per process by `idc_models_tpu.runtime.setup_compile_cache`
+(`cli.main` calls it): it caches XLA IR→binary for EVERY jit in the
+process (training steps included), complementing this executable
+store, which skips tracing/lowering too.
 
 Counters (hits/misses/stores/evictions, deserialize + compile seconds)
 feed the `serve_compile_cache_*` gauges (serve/metrics.py) and the
@@ -51,18 +52,6 @@ import time
 from pathlib import Path
 
 import jax
-
-
-def enable_persistent_xla_cache(path) -> Path:
-    """Arm jax's built-in compilation cache under `path` — the
-    IR-level layer below the executable store: every jit compile in
-    the process (serve AND train programs) writes/reads it. Returns
-    the directory. Idempotent; safe to call before any engine
-    exists."""
-    p = Path(path)
-    p.mkdir(parents=True, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", str(p))
-    return p
 
 
 class CompileCache:
@@ -111,14 +100,16 @@ class CompileCache:
     def _file(self, key: str) -> Path:
         return self.path / f"{key}.jaxexe"
 
-    def load(self, key: str):
-        """Deserialize the stored executable for `key`, or None on a
-        miss. A file that exists but cannot load (torn write survived
-        a crash, foreign-toolchain blob under a colliding path) is
-        evicted and reported as a miss: the cache is best-effort by
-        contract — spin-up must fall back to a real compile, never
-        die on a bad cache entry (the rebuilt entry then replaces
-        it)."""
+    def load(self, key: str, *, devices):
+        """Deserialize the stored executable for `key` onto `devices`
+        — the devices it was compiled for, in assignment order (the
+        key already pins them; jax would otherwise load it onto EVERY
+        device of the backend). None on a miss. A file that exists but
+        cannot load (torn write survived a crash, foreign-toolchain
+        blob under a colliding path) is evicted and reported as a
+        miss: the cache is best-effort by contract — spin-up must fall
+        back to a real compile, never die on a bad cache entry (the
+        rebuilt entry then replaces it)."""
         from jax.experimental import serialize_executable as se
 
         f = self._file(key)
@@ -129,7 +120,9 @@ class CompileCache:
         t0 = time.perf_counter()
         try:
             payload, in_tree, out_tree = pickle.loads(f.read_bytes())
-            exe = se.deserialize_and_load(payload, in_tree, out_tree)
+            exe = se.deserialize_and_load(
+                payload, in_tree, out_tree,
+                execution_devices=list(devices))
         except Exception as e:
             f.unlink(missing_ok=True)
             self.evicted_corrupt += 1

@@ -530,7 +530,9 @@ def two_phase_fit(model_name: str, num_outputs: int, train_ds: ArrayDataset,
                         batch_size=config.batch_size,
                         steps=config.eval_steps,
                         compute_dtype=config.compute_dtype)
-    print(f"initial loss: {baseline['loss']:.2f}")
+    # 4 decimals: one head-only epoch from random features moves the
+    # loss in the 4th (chip_smoke.py compares against this line)
+    print(f"initial loss: {baseline['loss']:.4f}")
     print(f"initial accuracy: {baseline['accuracy']:.2f}")
 
     with Timer(f"Pre-training for {config.epochs} epochs",

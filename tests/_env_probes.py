@@ -62,7 +62,7 @@ meshlib.initialize_multihost(coordinator=coordinator, num_processes=n,
                              process_id=i)
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from idc_models_tpu.compat import shard_map
+from jax import shard_map
 mesh = meshlib.data_mesh()          # spans BOTH processes (2 devices)
 f = jax.jit(shard_map(lambda x: jax.lax.psum(x, meshlib.DATA_AXIS),
                       mesh=mesh, in_specs=P(meshlib.DATA_AXIS),

@@ -50,7 +50,7 @@ def layout_invariant() -> bool:
 
     from idc_models_tpu import collectives
     from idc_models_tpu import mesh as meshlib
-    from idc_models_tpu.compat import shard_map
+    from jax import shard_map
     from idc_models_tpu.data import synthetic
     from idc_models_tpu.models import small_cnn
     from idc_models_tpu.train.losses import binary_cross_entropy

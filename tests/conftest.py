@@ -6,44 +6,34 @@ collection time before this file executes (pytest guarantees conftest.py
 is imported before test modules).
 """
 
+import os
 import pathlib
 
-from idc_models_tpu import mesh as _meshlib
+# Persistent compilation cache: repeat suite runs skip recompiles (a
+# VGG16 train-step compile drops ~1.6s -> ~0.3s; the suite is full of
+# them). Keyed by HLO + compile options + jax version, so stale entries
+# can't be served; the dir is gitignored. Placed through the
+# ENVIRONMENT, before jax is imported: jax reads the variables at
+# import, the package's one cache site (runtime.setup_compile_cache,
+# reached by every in-process cli.main) then sets nothing, and the
+# subprocess tests inherit the same cache. A JAX_COMPILATION_CACHE_DIR
+# set from outside wins, the same rule as the package's.
+os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
+                      str(pathlib.Path(__file__).parent / ".jax_cache"))
+os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0.5")
+os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES", "-1")
+
+from idc_models_tpu import mesh as _meshlib  # noqa: E402
 
 _meshlib.force_cpu_pod(8)
 
 import jax  # noqa: E402
 
-# Persistent compilation cache: repeat suite runs skip recompiles (a
-# VGG16 train-step compile drops ~1.6s -> ~0.3s; the suite is full of
-# them). Keyed by HLO + compile options + jax version, so stale entries
-# can't be served; the dir is gitignored.
-#
-# ONLY on newer jax (the top-level-shard_map API line): on 0.4.x
-# XLA:CPU a DESERIALIZED cached executable of a donating jitted train
-# step silently returns wrong outputs — first (cold) run correct,
-# second (warm) run leaves updated params untouched (reproduced via
-# test_freeze_machinery_applies: head delta 0.0316 cold, 0.0 from the
-# cache hit). Correctness over speed: leave the cache off there.
-PERSISTENT_CACHE_OK = hasattr(jax, "shard_map")
-if PERSISTENT_CACHE_OK:
-    jax.config.update("jax_compilation_cache_dir",
-                      str(pathlib.Path(__file__).parent / ".jax_cache"))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-else:
-    # actively DISABLE it: an ambient JAX_COMPILATION_CACHE_DIR in the
-    # developer's shell would re-enable the broken cache behind the
-    # guard (and test_examples.py copies os.environ into subprocesses)
-    import os as _os
-
-    for _var in ("JAX_COMPILATION_CACHE_DIR",
-                 "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS",
-                 "JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES"):
-        _os.environ.pop(_var, None)
-    jax.config.update("jax_compilation_cache_dir", None)
-
-import os  # noqa: E402
+if (jax.config.jax_compilation_cache_dir
+        != os.environ["JAX_COMPILATION_CACHE_DIR"]):
+    raise RuntimeError(
+        "jax was imported before tests/conftest.py placed the compile "
+        "cache; the suite would run cold")
 
 import pytest  # noqa: E402
 

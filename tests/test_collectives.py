@@ -9,7 +9,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from idc_models_tpu import collectives, mesh as meshlib
-from idc_models_tpu.compat import shard_map
+from jax import shard_map
 
 N = 8
 

@@ -39,6 +39,7 @@ from idc_models_tpu.serve import (
 from idc_models_tpu.serve.cluster import autoscaler as asc
 
 VOCAB, SEQ, E, HEADS, MLP, BLOCKS = 11, 32, 32, 2, 64, 2
+DEV0 = jax.devices()[:1]   # where an unsharded jit compiles and runs
 
 
 @pytest.fixture(scope="module")
@@ -189,12 +190,12 @@ def test_compile_cache_roundtrip_and_key_drift(tmp_path):
     lowered = f.lower(jnp.zeros((4,), jnp.float32))
     cc = CompileCache(tmp_path)
     key = cc.key(program="probe", fingerprint={"embed": E})
-    assert cc.load(key) is None and cc.misses == 1
+    assert cc.load(key, devices=DEV0) is None and cc.misses == 1
     exe = cc.compile_and_store(key, lowered)
     assert cc.stores == 1 and cc.compile_s > 0
     # a fresh instance (the "new process") deserializes the same key
     cc2 = CompileCache(tmp_path)
-    warm = cc2.load(key)
+    warm = cc2.load(key, devices=DEV0)
     assert warm is not None
     assert cc2.summary()["hits"] == 1 and cc2.deserialize_s > 0
     x = jnp.arange(4, dtype=jnp.float32)
@@ -214,12 +215,12 @@ def test_compile_cache_corrupt_blob_evicted_as_miss(tmp_path):
     key = cc.key(program="probe", fingerprint={})
     blob = cc._file(key)
     blob.write_bytes(b"not a serialized executable")
-    assert cc.load(key) is None
+    assert cc.load(key, devices=DEV0) is None
     assert cc.evicted_corrupt == 1 and cc.misses == 1
     assert not blob.exists()               # evicted, not left to rot
     f = jax.jit(lambda x: x + 1)
     cc.compile_and_store(key, f.lower(jnp.zeros((2,), jnp.float32)))
-    assert CompileCache(tmp_path).load(key) is not None
+    assert CompileCache(tmp_path).load(key, devices=DEV0) is not None
 
 
 def test_warm_replica_spinup_hits_cache(params, tmp_path):

@@ -2,7 +2,6 @@
 subprocess exactly as the README tells users to run them (they
 self-configure the virtual 8-device CPU pod)."""
 
-import os
 import subprocess
 import sys
 from pathlib import Path
@@ -10,25 +9,15 @@ from pathlib import Path
 import pytest
 
 EXAMPLES = sorted((Path(__file__).parent.parent / "examples").glob("*.py"))
-# share the suite's persistent compilation cache (conftest.py) with the
-# subprocesses so repeat runs skip the example models' compiles too —
-# only where the cache is trustworthy (see conftest.PERSISTENT_CACHE_OK:
-# 0.4.x XLA:CPU serves silently-wrong deserialized executables)
-from conftest import PERSISTENT_CACHE_OK
-
-_ENV = dict(os.environ)
-if PERSISTENT_CACHE_OK:
-    _ENV.update(
-        JAX_COMPILATION_CACHE_DIR=str(Path(__file__).parent / ".jax_cache"),
-        JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0.5",
-        JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES="-1",
-    )
+# conftest.py placed the suite's persistent compilation cache in the
+# environment, so the subprocesses inherit it and repeat runs skip the
+# example models' compiles too
 
 
 @pytest.mark.parametrize("script", EXAMPLES, ids=lambda p: p.name)
 def test_example_runs(script):
     r = subprocess.run([sys.executable, str(script)], capture_output=True,
-                       text=True, timeout=600, env=_ENV)
+                       text=True, timeout=600)
     assert r.returncode == 0, f"{script.name} failed:\n{r.stdout}\n{r.stderr}"
     assert r.stdout.strip(), f"{script.name} printed nothing"
 

@@ -2,7 +2,7 @@
 references.
 
 Everything here runs the REAL kernel body: on CPU `interpret=None`
-resolves to the Pallas interpreter (ops/fused_conv.default_interpret),
+resolves to the Pallas interpreter (mesh.pallas_interpret),
 which executes the same `_kernel` the TPU lowers through Mosaic — the
 tier-1-on-CPU testing contract. Tolerances match the taps-parity suite
 (tests/test_core_layers.py): rtol=1e-5 / atol=1e-6 for forward paths

@@ -94,31 +94,38 @@ def test_all_bad_raises(tmp_path):
 
 
 def test_stale_abi_binary_triggers_rebuild(tmp_path, monkeypatch):
-    """A wrong-ABI .so that escapes the mtime test must be rebuilt from
-    source, not cached as a permanent failure."""
+    """A wrong-ABI .so sitting under the right name must be rebuilt
+    from source, not cached as a permanent failure."""
     import shutil
+    import subprocess
 
     import idc_models_tpu.data.native as nat
 
     src = tmp_path / "loader.cpp"
-    so = tmp_path / "_native_loader.so"
     shutil.copy(nat._SRC, src)
-    # build a fake ABI-0 binary, dated in the future so mtime says fresh
-    import subprocess
+    monkeypatch.setattr(nat, "_DIR", tmp_path)
+    monkeypatch.setattr(nat, "_SRC", src)
+    monkeypatch.setattr(nat, "_lib", None)
+    monkeypatch.setattr(nat, "_build_error", None)
     stub = tmp_path / "stub.cpp"
     stub.write_text('extern "C" int idc_loader_abi_version() { return 0; }')
     subprocess.run(["g++", "-O3", "-shared", "-fPIC", str(stub),
-                    "-o", str(so)], check=True)
-    import os as _os
-    future = _os.stat(src).st_mtime + 10_000
-    _os.utime(so, (future, future))
-
-    monkeypatch.setattr(nat, "_SRC", src)
-    monkeypatch.setattr(nat, "_SO", so)
-    monkeypatch.setattr(nat, "_lib", None)
-    monkeypatch.setattr(nat, "_build_error", None)
+                    "-o", str(nat._so_path())], check=True)
     assert nat.available(), nat.build_error()
     assert nat._lib.idc_loader_abi_version() == nat._ABI
+
+
+def test_binary_name_follows_the_source(tmp_path, monkeypatch):
+    """A binary built from another loader.cpp is never loaded: the name
+    carries the source's digest (file times do not survive a copy)."""
+    import idc_models_tpu.data.native as nat
+
+    before = nat._so_path()
+    src = tmp_path / "loader.cpp"
+    src.write_bytes(nat._SRC.read_bytes() + b"\n// edited\n")
+    monkeypatch.setattr(nat, "_SRC", src)
+    assert nat._so_path() != before
+    assert nat._so_path().parent == before.parent
 
 
 def test_load_directory_native_equals_pil(tmp_path):

@@ -12,7 +12,7 @@ from jax.sharding import PartitionSpec as P
 
 from idc_models_tpu import collectives, faults
 from idc_models_tpu import mesh as meshlib
-from idc_models_tpu.compat import shard_map
+from jax import shard_map
 from idc_models_tpu.data import synthetic
 from idc_models_tpu.data.idc import ArrayDataset
 from idc_models_tpu.data.partition import pad_clients, partition_clients
