@@ -2724,7 +2724,8 @@ def bench_tracer_overhead(on_accelerator: bool):
       (micro-timed over a large N);
     - `serve_trace_spans_per_window` — how many span sites one decode
       cycle executes (counted by running the same loop under an
-      enabled tracer);
+      enabled tracer: tick, admit, collect, device.sync, refill,
+      window, and turnaround's open and close);
     - `serve_decode_window_ms` — the wall cost of one steady-state
       decode cycle through the scheduler (host fetch fence: collect's
       token transfer data-depends on the window);
@@ -2816,8 +2817,13 @@ def bench_tracer_overhead(on_accelerator: bool):
             server2.step()
     finally:
         trace_lib.set_tracer(prev)
-    spans_per_window = len([r for r in tr.records()
-                            if r["name"].startswith("serve.")]) / n_ticks
+    # every disabled call site of a cycle is one call: a recorded span
+    # is one (the scheduler's and the engine's, `device.sync` in
+    # collect among them), and the detached `serve.turnaround` is two
+    # (its `start_span` and its `close`)
+    recs = tr.records()
+    spans_per_window = (len(recs) + sum(
+        r["name"] == "serve.turnaround" for r in recs)) / n_ticks
 
     overhead_pct = (spans_per_window * disabled_ns * 1e-9
                     / window_s * 100.0)

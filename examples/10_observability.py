@@ -9,9 +9,12 @@ continuous-batching engine with a tracer armed; the run produces:
 - `/tmp/idc_obs_example/trace.json` — Chrome trace-event JSON. Open it
   in Perfetto (https://ui.perfetto.dev) or chrome://tracing and you see
   the scheduler's cycles: `serve.tick` spans with `serve.admit` (and
-  the chunked `serve.prefill_chunk` dispatches under it),
-  `serve.collect` (blocking on the in-flight window's tokens) and
-  `serve.window` (the next fused dispatch) nested inside.
+  the chunked `serve.start_prefill` / `serve.prefill_chunk` /
+  `serve.insert` host work under it), `serve.collect` (blocking on the
+  in-flight window's tokens), `serve.refill` (the admission pass after
+  collect) and `serve.window` (the next fused dispatch) nested inside,
+  and the detached `serve.turnaround` from collect's return to that
+  dispatch's return: the host's side of the device's idle gap.
 - the same spans as a jsonl file, summarized by `observe.stats` — the
   library form of the `python -m idc_models_tpu stats <file>` verb.
 - the process-wide metrics registry in Prometheus text exposition.

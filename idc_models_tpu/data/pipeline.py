@@ -21,6 +21,7 @@ from jax.sharding import Mesh
 
 from idc_models_tpu import mesh as meshlib
 from idc_models_tpu.data.idc import ArrayDataset
+from idc_models_tpu.observe import trace
 
 
 class _EpochSchedule:
@@ -278,40 +279,78 @@ def prefetch_to_mesh(batches: Iterator, mesh: Mesh, *, axis=meshlib.DATA_AXIS,
     sharded over `axis`. A bounded queue of `prefetch` in-flight transfers
     overlaps host decode/transfer with device compute — the AUTOTUNE
     prefetch of the reference, made explicit.
+
+    Traced (observe/trace.py; every site is the shared no-op handle
+    unless a tracer is active), all under the span open on the
+    consumer's thread when iteration starts (`train.epoch`,
+    `train.eval`): `data.wait` around each `q.get()` on the consumer;
+    on the producer thread (`idc-prefetch`) `data.load` around each
+    `next(batches)`, `data.put` around one batch's `put_with_sharding`
+    calls and `data.full` around the bounded put; and the detached
+    `data.transfer`, from the put call to the placed arrays being ready
+    on the device. `device_put` returns before the bytes land, so that
+    last span is closed by a watcher thread that waits for them off both
+    paths; it exists only while a tracer is active.
     """
     sh = meshlib.sharding(mesh, axis)
     q: queue.Queue = queue.Queue(maxsize=prefetch)
     stop = threading.Event()
     _END = object()
+    parent = trace.current_span_id()
+    placed_q = _start_transfer_watcher(stop)
+    traced = placed_q is not None       # attributes are computed only then
 
     def put(item) -> bool:
         # Bounded put that gives up when the consumer is gone — otherwise
         # an abandoned iterator would leave this thread blocked forever,
         # pinning `prefetch` HBM-resident batches.
-        while not stop.is_set():
-            try:
-                q.put(item, timeout=0.1)
-                return True
-            except queue.Full:
-                continue
+        with trace.span("data.full", parent=parent):
+            while not stop.is_set():
+                try:
+                    q.put(item, timeout=0.1)
+                    return True
+                except queue.Full:
+                    continue
         return False
 
     def producer():
         try:
-            for batch in batches:
-                if not put(jax.tree.map(
-                        lambda a: meshlib.put_with_sharding(a, sh), batch)):
+            it = iter(batches)
+            index = 0
+            while True:
+                with trace.span("data.load", parent=parent) as sp:
+                    batch = next(it, _END)
+                    if traced:
+                        sp.set(index=index)
+                if batch is _END:
+                    break
+                if traced:
+                    nbytes = sum(int(getattr(a, "nbytes", 0))
+                                 for a in jax.tree.leaves(batch))
+                    moving = trace.start_span("data.transfer", parent=parent,
+                                              bytes=nbytes, index=index)
+                with trace.span("data.put", parent=parent) as sp:
+                    placed = jax.tree.map(
+                        lambda a: meshlib.put_with_sharding(a, sh), batch)
+                    if traced:
+                        sp.set(bytes=nbytes)
+                        placed_q.put((moving, placed))
+                if not put(placed):
                     return
+                index += 1
         except BaseException as e:  # surface errors to the consumer
             put(e)
             return
         put(_END)
 
-    t = threading.Thread(target=producer, daemon=True)
+    t = threading.Thread(target=producer, name="idc-prefetch", daemon=True)
     t.start()
     try:
         while True:
-            item = q.get()
+            with trace.span("data.wait") as sp:
+                if traced:
+                    sp.set(depth=q.qsize())
+                item = q.get()
             if item is _END:
                 return
             if isinstance(item, BaseException):
@@ -319,6 +358,39 @@ def prefetch_to_mesh(batches: Iterator, mesh: Mesh, *, axis=meshlib.DATA_AXIS,
             yield item
     finally:
         stop.set()
+
+
+def _start_transfer_watcher(stop: threading.Event):
+    """With a tracer active, start the thread that closes `data.transfer`
+    spans and return the queue that feeds it `(span, placed batch)`
+    pairs; None, and no thread, otherwise. It waits for each placed
+    batch off the producer's and the consumer's path, so neither is
+    paced by being measured, and ends once `stop` is set and the queue is
+    drained (what is queued was already dispatched, so each wait is
+    finite)."""
+    if trace.get_tracer() is None:
+        return None
+    placed_q: queue.SimpleQueue = queue.SimpleQueue()
+
+    def watcher():
+        while True:
+            try:
+                moving, placed = placed_q.get(timeout=0.1)
+            except queue.Empty:
+                if stop.is_set():
+                    return
+                continue
+            try:
+                jax.block_until_ready(placed)
+            except Exception as e:   # the consumer meets the same error
+                moving.close(error=type(e).__name__)
+            else:
+                moving.close()
+            del placed      # or the idle wait below pins a batch in HBM
+
+    threading.Thread(target=watcher, name="idc-prefetch-watch",
+                     daemon=True).start()
+    return placed_q
 
 
 def prefetch_eval_batches(ds: ArrayDataset, mesh: Mesh, batch_size: int, *,

@@ -7,10 +7,11 @@ training epochs — need to answer "where did this token/round actually
 spend its time" without each loop growing its own ad-hoc stopwatch.
 
 One `Tracer` records SPANS: named intervals with a process-unique id, a
-parent id (the innermost open span on the same thread), per-span
-attributes, and both clocks — a monotonic offset for durations and a
-wall-clock anchor so traces line up with jsonl logs. Two export
-formats:
+parent id (the innermost open span on the same thread, or the id a
+thread's outermost span was handed as `parent=`), the opening thread's
+ident and name, per-span attributes, and both clocks — a monotonic
+offset for durations and a wall-clock anchor so traces line up with
+jsonl logs. Two export formats:
 
 - `export_jsonl(path)` — one record per span, the same append-only
   shape every other run log in the framework uses.
@@ -77,16 +78,20 @@ class Span:
     the module-level `span()`); `set(**attrs)` attaches attributes any
     time before exit."""
 
-    __slots__ = ("name", "span_id", "parent_id", "tid", "attrs",
+    __slots__ = ("name", "span_id", "parent_id", "tid", "thread", "attrs",
                  "_tracer", "_t0", "_stack", "_detached", "dur_s")
 
-    def __init__(self, tracer: "Tracer", name: str, attrs: dict):
+    def __init__(self, tracer: "Tracer", name: str, attrs: dict,
+                 parent=None):
         self.name = name
         self.attrs = attrs
         self._tracer = tracer
         self.span_id = next(tracer._ids)
-        self.parent_id = None
+        # the caller's explicit cause; __enter__ keeps it only when the
+        # opening thread has no open span of its own
+        self.parent_id = parent
         self.tid = 0
+        self.thread = ""
         self._t0 = 0.0
         self._stack = None
         self._detached = False
@@ -104,14 +109,22 @@ class Span:
         # thread's stack instead would leave it dangling and corrupt
         # the parenting of every later span on the opening thread
         stack = self._stack = tr._stack()
-        self.parent_id = stack[-1].span_id if stack else None
-        self.tid = threading.get_ident()
+        if stack:
+            self.parent_id = stack[-1].span_id
+        self._stamp_thread()
         stack.append(self)
         # the clock read is LAST on entry (and first on exit) so nested
         # spans exclude as much of the tracer's own bookkeeping as
         # possible from their measured interval
         self._t0 = tr._clock()
         return self
+
+    def _stamp_thread(self) -> None:
+        # the name is kept on the span, not looked up by ident at export:
+        # idents are reused once a thread exits (the prefetch thread
+        # starts anew every epoch)
+        self.tid = threading.get_ident()
+        self.thread = threading.current_thread().name
 
     def __exit__(self, exc_type, exc, tb) -> None:
         tr = self._tracer
@@ -162,8 +175,16 @@ class Tracer:
             st = self._local.stack = []
         return st
 
-    def span(self, name: str, **attrs) -> Span:
-        return Span(self, name, attrs)
+    def span(self, name: str, parent=None, **attrs) -> Span:
+        """`parent` names the cause of a thread's OUTERMOST span (a
+        worker thread's work under the span that started it); with a
+        span already open on the entering thread, that one parents."""
+        return Span(self, name, attrs, parent)
+
+    def current_span_id(self):
+        """Id of the calling thread's innermost open span, or None."""
+        stack = self._stack()
+        return stack[-1].span_id if stack else None
 
     def start_span(self, name: str, parent=None, **attrs) -> Span:
         """A DETACHED span: opened now, finalized by `close()`, never on
@@ -173,9 +194,8 @@ class Tracer:
         request's whole submit→finish lifetime spanning many scheduler
         ticks (a stack-entered span held open that long would corrupt
         the parenting of every tick span under it)."""
-        s = Span(self, name, attrs)
-        s.parent_id = parent
-        s.tid = threading.get_ident()
+        s = Span(self, name, attrs, parent)
+        s._stamp_thread()
         s._detached = True
         s._t0 = self._clock()
         return s
@@ -205,6 +225,7 @@ class Tracer:
             out.append({
                 "event": "span", "name": s.name, "id": s.span_id,
                 "parent": s.parent_id, "tid": s.tid,
+                "thread": s.thread,
                 "t_ms": round(start * 1e3, 4),
                 "dur_ms": round(s.dur_s * 1e3, 4),
                 "wall": round(self.wall_t0 + start, 6),
@@ -226,7 +247,9 @@ class Tracer:
     def export_chrome(self, path) -> str:
         """Chrome trace-event JSON: `ph:"X"` complete events with
         microsecond `ts`/`dur` (Perfetto's expectations), one event per
-        finished span, plus a process-name metadata record."""
+        finished span, plus a process-name metadata record and one
+        thread-name record per thread ident (an ident reused by a later
+        thread lists both names)."""
         path = Path(path)
         path.parent.mkdir(parents=True, exist_ok=True)
         pid = os.getpid()
@@ -234,7 +257,11 @@ class Tracer:
             "ph": "M", "name": "process_name", "pid": pid, "tid": 0,
             "args": {"name": "idc_models_tpu"},
         }]
+        threads: dict[int, list[str]] = {}
         for rec in self.records():
+            names = threads.setdefault(rec["tid"], [])
+            if rec["thread"] not in names:
+                names.append(rec["thread"])
             events.append({
                 "name": rec["name"], "ph": "X", "pid": pid,
                 "tid": rec["tid"],
@@ -243,6 +270,9 @@ class Tracer:
                 "args": {**rec["attrs"], "span_id": rec["id"],
                          "parent_id": rec["parent"]},
             })
+        events[1:1] = [{"ph": "M", "name": "thread_name", "pid": pid,
+                        "tid": tid, "args": {"name": "/".join(names)}}
+                       for tid, names in threads.items()]
         with open(path, "w") as f:
             json.dump({"traceEvents": events, "displayTimeUnit": "ms"},
                       f)
@@ -266,14 +296,26 @@ def get_tracer() -> Tracer | None:
     return _ACTIVE
 
 
-def span(name: str, **attrs):
+def span(name: str, parent=None, **attrs):
     """A span on the active tracer — or the shared no-op handle when
     tracing is disabled. THE instrumentation entry point for every hot
-    path; its disabled cost is gated by `bench_tracer_overhead`."""
+    path; its disabled cost is gated by `bench_tracer_overhead`.
+    `parent` (a span id) is used when the opening thread has no open
+    span of its own — see `Tracer.span`."""
     tr = _ACTIVE
     if tr is None:
         return _NULL_SPAN
-    return Span(tr, name, attrs)
+    return Span(tr, name, attrs, parent)
+
+
+def current_span_id():
+    """Id of the calling thread's innermost open span — None when
+    there is none, or when tracing is disabled. What a thread hands to
+    the workers it starts, as their spans' `parent=`."""
+    tr = _ACTIVE
+    if tr is None:
+        return None
+    return tr.current_span_id()
 
 
 def start_span(name: str, parent=None, **attrs):

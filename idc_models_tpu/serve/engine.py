@@ -1588,7 +1588,7 @@ class SlotEngine:
                 f"tenants")
 
     def _insert(self, slot, caches1, logits1, p_len, max_new_tokens,
-                eos_id, rng, tid: int = 0, prompt=None) -> None:
+                eos_id, rng, tid: int = 0, prompt=None, tag=None) -> None:
         """Scatter a fully prefilled request into the batch row — the
         shared tail of both admission paths. `tid` is the request's
         tenant id (0 = default): a traced scalar into the tslot row,
@@ -1596,34 +1596,38 @@ class SlotEngine:
         `prompt` (the [P] token row) seeds the learned drafter's state
         for this slot when one is armed — both admission paths pass
         it; `import_slot` restores drafter state from its snapshot
-        instead."""
-        eos = self.eos_id if eos_id is None else eos_id
-        eos = -1 if eos is None else int(eos)
-        kd_row = (_key_data(rng) if rng is not None
-                  else np.zeros(2, np.uint32))
-        if self.paged:
-            # the prompt K/V already lives in the slot's pages — the
-            # paged insert is a scalar/row scatter only
-            (self._logits, self._kd, self._pos, self._rem,
-             self._eos, self._tslot) = self._efns.insert(
-                self._logits, self._kd, self._pos, self._rem,
-                self._eos, self._tslot, logits1, np.int32(slot),
-                np.int32(p_len), np.int32(max_new_tokens),
-                np.int32(eos), np.int32(tid), kd_row)
-        else:
-            (self._caches, self._logits, self._kd, self._pos, self._rem,
-             self._eos, self._tslot, self._scales) = self._efns.insert(
-                self._caches, self._logits, self._kd, self._pos,
-                self._rem, self._eos, self._tslot, self._scales,
-                caches1, logits1, np.int32(slot), np.int32(p_len),
-                np.int32(max_new_tokens), np.int32(eos),
-                np.int32(tid), kd_row)
-        self._pos_h[slot] = p_len
-        self._rem_h[slot] = max_new_tokens
-        self._eos_h[slot] = eos
-        self._occupied[slot] = True
-        if self._dfns is not None and prompt is not None:
-            self._draft_admit(slot, np.asarray(prompt, np.int32).ravel())
+        instead. `tag` (the rid) is stamped on the `serve.insert` span,
+        which covers the host's side of the scatter: its dispatch."""
+        with trace.span("serve.insert", slot=slot, rid=tag):
+            eos = self.eos_id if eos_id is None else eos_id
+            eos = -1 if eos is None else int(eos)
+            kd_row = (_key_data(rng) if rng is not None
+                      else np.zeros(2, np.uint32))
+            if self.paged:
+                # the prompt K/V already lives in the slot's pages — the
+                # paged insert is a scalar/row scatter only
+                (self._logits, self._kd, self._pos, self._rem,
+                 self._eos, self._tslot) = self._efns.insert(
+                    self._logits, self._kd, self._pos, self._rem,
+                    self._eos, self._tslot, logits1, np.int32(slot),
+                    np.int32(p_len), np.int32(max_new_tokens),
+                    np.int32(eos), np.int32(tid), kd_row)
+            else:
+                (self._caches, self._logits, self._kd, self._pos,
+                 self._rem, self._eos, self._tslot,
+                 self._scales) = self._efns.insert(
+                    self._caches, self._logits, self._kd, self._pos,
+                    self._rem, self._eos, self._tslot, self._scales,
+                    caches1, logits1, np.int32(slot), np.int32(p_len),
+                    np.int32(max_new_tokens), np.int32(eos),
+                    np.int32(tid), kd_row)
+            self._pos_h[slot] = p_len
+            self._rem_h[slot] = max_new_tokens
+            self._eos_h[slot] = eos
+            self._occupied[slot] = True
+            if self._dfns is not None and prompt is not None:
+                self._draft_admit(slot,
+                                  np.asarray(prompt, np.int32).ravel())
 
     def _draft_admit(self, slot: int, prompt: np.ndarray) -> None:
         """Seed the learned drafter's row for a fresh admission: prefill
@@ -1693,7 +1697,7 @@ class SlotEngine:
             logits1, caches1 = self._sfns.prefill(self._params, padded,
                                                   np.int32(p_len))
             self._insert(slot, caches1, logits1, p_len, max_new_tokens,
-                         eos_id, rng, tid, prompt=prompt[0])
+                         eos_id, rng, tid, prompt=prompt[0], tag=tag)
 
     # -- chunked prefill --------------------------------------------------
 
@@ -1710,22 +1714,28 @@ class SlotEngine:
         insert (or `cancel_prefill`)."""
         if self.prefill_chunk is None:
             raise RuntimeError("engine built without prefill_chunk")
-        prompt = self._validate_admit(slot, prompt, max_new_tokens, rng)
-        self._check_tid(tid)
-        if self.paged:
-            self._start_prefill_paged(slot, prompt, max_new_tokens,
-                                      rng, eos_id, tag, tid)
-            return
-        start, caches, logits = 0, None, None
-        if self.prefix_cache is not None:
-            start, caches, logits = self.prefix_cache.lookup(prompt[0])
-            start = min(start, prompt.shape[1])
-        if caches is None:
-            caches = self._sfns.init_caches(1)
-        self._prefills[slot] = _PendingPrefill(
-            prompt=prompt, budget=int(max_new_tokens), rng=rng,
-            eos_id=eos_id, caches=caches, logits=logits,
-            next_start=start, tag=tag, tid=tid)
+        # the span covers what admission costs the host before any
+        # chunk runs: validation, the prefix lookup, and the request's
+        # own cache row (contiguous) or page grant (paged)
+        with trace.span("serve.start_prefill", slot=slot, rid=tag):
+            prompt = self._validate_admit(slot, prompt, max_new_tokens,
+                                          rng)
+            self._check_tid(tid)
+            if self.paged:
+                self._start_prefill_paged(slot, prompt, max_new_tokens,
+                                          rng, eos_id, tag, tid)
+                return
+            start, caches, logits = 0, None, None
+            if self.prefix_cache is not None:
+                start, caches, logits = self.prefix_cache.lookup(
+                    prompt[0])
+                start = min(start, prompt.shape[1])
+            if caches is None:
+                caches = self._sfns.init_caches(1)
+            self._prefills[slot] = _PendingPrefill(
+                prompt=prompt, budget=int(max_new_tokens), rng=rng,
+                eos_id=eos_id, caches=caches, logits=logits,
+                next_start=start, tag=tag, tid=tid)
 
     def _pages_for(self, p_len: int, budget: int) -> int:
         """Pages an admission reserves: the prompt plus the decode
@@ -1899,7 +1909,7 @@ class SlotEngine:
                                           pend.pages[n_prompt - 1])
             self._insert(slot, pend.caches, pend.logits, p_len,
                          pend.budget, pend.eos_id, pend.rng, pend.tid,
-                         prompt=pend.prompt)
+                         prompt=pend.prompt, tag=pend.tag)
         return done
 
     def cancel_prefill(self, slot: int) -> None:

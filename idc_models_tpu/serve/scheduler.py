@@ -754,14 +754,20 @@ class Scheduler:
         freed by the in-flight window refills next tick.
 
         Traced (observe/trace.py, no-op unless a tracer is active):
-        one `serve.tick` span per cycle with `serve.admit`,
-        `serve.collect` and `serve.window` nested under it, and the
-        engine's `serve.prefill`/`serve.prefill_chunk` spans nested
-        under the admit. ACROSS ticks, each request's detached
-        `serve.request` span (opened at submit) accumulates its
-        lifecycle chain — see the Entry fields above."""
-        with trace.span("serve.tick"):
-            return self._tick()
+        one `serve.tick` span per cycle with `serve.admit` (the
+        admission pass that overlaps the window in flight),
+        `serve.collect`, `serve.refill` (the pass after collect, while
+        the device has nothing to run) and `serve.window` nested under
+        it, and the engine's `serve.start_prefill` / `serve.prefill` /
+        `serve.prefill_chunk` / `serve.insert` spans nested under the
+        two passes. `serve.turnaround` (detached, under the tick) runs
+        from collect's return to the next dispatch's return: the host's
+        side of the device's idle time between two windows. ACROSS
+        ticks, each request's detached `serve.request` span (opened at
+        submit) accumulates its lifecycle chain — see the Entry fields
+        above."""
+        with trace.span("serve.tick") as tick_span:
+            return self._tick(tick_span.span_id)
 
     def quiesce(self) -> list[Entry]:
         """One normal cycle with the end-of-tick window dispatch
@@ -774,12 +780,12 @@ class Scheduler:
         decode idleness; the next tick() resumes dispatching."""
         self._skip_dispatch = True
         try:
-            with trace.span("serve.tick", quiesce=True):
-                return self._tick()
+            with trace.span("serve.tick", quiesce=True) as tick_span:
+                return self._tick(tick_span.span_id)
         finally:
             self._skip_dispatch = False
 
-    def _tick(self) -> list[Entry]:
+    def _tick(self, tick_span_id=None) -> list[Entry]:
         now = self.clock()
         done: list[Entry] = []
         # 0. declarative fault drills (default-off): stall/crash/
@@ -858,6 +864,14 @@ class Scheduler:
             # over a non-empty running set always returns rows, so
             # `out or spec` detects exactly the collected dispatches.
             spec = getattr(self.engine, "last_spec", None)
+            # a dispatch was collected: from here to the next one's
+            # return the device has nothing to run (detached: the
+            # refill / propose / window spans below stay children of
+            # the tick and split it; the dispatch, the decision not to
+            # dispatch and the failure paths each close it)
+            turnaround = (trace.start_span("serve.turnaround",
+                                           parent=tick_span_id)
+                          if out or spec else None)
             if (out or spec) and self.metrics:
                 self.metrics.on_dispatch("verify" if spec else "window")
             # a collected VERIFY reports its accept bookkeeping
@@ -908,11 +922,12 @@ class Scheduler:
         if self.admit_after_collect:
             t_pf2 = self.clock()
             try:
-                with trace.span("serve.admit", refill=True) as _sp:
+                with trace.span("serve.refill") as _sp:
                     n2 = self._admit_free_slots()
                     _sp.set(admitted=n2)
                 admitted += n2
             except Exception as e:
+                self._end_turnaround(turnaround, False, e)
                 # same salvage as a begin_window failure: the entries
                 # the just-collected window completed are real results
                 # — finalize them (and the step-1 expiries) into the
@@ -988,7 +1003,9 @@ class Scheduler:
                             _wsp.set(rids=[e.rid for e
                                            in self._running.values()])
                         self.engine.begin_window(self.window)
+                self._end_turnaround(turnaround, True)
             except Exception as e:
+                self._end_turnaround(turnaround, False, e)
                 # entries the just-collected window COMPLETED (EOS/
                 # budget/deadline) are real results, not casualties:
                 # finalize them with their true statuses — plus the
@@ -999,6 +1016,8 @@ class Scheduler:
                 self._failed.extend(done)
                 self._abort_running(e)
                 raise
+        else:
+            self._end_turnaround(turnaround, False)
         # 7. deferred bookkeeping — runs WHILE the new window computes.
         #    Cycles that only admitted/prefilled (nothing decoding yet —
         #    e.g. a long prompt's chunk-by-chunk admission) STILL record:
@@ -1052,6 +1071,15 @@ class Scheduler:
             if on_jit is not None and sizes is not None:
                 on_jit(sum(sizes().values()))
         return done
+
+    def _end_turnaround(self, span, dispatched: bool, error=None) -> None:
+        """Close a tick's `serve.turnaround` (None: no window was
+        collected, so nothing was opened)."""
+        if span is None:
+            return
+        if error is not None:
+            span.set(error=type(error).__name__)
+        span.close(slots=len(self._running), dispatched=dispatched)
 
     def _propose_drafts(self, got):
         """The speculative policy pass — pure host work in the

@@ -285,9 +285,10 @@ def fit(model: core.Module, optimizer: optax.GradientTransformation,
         with trace.span("train.epoch", epoch=epoch) as ep_span:
             for x, y in prefetch_to_mesh(loader.epoch(epoch), mesh):
                 key, sub = jax.random.split(key)
-                # the span covers host wait + async step DISPATCH; the
-                # device time it hides is fenced by the epoch-mean
-                # fetch below, inside train.epoch
+                # the span covers the async step DISPATCH alone: the
+                # wait for the batch is prefetch_to_mesh's data.wait,
+                # and the device time the dispatch hides is fenced by
+                # the epoch-mean fetch below, inside train.epoch
                 with trace.span("train.step"):
                     state, m = step_fn(state, x, y, sub)
                 if not accounted:
@@ -301,8 +302,10 @@ def fit(model: core.Module, optimizer: optax.GradientTransformation,
                 accs.append(m["accuracy"])
             m_steps.inc(len(losses))
             # the epoch-mean fetch is where this loop BLOCKS on the
-            # device — bracketed as device.sync so a DeviceTimeline
-            # can split train.epoch into device-wait vs host gap
+            # device — bracketed as device.sync: its share of
+            # train.epoch is the benchmark's loop_sync_share, and
+            # train.epoch minus data.wait, train.step and this span is
+            # the loop's own overhead
             with trace.span("device.sync"):
                 ep = {
                     "loss": float(jnp.mean(jnp.stack(losses))),

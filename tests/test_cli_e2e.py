@@ -568,7 +568,8 @@ def test_cli_serve_trace_out_and_stats(tmp_path, capsys):
     names = {e["name"] for e in spans}
     assert {"serve.tick", "serve.admit", "serve.collect",
             "serve.window", "serve.prefill_chunk",
-            "Serving trace"} <= names
+            "serve.refill", "serve.turnaround", "serve.start_prefill",
+            "serve.insert", "Serving trace"} <= names
     by_id = {e["args"]["span_id"]: e for e in spans}
     # Perfetto's expectations: numeric microsecond ts/dur, and children
     # contained in their parent's interval
@@ -583,9 +584,18 @@ def test_cli_serve_trace_out_and_stats(tmp_path, capsys):
                      for e in spans
                      if e["name"] == "serve.prefill_chunk"}
     assert chunk_parents == {"serve.admit"}
-    window_parents = {by_id[e["args"]["parent_id"]]["name"]
-                      for e in spans if e["name"] == "serve.window"}
-    assert window_parents == {"serve.tick"}
+    for name in ("serve.window", "serve.refill", "serve.turnaround"):
+        assert {by_id[e["args"]["parent_id"]]["name"]
+                for e in spans if e["name"] == name} == {"serve.tick"}
+    assert {by_id[e["args"]["parent_id"]]["name"] for e in spans
+            if e["name"] == "serve.start_prefill"} <= {"serve.admit",
+                                                       "serve.refill"}
+    assert all(e["args"]["rid"] for e in spans
+               if e["name"] in ("serve.insert", "serve.start_prefill"))
+    # every thread that opened a span is named in the export
+    named = {e["tid"] for e in doc["traceEvents"]
+             if e.get("ph") == "M" and e["name"] == "thread_name"}
+    assert {e["tid"] for e in spans} <= named
 
     # ISSUE-7 acceptance: for EVERY finished rid, the submit->finish
     # chain reconstructs from the exported file with correct nesting
