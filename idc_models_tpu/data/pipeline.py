@@ -7,13 +7,20 @@ per-epoch order is a fresh seeded permutation (the shuffle); batches are
 cut to a multiple of the mesh's data-axis size; and a background thread
 keeps `prefetch` batches already transferred to device HBM with the right
 NamedSharding (the prefetch) so the chips never wait on PCIe/host.
+Where that thread is handed a `Loader`'s epoch it assembles the batches
+in the loader's ring of recycled host buffers (`_StagingRing`): writing a
+batch into pages that are already mapped, instead of into a fresh
+allocation every time, is what lets the host keep up with the chips.
 """
 
 from __future__ import annotations
 
+import os
 import queue
 import threading
+import time
 from collections.abc import Iterator
+from concurrent.futures import ThreadPoolExecutor
 
 import jax
 import numpy as np
@@ -118,10 +125,16 @@ class _EpochSchedule:
 
 class Loader(_EpochSchedule):
     """Iterates (images, labels) numpy batches of a materialized
-    ArrayDataset over epochs (see _EpochSchedule for the knobs)."""
+    ArrayDataset over epochs (see _EpochSchedule for the knobs).
+
+    Iterated directly, every batch is a fresh pair of arrays the caller
+    owns. Handed to `prefetch_to_mesh`, which owns the hand-over to the
+    device, the same batches are assembled in a ring of host buffers
+    that lives as long as the loader (`_StagingRing`)."""
 
     def __init__(self, ds: ArrayDataset, batch_size: int, **kw):
         self.ds = ds
+        self._ring: _StagingRing | None = None
         super().__init__(batch_size, **kw)
 
     def _num_examples(self) -> int:
@@ -129,6 +142,146 @@ class Loader(_EpochSchedule):
 
     def _gather(self, idx):
         return self.ds.images[idx], self.ds.labels[idx]
+
+    def epoch(self, epoch: int = 0) -> "_LoaderEpoch":
+        return _LoaderEpoch(self, epoch)
+
+    def replace(self, **kw) -> "Loader":
+        new = super().replace(**kw)
+        new._ring = None    # sized by the copy's own batch and dataset
+        return new
+
+    def _staging(self, depth: int) -> "_StagingRing":
+        """The loader's ring, built at the first hand-over (a loader that
+        is only iterated directly never has one) and again only if a
+        later hand-over asks for another depth."""
+        if self._ring is None or len(self._ring.slots) != depth:
+            self._ring = _StagingRing(depth, self.batch_size, self.ds)
+        return self._ring
+
+
+class _LoaderEpoch:
+    """One epoch of a `Loader`'s batches. As an iterator it yields what
+    the generator it replaces yielded: `_gather`'s fresh arrays.
+    `prefetch_to_mesh` recognises it and calls `next_into` instead, which
+    writes the same rows into a slot of the loader's staging ring; which
+    of the two runs follows from who iterates, nothing else."""
+
+    def __init__(self, loader: Loader, epoch: int):
+        self.loader = loader
+        self._indices = loader._index_batches(epoch)
+        self.left = len(loader)         # batches not yet drawn
+
+    def __iter__(self):
+        return self
+
+    def _next_indices(self) -> np.ndarray:
+        idx = next(self._indices)
+        self.left -= 1
+        return idx
+
+    def __next__(self):
+        return self.loader._gather(self._next_indices())
+
+    def next_into(self, ring: "_StagingRing", slot: "_Slot"):
+        return ring.fill(slot, self.loader.ds, self._next_indices())
+
+
+# A batch is cut into row slices of about this size for the ring's pool
+# (see _StagingRing): one thread writes mapped pages at 11 GB/s on the
+# chip's host, so a smaller batch is done before a pool would have
+# started on it.
+_FILL_SLICE_BYTES = 64 << 20
+
+
+class _Slot:
+    """One staging buffer pair, and the device arrays last placed from
+    it: while they are not ready the transfer may still read the host
+    buffers, so `_StagingRing.acquire` waits for them before a refill."""
+
+    __slots__ = ("images", "labels", "placed")
+
+    def __init__(self, rows: int, ds: ArrayDataset):
+        self.images = np.empty((rows,) + ds.images.shape[1:], ds.images.dtype)
+        self.labels = np.empty((rows,) + ds.labels.shape[1:], ds.labels.dtype)
+        self.placed = None
+
+
+class _StagingRing:
+    """A `Loader`'s recycled host batches, used round-robin by the
+    prefetch thread across epochs.
+
+    `images[idx]` allocates a fresh batch every time, and writing fresh
+    pages is what it costs (0.94 GB/s on the chip's host, PERF.md §5);
+    `np.take(..., out=)` into pages mapped by an earlier batch moves the
+    same rows several times faster, and releases the interpreter lock, so
+    a large batch is filled in row slices by a few threads. Depth and
+    pool width are derived: the depth from the prefetch queue's (by
+    `prefetch_to_mesh`), the width from the batch's bytes and the host's
+    cores. The buffers are mapped by their first fill, not before.
+    """
+
+    def __init__(self, depth: int, rows: int, ds: ArrayDataset):
+        self.slots = [_Slot(rows, ds) for _ in range(depth)]
+        self._next = 0
+        self._lock = threading.Lock()
+        self._owner_stop: threading.Event | None = None
+        nbytes = self.slots[0].images.nbytes
+        # a quarter of the cores: the copy rate levels off at four
+        # threads (PERF.md §5), and the transfer runs on the others
+        self.width = max(1, min(nbytes // _FILL_SLICE_BYTES,
+                                (os.cpu_count() or 1) // 4))
+        self._pool = (ThreadPoolExecutor(self.width,
+                                         thread_name_prefix="idc-stage")
+                      if self.width > 1 else None)
+
+    def checkout(self, stop: threading.Event) -> bool:
+        """Claim the ring for one producer thread until `release`. An
+        abandoned epoch's producer may still be writing a slot: it is on
+        its way out (its `stop` is set), so wait for it. A live epoch of
+        the same loader keeps the ring, and this one gathers the plain
+        way (False)."""
+        while not self._lock.acquire(blocking=False):
+            owner = self._owner_stop
+            if owner is None or not owner.is_set():
+                return False
+            time.sleep(0.005)
+        self._owner_stop = stop
+        return True
+
+    def release(self) -> None:
+        self._lock.release()
+
+    def acquire(self) -> _Slot:
+        """The next slot, once nothing can still read it: the arrays
+        placed from it last time round (by this epoch or by one that was
+        abandoned with transfers in flight) are ready on the device."""
+        slot = self.slots[self._next]
+        self._next = (self._next + 1) % len(self.slots)
+        if slot.placed is not None:
+            jax.block_until_ready(slot.placed)
+            slot.placed = None
+        return slot
+
+    def fill(self, slot: _Slot, ds: ArrayDataset, idx: np.ndarray):
+        """Rows `idx` of `ds` written into the head of `slot`; returns
+        the views that hold them (all of the slot, but for a final
+        partial batch). `idx` comes from the schedule, so it is in range
+        and `mode="clip"` changes nothing but numpy's buffering of
+        `out`."""
+        n = len(idx)
+        x, y = slot.images[:n], slot.labels[:n]
+        np.take(ds.labels, idx, axis=0, out=y, mode="clip")
+        if self._pool is None:
+            np.take(ds.images, idx, axis=0, out=x, mode="clip")
+        else:
+            cuts = [n * i // self.width for i in range(self.width + 1)]
+            parts = [self._pool.submit(np.take, ds.images, idx[lo:hi], 0,
+                                       x[lo:hi], "clip")
+                     for lo, hi in zip(cuts, cuts[1:])]
+            for part in parts:
+                part.result()
+        return x, y
 
 
 class FileStream(_EpochSchedule):
@@ -280,11 +433,24 @@ def prefetch_to_mesh(batches: Iterator, mesh: Mesh, *, axis=meshlib.DATA_AXIS,
     overlaps host decode/transfer with device compute — the AUTOTUNE
     prefetch of the reference, made explicit.
 
+    When `batches` is a `Loader`'s epoch (`loader.epoch(e)`,
+    `iter(loader)`), the hand-over to the device is this function's, so
+    no caller can keep a host batch, and the producer assembles each
+    batch in the loader's staging ring (`_StagingRing`) instead of in a
+    fresh allocation. The ring is as deep as the batches that can be
+    live at once: the one being filled, the `prefetch` queued, and the
+    one whose transfer the consumer's step may still wait for. A slot is
+    refilled only after the arrays placed from it are ready on the
+    device, and they are placed with `may_alias=False`. Any other
+    source (`FileStream`, a generator) keeps the arrays it yields.
+
     Traced (observe/trace.py; every site is the shared no-op handle
     unless a tracer is active), all under the span open on the
     consumer's thread when iteration starts (`train.epoch`,
     `train.eval`): `data.wait` around each `q.get()` on the consumer;
-    on the producer thread (`idc-prefetch`) `data.load` around each
+    on the producer thread (`idc-prefetch`) `data.recycle` around each
+    acquisition of a ring slot (the wait for the slot's previous
+    transfer; none without a ring), `data.load` around each
     `next(batches)`, `data.put` around one batch's `put_with_sharding`
     calls and `data.full` around the bounded put; and the detached
     `data.transfer`, from the put call to the placed arrays being ready
@@ -299,6 +465,9 @@ def prefetch_to_mesh(batches: Iterator, mesh: Mesh, *, axis=meshlib.DATA_AXIS,
     parent = trace.current_span_id()
     placed_q = _start_transfer_watcher(stop)
     traced = placed_q is not None       # attributes are computed only then
+    # multi-process placement reads the host batch through a callback of
+    # its own (put_with_sharding): those batches stay fresh allocations
+    staged = isinstance(batches, _LoaderEpoch) and sh.is_fully_addressable
 
     def put(item) -> bool:
         # Bounded put that gives up when the consumer is gone — otherwise
@@ -313,34 +482,56 @@ def prefetch_to_mesh(batches: Iterator, mesh: Mesh, *, axis=meshlib.DATA_AXIS,
                     continue
         return False
 
-    def producer():
-        try:
-            it = iter(batches)
-            index = 0
-            while True:
-                with trace.span("data.load", parent=parent) as sp:
-                    batch = next(it, _END)
-                    if traced:
-                        sp.set(index=index)
-                if batch is _END:
-                    break
+    def produce(ring):
+        it = iter(batches)
+        index = 0
+        while True:
+            slot = None
+            if ring is not None and batches.left:
+                with trace.span("data.recycle", parent=parent):
+                    slot = ring.acquire()
+            with trace.span("data.load", parent=parent) as sp:
+                batch = (next(it, _END) if slot is None
+                         else batches.next_into(ring, slot))
                 if traced:
-                    nbytes = sum(int(getattr(a, "nbytes", 0))
-                                 for a in jax.tree.leaves(batch))
-                    moving = trace.start_span("data.transfer", parent=parent,
-                                              bytes=nbytes, index=index)
-                with trace.span("data.put", parent=parent) as sp:
-                    placed = jax.tree.map(
-                        lambda a: meshlib.put_with_sharding(a, sh), batch)
-                    if traced:
-                        sp.set(bytes=nbytes)
-                        placed_q.put((moving, placed))
-                if not put(placed):
-                    return
-                index += 1
+                    sp.set(index=index)
+            if batch is _END:
+                return True
+            if traced:
+                nbytes = sum(int(getattr(a, "nbytes", 0))
+                             for a in jax.tree.leaves(batch))
+                moving = trace.start_span("data.transfer", parent=parent,
+                                          bytes=nbytes, index=index)
+            with trace.span("data.put", parent=parent) as sp:
+                # a slot is written again: nothing may keep its memory
+                may_alias = None if slot is None else False
+                placed = jax.tree.map(
+                    lambda a: meshlib.put_with_sharding(
+                        a, sh, may_alias=may_alias), batch)
+                if slot is not None:
+                    slot.placed = placed
+                if traced:
+                    sp.set(bytes=nbytes)
+                    placed_q.put((moving, placed))
+            if not put(placed):
+                return False
+            index += 1
+
+    def producer():
+        ring = None
+        try:
+            if staged:
+                ring = batches.loader._staging(prefetch + 2)
+                if not ring.checkout(stop):
+                    ring = None
+            if not produce(ring):
+                return
         except BaseException as e:  # surface errors to the consumer
             put(e)
             return
+        finally:
+            if ring is not None:
+                ring.release()
         put(_END)
 
     t = threading.Thread(target=producer, name="idc-prefetch", daemon=True)

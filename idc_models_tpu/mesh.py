@@ -256,7 +256,7 @@ def batch_axis(mesh: Mesh, axis: str | None = None) -> str:
                      f"{mesh.axis_names}; pass axis=...")
 
 
-def put_with_sharding(a, sh: NamedSharding):
+def put_with_sharding(a, sh: NamedSharding, *, may_alias: bool | None = None):
     """Host array -> device(s) under `sh`, multi-process safe.
 
     `jax.device_put` onto a sharding that spans other processes' devices
@@ -265,11 +265,21 @@ def put_with_sharding(a, sh: NamedSharding):
     host to feed only its local shards anyway. `make_array_from_callback`
     does exactly that: this process materializes only the index slices
     belonging to its addressable devices.
+
+    `may_alias=False` is for a caller that will write to `a` again (a
+    recycled staging buffer): the placed array never shares its memory.
+    An accelerator copies anyway, and needs `a` unchanged only until the
+    array is ready.
     """
     if isinstance(a, jax.Array) and a.sharding == sh:
         return a  # already placed — don't round-trip through host
     if sh.is_fully_addressable:
-        return jax.device_put(a, sh)
+        if may_alias is False and sh.mesh.devices.flat[0].platform == "cpu":
+            # jax 0.9.0 drops `may_alias` for a numpy argument, and its
+            # CPU client keeps a 64-byte-aligned host buffer as the
+            # array's own memory: there the copy has to be made here
+            a = np.array(a)
+        return jax.device_put(a, sh, may_alias=may_alias)
     arr = np.asarray(a)
     return jax.make_array_from_callback(arr.shape, sh,
                                         lambda idx: arr[idx])

@@ -384,3 +384,185 @@ def test_patchify_rejections():
         sequences.patchify(np.zeros((6, 6, 3), np.float32), 2)
     with pytest.raises(ValueError, match=">= 1"):
         sequences.sequence_shape(6, 0)
+
+
+# -- the loader's staging ring (recycled host batches under prefetch) ------
+
+
+def _tagged_loader(n, batch, **kw):
+    """Every row carries its own index, so a wrong or torn row shows."""
+    imgs = synthetic.make_idc_like(n, size=6, seed=0)[0].astype(np.float32)
+    imgs[:, 0, 0, 0] = np.arange(n)
+    return Loader(ArrayDataset(imgs, np.arange(n, dtype=np.int32)), batch, **kw)
+
+
+def _host(batch):
+    return tuple(np.asarray(a) for a in batch)
+
+
+@pytest.mark.parametrize("kw", [
+    dict(shuffle=True, repeat=2, seed=3),
+    dict(shuffle=True, seed=1, drop_remainder=False),   # final batch of 8
+    dict(shuffle=False),
+], ids=["shuffle_repeat2", "partial_final_batch", "in_order"])
+def test_staged_stream_equals_direct_epoch(devices, kw):
+    """Handed to the prefetcher, a loader's epochs come out of its ring
+    bit for bit as `Loader.epoch` yields them, slots reused across
+    epochs."""
+    mesh = meshlib.data_mesh(8)
+    ld = _tagged_loader(72, 16, **kw)
+    for epoch in range(4):
+        got = [_host(b) for b in
+               pipeline.prefetch_to_mesh(ld.epoch(epoch), mesh)]
+        want = list(ld.epoch(epoch))
+        assert len(got) == len(want) == len(ld)
+        for (x, y), (wx, wy) in zip(got, want):
+            assert x.dtype == wx.dtype and y.dtype == wy.dtype
+            np.testing.assert_array_equal(x, wx)
+            np.testing.assert_array_equal(y, wy)
+    ring = ld._ring
+    assert ring is not None and len(ring.slots) == 4    # prefetch 2 + 2
+    assert all(s.placed is not None for s in ring.slots)
+
+
+def test_staged_batches_do_not_change_after_hand_over(devices):
+    """The ring (3 slots) is shallower than the epoch (12 batches): every
+    slot is refilled three times while the consumer still holds every
+    device array it was given."""
+    mesh = meshlib.data_mesh(8)
+    ld = _tagged_loader(96, 8, shuffle=True, seed=5)
+    held = list(pipeline.prefetch_to_mesh(ld.epoch(0), mesh, prefetch=1))
+    assert len(ld._ring.slots) == 3 and len(held) == 12
+    for got, (wx, wy) in zip(held, ld.epoch(0)):
+        x, y = _host(got)
+        np.testing.assert_array_equal(x, wx)
+        np.testing.assert_array_equal(y, wy)
+
+
+def test_put_with_sharding_may_alias_false_copies_an_aligned_buffer(devices):
+    """jax's CPU client keeps a 64-byte-aligned numpy buffer as the
+    array's memory; a staging slot is written again, so its placement
+    must not."""
+    sh = meshlib.sharding(meshlib.data_mesh(8), meshlib.DATA_AXIS)
+    raw = np.empty(8 * 64 * 4 + 128, np.uint8)
+    start = (-raw.ctypes.data) % 64
+    a = raw[start:start + 8 * 64 * 4].view(np.float32).reshape(8, 64)
+    a[...] = 1.0
+    placed = meshlib.put_with_sharding(a, sh, may_alias=False)
+    placed.block_until_ready()
+    a[...] = 2.0
+    np.testing.assert_array_equal(np.asarray(placed), 1.0)
+
+
+def test_abandoned_epoch_then_new_epoch_on_the_same_loader(devices):
+    """The abandoned epoch's producer may still be filling a slot, and
+    its transfers are in flight: the next epoch takes the ring over only
+    once it is gone, and waits for the slots' arrays."""
+    mesh = meshlib.data_mesh(8)
+    ld = _tagged_loader(96, 8, shuffle=True, seed=2)
+    for epoch in range(3):
+        it = pipeline.prefetch_to_mesh(ld.epoch(epoch), mesh, prefetch=1)
+        next(it)
+        it.close()
+        got = [_host(b) for b in pipeline.prefetch_to_mesh(
+            ld.epoch(epoch + 10), mesh, prefetch=1)]
+        for (x, y), (wx, wy) in zip(got, ld.epoch(epoch + 10), strict=True):
+            np.testing.assert_array_equal(x, wx)
+            np.testing.assert_array_equal(y, wy)
+    assert ld._ring._lock.acquire(timeout=5)    # the last producer let go
+    ld._ring._lock.release()
+
+
+def test_two_live_epochs_of_one_loader_do_not_share_the_ring(devices):
+    """A second epoch started while the first still runs gathers the
+    plain way; neither blocks, both are right."""
+    mesh = meshlib.data_mesh(8)
+    ld = _tagged_loader(64, 8, shuffle=True, seed=4)
+    a = pipeline.prefetch_to_mesh(ld.epoch(0), mesh)
+    b = pipeline.prefetch_to_mesh(ld.epoch(1), mesh)
+    for ga, gb, wa, wb in zip(a, b, ld.epoch(0), ld.epoch(1), strict=True):
+        np.testing.assert_array_equal(np.asarray(ga[0]), wa[0])
+        np.testing.assert_array_equal(np.asarray(gb[0]), wb[0])
+
+
+def test_loader_iterated_directly_yields_arrays_the_caller_owns(devices):
+    mesh = meshlib.data_mesh(8)
+    ld = _tagged_loader(64, 8, shuffle=True, seed=6)
+    assert iter(ld.epoch(0)) is not iter(ld.epoch(0))
+    before = list(ld.epoch(0))
+    assert ld._ring is None             # no hand-over, no ring
+    copies = [(x.copy(), y.copy()) for x, y in before]
+    list(pipeline.prefetch_to_mesh(ld.epoch(1), mesh))      # uses the ring
+    after = list(ld)                    # __iter__ is epoch 0
+    slots = [s.images for s in ld._ring.slots]
+    for i, ((x, y), (cx, cy), (ax, ay)) in enumerate(
+            zip(before, copies, after, strict=True)):
+        np.testing.assert_array_equal(x, cx)     # untouched by the ring
+        np.testing.assert_array_equal(ax, cx)
+        np.testing.assert_array_equal(ay, cy)
+        assert x.flags.owndata and ax.flags.owndata
+        for other in [b[0] for b in before[:i]] + slots:
+            assert not np.shares_memory(x, other)
+            assert not np.shares_memory(ax, other)
+
+
+def test_replaced_loader_has_a_ring_of_its_own(devices):
+    mesh = meshlib.data_mesh(8)
+    ld = _tagged_loader(64, 8, shuffle=True, seed=6)
+    list(pipeline.prefetch_to_mesh(ld.epoch(0), mesh))
+    big = ld.replace(batch_size=16, seed=7)
+    assert big._ring is None
+    got = [_host(b) for b in pipeline.prefetch_to_mesh(big.epoch(0), mesh)]
+    for (x, y), (wx, wy) in zip(got, big.epoch(0), strict=True):
+        np.testing.assert_array_equal(x, wx)
+    assert big._ring is not ld._ring
+    assert big._ring.slots[0].images.shape[0] == 16
+
+
+def test_prefetch_eval_batches_pads_the_final_partial_batch(devices):
+    """The eval pipeline stays on the plain path (its padded batches are
+    built by a generator of its own): 21 rows in batches of 8 over 8
+    devices end in 5 rows padded to 8."""
+    mesh = meshlib.data_mesh(8)
+    ld = _tagged_loader(21, 8, shuffle=False)
+    got = list(pipeline.prefetch_eval_batches(ld.ds, mesh, 8))
+    assert [size for _, _, size in got] == [8, 8, 5]
+    rows = np.concatenate([np.asarray(x)[:size] for x, _, size in got])
+    np.testing.assert_array_equal(rows, ld.ds.images)
+    x, y, _ = got[-1]
+    assert x.shape[0] == 8
+    np.testing.assert_array_equal(np.asarray(x)[5:], 0.0)
+    np.testing.assert_array_equal(np.asarray(y)[:5], ld.ds.labels[16:])
+
+
+def test_sliced_fill_equals_single_thread_fill(monkeypatch):
+    """A batch of several slices is filled by the ring's pool, row slice
+    by row slice; the pool's threads live and die with the ring."""
+    import os
+    import sys
+    import threading
+
+    ld = _tagged_loader(512, 96, shuffle=True, seed=9)
+    row_bytes = ld.ds.images[0].nbytes
+    monkeypatch.setattr(pipeline, "_FILL_SLICE_BYTES", 8 * row_bytes)
+    monkeypatch.setattr(os, "cpu_count", lambda: 16)
+    ring = pipeline._StagingRing(2, 96, ld.ds)
+    assert ring.width == 4
+    one = pipeline._StagingRing(1, 4, ld.ds)            # under one slice
+    assert one.width == 1 and one._pool is None
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for i, idx in enumerate(list(ld._index_batches(0)) * 20):
+            idx = idx[:len(idx) - i % 7]                # ragged ends too
+            x, y = ring.fill(ring.slots[i % 2], ld.ds, idx)
+            np.testing.assert_array_equal(x, ld.ds.images[idx])
+            np.testing.assert_array_equal(y, ld.ds.labels[idx])
+            x1, _ = one.fill(one.slots[0], ld.ds, idx[:4])
+            np.testing.assert_array_equal(x1, x[:4])
+    finally:
+        sys.setswitchinterval(old)
+    assert any(t.name.startswith("idc-stage") for t in threading.enumerate())
+    ring._pool.shutdown(wait=True)
+    assert not any(t.name.startswith("idc-stage")
+                   for t in threading.enumerate())

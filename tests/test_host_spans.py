@@ -6,6 +6,7 @@ Chrome export carry the thread's name. Input pipeline: `data.wait` on
 the consumer, `data.load` / `data.put` / `data.full` on the producer
 thread, `data.transfer` closed by the watcher once the batch is on the
 device — and none of it, not even the watcher thread, without a tracer.
+ISSUE 25 adds `data.recycle`, the producer's wait for a staging slot.
 Serve: `serve.refill`, `serve.start_prefill`, `serve.insert` and the
 detached `serve.turnaround` from collect's return to the next dispatch.
 """
@@ -170,6 +171,59 @@ def test_prefetch_spans_by_name_thread_and_parent(devices, tracer):
         # opened before its put's dispatch, closed after the put returned
         assert m["t_ms"] <= p["t_ms"] + 1e-3
         assert m["t_ms"] + m["dur_ms"] >= p["t_ms"] + p["dur_ms"] - 1e-3
+
+
+def test_recycle_span_once_per_batch_under_the_consumers_span(devices,
+                                                              tracer):
+    """`data.recycle`: the producer's acquisition of a ring slot, one a
+    batch (none for the end marker), parented like `data.load`; the
+    second epoch's hold the wait for the first epoch's transfers."""
+    mesh = meshlib.data_mesh(8)
+    ld = _loader()
+    for epoch in range(2):
+        with trace.span("train.epoch") as ep:
+            assert len(list(pipeline.prefetch_to_mesh(ld.epoch(epoch),
+                                                      mesh))) == 8
+        recs = _by_name(tracer.records())
+        mine = [r for r in recs["data.recycle"] if r["parent"] == ep.span_id]
+        loads = [r for r in recs["data.load"] if r["parent"] == ep.span_id]
+        assert len(mine) == 8 and len(loads) == 9
+        assert len(recs["data.recycle"]) == 8 * (epoch + 1)
+        for r, load in zip(mine, loads):
+            assert r["thread"] == "idc-prefetch"
+            assert _inside(r, recs["train.epoch"][epoch])
+            # acquired first, then filled: not inside the load
+            assert r["t_ms"] + r["dur_ms"] <= load["t_ms"] + 1e-3
+
+
+def test_no_recycle_span_without_a_ring(devices, tracer, tmp_path):
+    """Iterated directly, wrapped in a generator, or a `FileStream`: the
+    batches are the caller's or the stream's own, and nothing opens
+    `data.recycle`."""
+    from PIL import Image
+
+    mesh = meshlib.data_mesh(8)
+    ld = _loader()
+    with trace.span("train.epoch"):
+        assert len(list(ld.epoch(0))) == 8
+        assert len(list(pipeline.prefetch_to_mesh(
+            (b for b in ld.epoch(0)), mesh))) == 8
+    pairs = []
+    for i in range(16):
+        path = tmp_path / f"p{i}.png"
+        Image.fromarray(np.full((8, 8, 3), i, np.uint8)).save(path)
+        pairs.append((str(path), i % 2))
+    stream = pipeline.FileStream(pairs, 8, 8, shuffle=False, workers=2)
+    try:
+        with trace.span("train.epoch"):
+            assert len(list(pipeline.prefetch_to_mesh(stream.epoch(0),
+                                                      mesh))) == 2
+    finally:
+        stream.close()
+    recs = _by_name(tracer.records())
+    assert len(recs["data.load"]) == 9 + 3
+    assert "data.recycle" not in recs
+    assert ld._ring is None
 
 
 def test_prefetch_untraced_starts_one_thread_and_allocates_no_span(
