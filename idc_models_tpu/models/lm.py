@@ -41,7 +41,7 @@ from jax import lax
 from jax.sharding import Mesh
 
 from idc_models_tpu import mesh as meshlib
-from idc_models_tpu.models import core
+from idc_models_tpu.models import core, moe
 from idc_models_tpu.models.attention import _seq_pin, transformer_block
 from idc_models_tpu.observe import trace
 from idc_models_tpu.ring_decode import (
@@ -136,20 +136,233 @@ def next_token_loss(logits: jax.Array, tokens: jax.Array) -> jax.Array:
     return -jnp.mean(ll)
 
 
+class Rotary(NamedTuple):
+    """Rotary positions of one layer kind: rotate-half pairing over the
+    first `dims` of each head, the rest passed through. `factor` > 1
+    turns on YaRN's frequency blend (`transformers`'
+    `_compute_yarn_parameters`); `attention_factor` multiplies cos and
+    sin both."""
+    theta: float
+    dims: int
+    factor: float = 1.0
+    original_max: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+
+class LayerSpec(NamedTuple):
+    """One block of the served model, as data."""
+    heads: int                      # query heads
+    kv_heads: int                   # cached heads (heads % kv_heads == 0)
+    head_dim: int
+    window: int | None = None       # None: full causal; W: the last W
+    gate: bool = False              # per-head sigmoid gate on the output
+    rotary: Rotary | None = None
+    ffn: str = "gelu"               # "gelu" (biased MLP) | "swiglu" | "experts"
+    experts: moe.Experts | None = None
+
+
+class ModelSpec(NamedTuple):
+    """What shapes the serving programs of one model: hashable, so one
+    spec maps to one compiled program set. `attention_spec` builds
+    `attention_lm`'s instance; a path that cannot carry some other spec
+    (paged KV, int8 KV, speculative verify, the prefix cache, slot
+    migration, a sequence ring of several devices, the monolithic ring
+    prefill) refuses it by name at construction."""
+    embed_dim: int
+    layers: tuple
+    norm: str = "layernorm"         # | "rmsnorm" (scale only)
+    norm_eps: float = 1e-6
+    learned_pos: bool = True        # a trained position table params["pos"]
+    param_dtype: str = "float32"
+
+    @property
+    def classic(self) -> bool:
+        """True for `attention_lm`'s own block: what every serving path
+        was written for."""
+        return (self.norm == "layernorm" and self.learned_pos
+                and all(l.heads == l.kv_heads and l.window is None
+                        and not l.gate and l.rotary is None
+                        and l.ffn == "gelu"
+                        and l.heads * l.head_dim == self.embed_dim
+                        for l in self.layers))
+
+    def require_classic(self, mechanism: str) -> None:
+        if not self.classic:
+            raise ValueError(
+                f"{mechanism} cannot serve this layer spec: it holds one "
+                f"cache class of one head count at learned positions "
+                f"(attention_lm's block); window layers, grouped-query "
+                f"heads, rotary positions and expert layers run on the "
+                f"contiguous chunked-prefill engine only (ROADMAP "
+                f"queue B)")
+
+    def cache_len(self, i: int, t_max: int) -> int:
+        """Rows of layer i's cache: t_max, or a window layer's ring."""
+        w = self.layers[i].window
+        return t_max if w is None else min(w, t_max)
+
+
+def attention_spec(embed_dim: int, num_heads: int,
+                   num_blocks: int) -> ModelSpec:
+    """`attention_lm`'s block as a spec."""
+    if embed_dim % num_heads:
+        raise ValueError(f"embed_dim {embed_dim} not divisible by "
+                         f"num_heads {num_heads}")
+    layer = LayerSpec(num_heads, num_heads, embed_dim // num_heads)
+    return ModelSpec(embed_dim, (layer,) * num_blocks)
+
+
+def laguna_spec(config: dict, *, held: tuple | None = None,
+                param_dtype: str = "bfloat16") -> ModelSpec:
+    """The spec of a `model_type: laguna` checkpoint from its
+    `config.json` keys (poolside/Laguna-S-2.1): RMSNorm, per-layer query
+    head counts over shared KV heads, YaRN rotary on half of each head
+    in full layers and plain rotary in sliding ones, a per-head output
+    gate, a dense SwiGLU layer where `mlp_layer_types` says so and
+    softmax-routed experts with one shared expert elsewhere.
+    `held = (first, count)` is this chip's share of each expert layer
+    (default: all `num_experts`). What `config.json` does not state, and
+    this takes as read: the gate is a sigmoid of the normed hidden
+    state, the router scores by softmax, the shared expert is added
+    ungated, q and k are not normed."""
+    for key, want in (("gating", "per-head"), ("norm_topk_prob", True),
+                      ("moe_router_logit_softcapping", 0),
+                      ("moe_apply_router_weight_on_input", False),
+                      ("attention_bias", False)):
+        if config.get(key, want) != want:
+            raise ValueError(f"laguna_spec knows {key}={want!r} alone, "
+                             f"the config states {config[key]!r}")
+    d = config["head_dim"]
+    n = config["num_experts"]
+    first, count = held if held is not None else (0, n)
+    if not 0 <= first <= first + count <= n:
+        raise ValueError(f"held experts [{first}, {first + count}) lie "
+                         f"outside the router's {n}")
+    experts = moe.Experts(
+        n, config["num_experts_per_tok"], first, count,
+        routed_scale=float(config["moe_routed_scaling_factor"]),
+        shared=config.get("shared_expert_intermediate_size", 0) > 0)
+
+    def rotary(r):
+        return Rotary(float(r["rope_theta"]),
+                      int(d * r.get("partial_rotary_factor", 1)),
+                      factor=float(r.get("factor", 1.0)),
+                      original_max=int(r.get(
+                          "original_max_position_embeddings", 0)),
+                      beta_fast=float(r.get("beta_fast", 32)),
+                      beta_slow=float(r.get("beta_slow", 1)),
+                      attention_factor=float(r.get("attention_factor", 1.0)))
+
+    layers = []
+    for i in range(config["num_hidden_layers"]):
+        full = config["layer_types"][i] == "full_attention"
+        sparse = config["mlp_layer_types"][i] == "sparse"
+        layers.append(LayerSpec(
+            config["num_attention_heads_per_layer"][i],
+            config["num_key_value_heads"], d,
+            window=None if full else config["sliding_window"], gate=True,
+            rotary=rotary(config["rope_parameters"][
+                "full_attention" if full else "sliding_attention"]),
+            ffn="experts" if sparse else "swiglu",
+            experts=experts if sparse else None))
+    return ModelSpec(config["hidden_size"], tuple(layers), norm="rmsnorm",
+                     norm_eps=config["rms_norm_eps"], learned_pos=False,
+                     param_dtype=param_dtype)
+
+
+def init_params(spec: ModelSpec, vocab_size: int, rng, *,
+                seq_len: int = 0, mlp_dim: int = 0,
+                expert_dim: int = 0):
+    """Seeded random parameters for `spec`, in the tree the serving
+    programs read and in `spec.param_dtype`: projections at
+    1 / sqrt(fan in), norm scales near 1, biases (where attention_lm's
+    block has them) near 0. `seq_len` sizes a learned position table,
+    `mlp_dim` the dense feed-forward layers, `expert_dim` each routed
+    and shared expert. Traceable: jit it to make the tree in one
+    device program."""
+    dt = jnp.dtype(spec.param_dtype)
+    e = spec.embed_dim
+    keys = iter(jax.random.split(rng, 16 * len(spec.layers) + 8))
+
+    def mat(*shape):
+        return (jax.random.normal(next(keys), shape, jnp.float32)
+                / np.sqrt(shape[-2])).astype(dt)
+
+    def near(value, n):
+        return (value + 0.02 * jax.random.normal(next(keys), (n,),
+                                                 jnp.float32)).astype(dt)
+
+    def norm():
+        p = {"scale": near(1.0, e)}
+        if spec.norm == "layernorm":
+            p["bias"] = near(0.0, e)
+        return p
+
+    def swiglu(width, *lead):
+        return {"w_gate": mat(*lead, e, width), "w_up": mat(*lead, e, width),
+                "w_down": mat(*lead, width, e)}
+
+    params = {"embed": jax.random.normal(next(keys), (vocab_size, e),
+                                         jnp.float32).astype(dt)}
+    if spec.learned_pos:
+        params["pos"] = (0.02 * jax.random.normal(
+            next(keys), (seq_len, e), jnp.float32)).astype(dt)
+    for i, l in enumerate(spec.layers):
+        hd = l.heads * l.head_dim
+        mha = {"wq": mat(e, hd), "wk": mat(e, l.kv_heads * l.head_dim),
+               "wv": mat(e, l.kv_heads * l.head_dim), "wo": mat(hd, e)}
+        if l.gate:
+            mha["wg"] = mat(e, l.heads)
+        block = {"ln1": norm(), "mha": mha, "ln2": norm()}
+        if l.ffn == "gelu":
+            mha["bo"] = near(0.0, e)
+            block["fc1"] = {"kernel": mat(e, mlp_dim),
+                            "bias": near(0.0, mlp_dim)}
+            block["fc2"] = {"kernel": mat(mlp_dim, e), "bias": near(0.0, e)}
+        elif l.ffn == "swiglu":
+            block["mlp"] = swiglu(mlp_dim)
+        else:
+            x = l.experts
+            block["moe"] = {"router": mat(e, x.n_experts),
+                            "experts": swiglu(expert_dim, x.count)}
+            if x.shared:
+                block["moe"]["shared"] = swiglu(expert_dim)
+        params[f"block{i}"] = block
+    params["ln_f"] = norm()
+    params["head"] = {"kernel": mat(e, vocab_size)}
+    if spec.norm == "layernorm":
+        params["head"]["bias"] = near(0.0, vocab_size)
+    return params
+
+
 class _ServeConfig(NamedTuple):
     """Everything that shapes the compiled serving programs — and
     NOTHING that doesn't (parameters are explicit arguments, prompt
     length and step count are jit shape keys). Hashable, so one config
     maps to one compiled program set for the life of the process."""
     mesh: Mesh
-    embed_dim: int
-    num_heads: int
-    num_blocks: int
+    spec: ModelSpec
     t_max: int
     cache_dtype: object          # np.dtype (normalized, hashable)
     block_impl: str
     temperature: float
     top_k: int | None
+
+    # the classic instance's three numbers, for the paths that serve
+    # nothing else (drafter, paged pools, slot export)
+    @property
+    def embed_dim(self) -> int:
+        return self.spec.embed_dim
+
+    @property
+    def num_heads(self) -> int:
+        return self.spec.layers[0].heads
+
+    @property
+    def num_blocks(self) -> int:
+        return len(self.spec.layers)
 
 
 def _place_params(params, mesh, rules=None):
@@ -186,32 +399,53 @@ class _ServeFns(NamedTuple):
     decode_loop: object   # (params, caches, logits, rng, offsets)
     #                       -> (tokens, logits, caches)
     prefill_chunk: object  # (params, caches, tokens, start, p_end)
-    #                        -> (logits, caches)
+    #                        -> (logits, caches, router picks | ())
 
 
-def _serve_config(params, *, embed_dim, num_heads, num_blocks, t_max,
+def _serve_config(params, *, embed_dim=None, num_heads=None,
+                  num_blocks=None, spec: ModelSpec | None = None, t_max,
                   mesh, cache_dtype, block_impl="jnp",
                   temperature=0.0, top_k=None) -> _ServeConfig:
-    if embed_dim % num_heads:
-        raise ValueError(f"embed_dim {embed_dim} not divisible by "
-                         f"num_heads {num_heads}")
-    if params["pos"].shape[0] < t_max:
+    """The model comes as `attention_lm`'s three numbers or as a
+    `ModelSpec`, never both."""
+    if (spec is None) == (embed_dim is None):
+        raise ValueError("name the model once: embed_dim / num_heads / "
+                         "num_blocks (attention_lm's block) or spec=")
+    if spec is None:
+        # attention_lm's block serves whatever dtype the tree holds
+        spec = attention_spec(embed_dim, num_heads, num_blocks)._replace(
+            param_dtype=str(jnp.dtype(params["embed"].dtype)))
+    elif num_heads is not None or num_blocks is not None:
+        raise ValueError("spec= already states heads and blocks")
+    for l in spec.layers:
+        if l.heads % l.kv_heads:
+            raise ValueError(f"{l.heads} query heads do not divide over "
+                             f"{l.kv_heads} KV heads")
+        if (l.ffn == "experts") != (l.experts is not None):
+            raise ValueError("ffn='experts' comes with an Experts shape, "
+                             "and nothing else does")
+    if spec.learned_pos and params["pos"].shape[0] < t_max:
         raise ValueError(
             f"cache t_max {t_max} exceeds the trained position table "
             f"({params['pos'].shape[0]}) — positions past it have no "
             f"embedding")
+    if jnp.dtype(params["embed"].dtype) != jnp.dtype(spec.param_dtype):
+        raise ValueError(
+            f"the parameters are {jnp.dtype(params['embed'].dtype)}, the "
+            f"spec states {spec.param_dtype}")
     mesh = mesh if mesh is not None else meshlib.seq_mesh(1)
     n = mesh.shape[meshlib.SEQ_AXIS]
     if t_max % n:
         raise ValueError(f"t_max {t_max} not divisible by the ring size "
                          f"{n} over mesh axis {meshlib.SEQ_AXIS!r}")
+    if n > 1:
+        spec.require_classic(f"a sequence ring of {n} devices")
     if temperature < 0.0:
         raise ValueError(f"temperature must be >= 0, got {temperature}")
     if top_k is not None and top_k < 1:
         raise ValueError(f"top_k must be >= 1, got {top_k}")
-    return _ServeConfig(mesh, embed_dim, num_heads, num_blocks, t_max,
-                        jnp.dtype(cache_dtype), block_impl,
-                        float(temperature), top_k)
+    return _ServeConfig(mesh, spec, t_max, jnp.dtype(cache_dtype),
+                        block_impl, float(temperature), top_k)
 
 
 def _check_prompt(tokens, t_max: int):
@@ -300,40 +534,151 @@ def _make_pick(cfg: _ServeConfig):
     return pick
 
 
-def _project_qkv(cfg: _ServeConfig, ln, p, h, seq_shape: tuple):
-    """Pre-LN q/k/v projection of one block — THE single definition
+def rotary_inv_freq(rot: Rotary) -> np.ndarray:
+    """The `dims / 2` rotary frequencies of one layer kind, float64.
+    Plain: ``theta ** (-2i / dims)``. YaRN (`factor` > 1): each
+    frequency blends the plain one with the one interpolated by
+    `factor`, along a linear ramp between the dimensions that turn
+    `beta_fast` and `beta_slow` times over `original_max` positions."""
+    half = rot.dims // 2
+    i = np.arange(half, dtype=np.float64)
+    extra = rot.theta ** (-2.0 * i / rot.dims)
+    if rot.factor <= 1.0:
+        return extra
+
+    def turns_dim(turns):
+        return (rot.dims * np.log(rot.original_max / (turns * 2 * np.pi))
+                / (2 * np.log(rot.theta)))
+
+    low = max(np.floor(turns_dim(rot.beta_fast)), 0)
+    high = min(np.ceil(turns_dim(rot.beta_slow)), rot.dims - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((i - low) / (high - low), 0.0, 1.0)
+    return (extra / rot.factor) * ramp + extra * (1.0 - ramp)
+
+
+def _rope(x, pos, rot: Rotary):
+    """Rotate x [..., H, D] at integer positions `pos` (broadcastable to
+    x's leading axes): rotate-half over the first `rot.dims` of each
+    head, in float32, the remaining dims passed through."""
+    r, half = rot.dims, rot.dims // 2
+    inv = jnp.asarray(rotary_inv_freq(rot), jnp.float32)
+    ang = (jnp.broadcast_to(pos, x.shape[:-2]).astype(jnp.float32)[..., None]
+           * inv)
+    cos = (jnp.cos(ang) * rot.attention_factor)[..., None, :]
+    sin = (jnp.sin(ang) * rot.attention_factor)[..., None, :]
+    xf = x[..., :r].astype(jnp.float32)
+    x1, x2 = xf[..., :half], xf[..., half:]
+    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                          axis=-1).astype(x.dtype)
+    return jnp.concatenate([out, x[..., r:]], axis=-1) if r < x.shape[-1] \
+        else out
+
+
+def _norm(spec: ModelSpec, p, x):
+    """The spec's normalisation over the feature axis, statistics in
+    float32: LayerNorm (scale and bias) or RMSNorm (scale alone)."""
+    if spec.norm == "layernorm":
+        return core.layer_norm(spec.embed_dim,
+                               eps=spec.norm_eps).apply(p, {}, x)[0]
+    xf = x.astype(jnp.float32)
+    y = xf * lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+                       + spec.norm_eps)
+    return (y * p["scale"].astype(jnp.float32)).astype(x.dtype)
+
+
+def _project_qkv(spec: ModelSpec, l: LayerSpec, p, h, seq_shape: tuple,
+                 pos):
+    """Pre-norm q/k/v projection of one block — THE single definition
     shared by the one-token decode forward (seq_shape=(1,)), the chunk
     prefill (seq_shape=(C,)), and the monolithic ring prefill
     (seq_shape=(P',)). A dtype/bias/reshape fix lands in every path at
     once or not at all — the bit-parity contracts between them hinge on
-    this sharing."""
+    this sharing. `pos` holds the tokens' positions (broadcastable to
+    [B, *seq_shape]) for a layer with rotary positions. Returns
+    (q [.., H, D], k, v [.., G, D], gate): `gate` is the per-head output
+    gate [.., H, 1] of a gated layer, else None."""
     b = h.shape[0]
-    head_dim = cfg.embed_dim // cfg.num_heads
-    a, _ = ln.apply(p["ln1"], {}, h)
-    split = lambda y: y.reshape(b, *seq_shape, cfg.num_heads, head_dim)
-    q = split(a @ p["mha"]["wq"].astype(a.dtype))
-    k = split(a @ p["mha"]["wk"].astype(a.dtype))
-    v = split(a @ p["mha"]["wv"].astype(a.dtype))
-    return q, k, v
+    a = _norm(spec, p["ln1"], h)
+    split = lambda y, n: y.reshape(b, *seq_shape, n, l.head_dim)
+    q = split(a @ p["mha"]["wq"].astype(a.dtype), l.heads)
+    k = split(a @ p["mha"]["wk"].astype(a.dtype), l.kv_heads)
+    v = split(a @ p["mha"]["wv"].astype(a.dtype), l.kv_heads)
+    if l.rotary is not None:
+        q, k = _rope(q, pos, l.rotary), _rope(k, pos, l.rotary)
+    gate = None
+    if l.gate:
+        gate = jax.nn.sigmoid(
+            (a @ p["mha"]["wg"].astype(a.dtype)).astype(jnp.float32))
+        gate = gate.reshape(b, *seq_shape, l.heads, 1)
+    return q, k, v, gate
 
 
-def _attn_residual(p, h, o):
-    """Out-projection + residual, one definition for every path."""
-    return h + (o @ p["mha"]["wo"].astype(o.dtype)
-                + p["mha"]["bo"].astype(o.dtype))
+def _attn_residual(p, h, o, gate=None):
+    """(Gated) out-projection + residual, one definition for every
+    path: o [B, S, H, D] are the heads' outputs for h [B, E] (S = 1) or
+    [B, S, E]; a bias is added where the tree holds one."""
+    if gate is not None:
+        o = (o.astype(jnp.float32) * gate).astype(o.dtype)
+    o = o.reshape(*h.shape[:-1], -1)
+    y = o @ p["mha"]["wo"].astype(o.dtype)
+    if "bo" in p["mha"]:
+        y = y + p["mha"]["bo"].astype(o.dtype)
+    return h + y
 
 
-def _mlp_residual(ln, p, h):
-    """Pre-LN MLP + residual, one definition for every path."""
-    a, _ = ln.apply(p["ln2"], {}, h)
-    m = jax.nn.gelu(a @ p["fc1"]["kernel"] + p["fc1"]["bias"])
-    return h + (m @ p["fc2"]["kernel"] + p["fc2"]["bias"])
+def _ffn_residual(spec: ModelSpec, l: LayerSpec, p, h, live=None,
+                  interpret: bool = False):
+    """Pre-norm feed-forward + residual, one definition for every path:
+    the biased GELU MLP, a SwiGLU MLP, or the expert layer (which also
+    hands back its routing statistics over the `live` rows, [B] on
+    the one-token path; None for the others; `interpret` is `mesh.pallas_interpret` of the serving
+    mesh, for its kernel)."""
+    a = _norm(spec, p["ln2"], h)
+    if l.ffn == "gelu":
+        m = jax.nn.gelu(a @ p["fc1"]["kernel"] + p["fc1"]["bias"])
+        return h + (m @ p["fc2"]["kernel"] + p["fc2"]["bias"]), None
+    if l.ffn == "swiglu":
+        return h + moe.swiglu(p["mlp"], a), None
+    y, stats = moe.expert_ffn(p["moe"], a.reshape(-1, a.shape[-1]),
+                              l.experts, live, interpret=interpret)
+    return h + y.reshape(a.shape), stats
 
 
-def _final_logits(ln, params, h):
-    """Final LN + vocab head, one definition for every path."""
-    h, _ = ln.apply(params["ln_f"], {}, h)
-    return h @ params["head"]["kernel"] + params["head"]["bias"]
+def _layer_forward(cfg, l: LayerSpec, p, h, seq_shape, pos, attend,
+                   live=None):
+    """One block on h [B, E] (seq_shape (1,)) or [B, C, E] ((C,)):
+    projection, `attend(q, k, v) -> (o, kc, vc)` (the cache fold,
+    closed over the layer's cache), the two residuals. Returns
+    (h, kc, vc, stats)."""
+    spec = cfg.spec
+    with jax.named_scope("attn_full" if l.window is None
+                         else "attn_window"):
+        q, k, v, gate = _project_qkv(spec, l, p, h, seq_shape, pos)
+        o, kc, vc = attend(q, k, v)
+        h = _attn_residual(p, h, o, gate)
+    h, stats = _ffn_residual(spec, l, p, h, live,
+                             meshlib.pallas_interpret(cfg.mesh))
+    return h, kc, vc, stats
+
+
+def _final_logits(spec: ModelSpec, params, h):
+    """Final norm + vocab head in float32, one definition for every
+    path; a bias is added where the tree holds one."""
+    h = _norm(spec, params["ln_f"], h)
+    y = jnp.dot(h, params["head"]["kernel"],
+                preferred_element_type=jnp.float32)
+    if "bias" in params["head"]:
+        y = y + params["head"]["bias"]
+    return y
+
+
+def _embed(spec: ModelSpec, params, tok, pos_rows):
+    """Token embedding, plus the rows `pos_rows()` of the learned
+    position table where the spec has one."""
+    h = jnp.take(params["embed"], tok, axis=0)
+    return h + pos_rows() if spec.learned_pos else h
 
 
 def make_adapter_head_hook(u, v, tslot):
@@ -372,14 +717,16 @@ def make_adapter_head_hook(u, v, tslot):
     return hook
 
 
-def _token_forward(cfg: _ServeConfig, ln, params, caches, tok, pos, fold):
+def _token_forward(cfg: _ServeConfig, params, caches, tok, pos, fold,
+                   live=None):
     """One token per row through every block — the single definition of
     the decode-time forward: embed (+position), then per block
-    [pre-LN -> q/k/v projection of THIS token -> cache fold ->
-    out-projection residual -> pre-LN MLP residual], final LN, vocab
-    head. `pos` may be a scalar (serial decode: every row at the same
-    position) or an int32 [B] vector (the serving engine's per-slot
-    positions) — the position-table gather broadcasts either way.
+    [pre-norm -> q/k/v projection of THIS token -> cache fold ->
+    out-projection residual -> pre-norm feed-forward residual], final
+    norm, vocab head. `pos` may be a scalar (serial decode: every row
+    at the same position) or an int32 [B] vector (the serving engine's
+    per-slot positions) — the position-table gather and the rotary
+    angles broadcast either way.
     `fold(block_idx, kc, vc, q, k, v) -> (o, kc, vc)` supplies the
     cache fold, so the serial scalar-pos path and the engine's masked
     per-row path share every other op bit-for-bit. The fold contract
@@ -388,53 +735,56 @@ def _token_forward(cfg: _ServeConfig, ln, params, caches, tok, pos, fold):
     (`ring_decode.make_paged_batched_ring_decode`, with the table
     closed over) through the same signature — which is why paged token
     streams are bit-identical to contiguous ones on a 1-device mesh:
-    everything outside the fold IS this one definition."""
-    b = tok.shape[0]
-    h = (jnp.take(params["embed"], tok, axis=0)
-         + params["pos"][pos])                          # [B, E]
-    new_caches = []
-    for i in range(cfg.num_blocks):
-        p = params[f"block{i}"]
+    everything outside the fold IS this one definition. Returns
+    (logits, caches, stats): `stats` holds one record per expert layer
+    (models/moe.py, counted over the `live` rows) and is () for a model
+    without them."""
+    spec = cfg.spec
+    h = _embed(spec, params, tok, lambda: params["pos"][pos])   # [B, E]
+    rows = jnp.asarray(pos, jnp.int32).reshape(-1, 1)
+    new_caches, stats = [], []
+    for i, l in enumerate(spec.layers):
         kc, vc = caches[i]
-        q, k, v = _project_qkv(cfg, ln, p, h, (1,))
-        o, kc, vc = fold(i, kc, vc, q, k, v)
-        h = _attn_residual(p, h, o.reshape(b, cfg.embed_dim))
-        h = _mlp_residual(ln, p, h)
+        h, kc, vc, st = _layer_forward(
+            cfg, l, params[f"block{i}"], h, (1,), rows,
+            lambda q, k, v, _i=i, _kc=kc, _vc=vc: fold(_i, _kc, _vc, q, k, v),
+            live)
         new_caches.append((kc, vc))
-    logits = _final_logits(ln, params, h)
-    return logits, tuple(new_caches)
+        if st is not None:
+            stats.append(st)
+    logits = _final_logits(spec, params, h)
+    return logits, tuple(new_caches), tuple(stats)
 
 
-def _chunk_batch_forward(cfg: _ServeConfig, ln, params, caches, toks,
-                         pos, fold):
+def _chunk_batch_forward(cfg: _ServeConfig, params, caches, toks, pos,
+                         fold):
     """C tokens per row through every block — `_token_forward` WIDENED
     to C positions with PER-ROW start positions: the model half of the
     speculative verify program. Row b's tokens occupy global positions
     [pos[b], pos[b] + C); embedding gathers each row's slice of the
-    position table, then per block [pre-LN -> q/k/v projection of the
-    C tokens -> chunk cache fold -> out-projection residual -> pre-LN
-    MLP residual], final LN, vocab head at EVERY position (the verify
-    needs all C next-token distributions, not just the last).
-    `fold(block_idx, kc, vc, q, k, v) -> (o [B,C,H,D], kc, vc)`
+    position table, then per block [pre-norm -> q/k/v projection of the
+    C tokens -> chunk cache fold -> out-projection residual -> pre-norm
+    feed-forward residual], final norm, vocab head at EVERY position
+    (the verify needs all C next-token distributions, not just the
+    last). `fold(block_idx, kc, vc, q, k, v) -> (o [B,C,H,D], kc, vc)`
     supplies the cache fold (the batched chunk fold — contiguous or
     page-table-indirect, with liveness and positions closed over by
     the caller), so this shares every other op with
     `_token_forward`/`chunk_body` bit-for-bit — the speculative parity
     contract, paged and contiguous alike, hinges on that sharing."""
+    spec = cfg.spec
     b, c = toks.shape
-    idx = jnp.clip(pos[:, None] + jnp.arange(c, dtype=jnp.int32),
-                   0, params["pos"].shape[0] - 1)
-    h = jnp.take(params["embed"], toks, axis=0) + params["pos"][idx]
+    idx = pos[:, None] + jnp.arange(c, dtype=jnp.int32)
+    h = _embed(spec, params, toks, lambda: params["pos"][
+        jnp.clip(idx, 0, params["pos"].shape[0] - 1)])
     new_caches = []
-    for i in range(cfg.num_blocks):
-        p = params[f"block{i}"]
+    for i, l in enumerate(spec.layers):
         kc, vc = caches[i]
-        q, k, v = _project_qkv(cfg, ln, p, h, (c,))
-        o, kc, vc = fold(i, kc, vc, q, k, v)
-        h = _attn_residual(p, h, o.reshape(b, c, cfg.embed_dim))
-        h = _mlp_residual(ln, p, h)
+        h, kc, vc, _ = _layer_forward(
+            cfg, l, params[f"block{i}"], h, (c,), idx,
+            lambda q, k, v, _i=i, _kc=kc, _vc=vc: fold(_i, _kc, _vc, q, k, v))
         new_caches.append((kc, vc))
-    logits = _final_logits(ln, params, h)                # [B, C, V]
+    logits = _final_logits(spec, params, h)              # [B, C, V]
     return logits, tuple(new_caches)
 
 
@@ -451,26 +801,29 @@ def _serving_fns(cfg: _ServeConfig) -> _ServeFns:
     tests/test_lm.py::test_generator_reuses_compilation)."""
     from idc_models_tpu.ring_attention import make_ring_attention
 
-    mesh, t_max = cfg.mesh, cfg.t_max
-    head_dim = cfg.embed_dim // cfg.num_heads
-    n_ring = mesh.shape[meshlib.SEQ_AXIS]
-    # un-jitted decode fold: it is traced INTO the jitted step and the
-    # fused scan below, whose top-level jit owns donation
-    decode = make_ring_decode(mesh, jit=False)
-    ring = make_ring_attention(mesh, causal=True,
-                               block_impl=cfg.block_impl)
-    ln = core.layer_norm(cfg.embed_dim)
+    mesh, t_max, spec = cfg.mesh, cfg.t_max, cfg.spec
+    # un-jitted decode folds: traced INTO the jitted step and the fused
+    # scan below, whose top-level jit owns donation. A window layer's
+    # cache wraps (position p at row p mod W), a full layer's does not.
+    wraps = [l.window is not None for l in spec.layers]
+    decode = {w: make_ring_decode(mesh, jit=False, wrap=w)
+              for w in set(wraps)}
+    chunk_fold = {w: make_chunk_ring_decode(mesh, jit=False, wrap=w)
+                  for w in set(wraps)}
     pin = _seq_pin(mesh)
 
     def init_caches(batch: int):
-        return tuple(init_cache(mesh, batch, t_max, cfg.num_heads,
-                                head_dim, dtype=cfg.cache_dtype)
-                     for _ in range(cfg.num_blocks))
+        return tuple(init_cache(mesh, batch, spec.cache_len(i, t_max),
+                                l.kv_heads, l.head_dim,
+                                dtype=cfg.cache_dtype)
+                     for i, l in enumerate(spec.layers))
 
     def step_body(params, caches, tok, pos):
-        return _token_forward(
-            cfg, ln, params, caches, tok, pos,
-            lambda _i, kc, vc, q, k, v: decode(kc, vc, q, k, v, pos))
+        logits, caches, _ = _token_forward(
+            cfg, params, caches, tok, pos,
+            lambda i, kc, vc, q, k, v: decode[wraps[i]](kc, vc, q, k, v,
+                                                        pos))
+        return logits, caches
 
     # one dispatch per token for callers driving single steps: without
     # this, every token pays ~15 eager host-side op dispatches per
@@ -491,24 +844,28 @@ def _serving_fns(cfg: _ServeConfig) -> _ServeFns:
         # same executable: prompt length stops being a compile key.
         # Causality makes the padding exact (pad positions cannot
         # influence real ones) and the pad K/V is masked out of the
-        # cache below.
+        # cache below. The training ring knows attention_lm's block
+        # alone, so this program serves the classic spec only.
+        spec.require_classic("the monolithic ring prefill "
+                             "(prefill_chunk=None)")
+        ring = make_ring_attention(mesh, causal=True,
+                                   block_impl=cfg.block_impl)
         b, p_pad = tokens.shape
         h = (jnp.take(params["embed"], tokens, axis=0)
              + params["pos"][:p_pad])                    # [B, P', E]
         h = pin(h)
         kvs = []
-        for i in range(cfg.num_blocks):
+        for i, l in enumerate(spec.layers):
             p = params[f"block{i}"]
-            q, k, v = _project_qkv(cfg, ln, p, h, (p_pad,))
+            q, k, v, _ = _project_qkv(spec, l, p, h, (p_pad,), None)
             o = ring(q, k, v)
-            o = o.reshape(b, p_pad, cfg.embed_dim)
             h = pin(_attn_residual(p, h, o))
-            h = pin(_mlp_residual(ln, p, h))
+            h = pin(_ffn_residual(spec, l, p, h)[0])
             kvs.append((k, v))
         # last REAL position's activations — p_len is traced, so this is
         # a dynamic gather, not a static index
         h_last = lax.dynamic_slice_in_dim(h, p_len - 1, 1, axis=1)[:, 0]
-        logits = _final_logits(ln, params, h_last)
+        logits = _final_logits(spec, params, h_last)
         sh = cache_sharding(mesh)
         keep = (jnp.arange(p_pad) < p_len)[None, :, None, None]
 
@@ -523,8 +880,6 @@ def _serving_fns(cfg: _ServeConfig) -> _ServeFns:
 
     prefill = jax.jit(prefill_body)
 
-    chunk_fold = make_chunk_ring_decode(mesh, jit=False)
-
     def chunk_body(params, caches, tokens, start, p_end):
         # one prompt CHUNK through every block, consuming and extending
         # an existing ring cache: the admission-path complement of the
@@ -535,26 +890,30 @@ def _serving_fns(cfg: _ServeConfig) -> _ServeFns:
         # ragged final chunk runs the same program. Structure per block
         # mirrors `_token_forward` widened to C positions, with the
         # chunk fold (append + per-query causal attend over the whole
-        # cache + ring merge) in place of the one-token fold.
+        # cache + ring merge) in place of the one-token fold. The third
+        # result is () or, for a model with expert layers, the router's
+        # picks at the chunk's positions, [expert layers, B, C, k].
         b, c = tokens.shape
-        pos_tab = lax.dynamic_slice_in_dim(params["pos"], start, c,
-                                           axis=0)
-        h = jnp.take(params["embed"], tokens, axis=0) + pos_tab
-        new_caches = []
-        for i in range(cfg.num_blocks):
-            p = params[f"block{i}"]
+        h = _embed(spec, params, tokens,
+                   lambda: lax.dynamic_slice_in_dim(params["pos"], start,
+                                                    c, axis=0))
+        rows = (start + jnp.arange(c, dtype=jnp.int32))[None, :]
+        new_caches, picks = [], []
+        for i, l in enumerate(spec.layers):
             kc, vc = caches[i]
-            q, k, v = _project_qkv(cfg, ln, p, h, (c,))
-            o, kc, vc = chunk_fold(kc, vc, q, k, v, start, p_end)
-            h = _attn_residual(p, h, o.reshape(b, c, cfg.embed_dim))
-            h = _mlp_residual(ln, p, h)
+            h, kc, vc, st = _layer_forward(
+                cfg, l, params[f"block{i}"], h, (c,), rows,
+                lambda q, k, v, _w=wraps[i], _kc=kc, _vc=vc:
+                chunk_fold[_w](_kc, _vc, q, k, v, start, p_end))
             new_caches.append((kc, vc))
+            if st is not None:
+                picks.append(st["picks"].reshape(b, c, -1))
         # logits of the LAST REAL position in this chunk (p_end is
         # traced -> dynamic gather); intermediate chunks' logits are
         # discarded by the caller, the final chunk's seed decode
         h_last = lax.dynamic_slice_in_dim(h, p_end - start - 1, 1,
                                           axis=1)[:, 0]
-        logits = _final_logits(ln, params, h_last)
+        logits = _final_logits(spec, params, h_last)
         sh = cache_sharding(mesh)
         # pin the outgoing caches to the canonical sharding spelling so
         # chunk -> chunk -> insert chains reuse one jit cache entry per
@@ -563,7 +922,7 @@ def _serving_fns(cfg: _ServeConfig) -> _ServeFns:
             (lax.with_sharding_constraint(kc, sh),
              lax.with_sharding_constraint(vc, sh))
             for kc, vc in new_caches)
-        return logits, new_caches
+        return logits, new_caches, (jnp.stack(picks) if picks else ())
 
     prefill_chunk = jax.jit(chunk_body, donate_argnums=(1,))
 
@@ -669,8 +1028,8 @@ def chunked_prefill(fns: _ServeFns, params, tokens: np.ndarray,
         end = min(c0 + chunk, p_len)
         padded = np.zeros((b, chunk), np.int32)
         padded[:, :end - c0] = tokens[:, c0:end]
-        logits, caches = fns.prefill_chunk(params, caches, padded,
-                                           np.int32(c0), np.int32(end))
+        logits, caches, _ = fns.prefill_chunk(
+            params, caches, padded, np.int32(c0), np.int32(end))
         c0 += chunk
     return logits, caches
 
@@ -700,17 +1059,23 @@ class Generator:
     otherwise be silently dropped (`ring_decode` can only guard
     concrete positions)."""
 
-    def __init__(self, params, *, embed_dim: int, num_heads: int,
-                 num_blocks: int, t_max: int, mesh: Mesh | None = None,
+    def __init__(self, params, *, embed_dim: int | None = None,
+                 num_heads: int | None = None,
+                 num_blocks: int | None = None, t_max: int,
+                 mesh: Mesh | None = None,
                  cache_dtype=jnp.bfloat16, block_impl: str = "jnp",
                  temperature: float = 0.0, top_k: int | None = None,
                  prefill_chunk: int | None = None,
-                 partition_rules=None):
+                 partition_rules=None, spec: ModelSpec | None = None):
+        # the model is attention_lm's three numbers OR a ModelSpec
         self._cfg = _serve_config(
             params, embed_dim=embed_dim, num_heads=num_heads,
-            num_blocks=num_blocks, t_max=t_max, mesh=mesh,
+            num_blocks=num_blocks, spec=spec, t_max=t_max, mesh=mesh,
             cache_dtype=cache_dtype, block_impl=block_impl,
             temperature=temperature, top_k=top_k)
+        if prefill_chunk is None:
+            self._cfg.spec.require_classic("the monolithic ring prefill "
+                                           "(prefill_chunk=None)")
         self._fns = _serving_fns(self._cfg)
         # partition_rules shard the params over the mesh's weight axes
         # ("model"/"data" — registry.LM_RULES) while the KV caches keep

@@ -53,6 +53,63 @@ from idc_models_tpu import mesh as meshlib
 _MASKED = -1e30  # same finite sentinel as ring_attention._MASKED
 
 
+def _scores(q, kc):
+    """q.k over the cache in float32: q [B, H, D] or [B, C, H, D] against
+    kc [B, K, G, D] -> [B, H, K] or [B, H, C, K]. With G < H (grouped
+    queries) query head h reads KV head h // (H / G); the cache is
+    never repeated to H heads."""
+    h, g = q.shape[-2], kc.shape[-2]
+    if q.ndim == 3:
+        if h == g:
+            return jnp.einsum("bhd,bkhd->bhk", q, kc,
+                              preferred_element_type=jnp.float32)
+        b, _, d = q.shape
+        s = jnp.einsum("bgrd,bkgd->bgrk", q.reshape(b, g, h // g, d), kc,
+                       preferred_element_type=jnp.float32)
+        return s.reshape(b, h, kc.shape[1])
+    if h == g:
+        return jnp.einsum("bchd,bkhd->bhck", q, kc,
+                          preferred_element_type=jnp.float32)
+    b, c, _, d = q.shape
+    s = jnp.einsum("bcgrd,bkgd->bgrck", q.reshape(b, c, g, h // g, d), kc,
+                   preferred_element_type=jnp.float32)
+    return s.reshape(b, h, c, kc.shape[1])
+
+
+def _weighted(p, vc):
+    """Probabilities times cached values in float32: p [B, H, K] or
+    [B, H, C, K] against vc [B, K, G, D] -> [B, H, D] or [B, H, C, D];
+    the grouped-query counterpart of `_scores`."""
+    h, g = p.shape[1], vc.shape[-2]
+    if p.ndim == 3:
+        if h == g:
+            return jnp.einsum("bhk,bkhd->bhd", p, vc,
+                              preferred_element_type=jnp.float32)
+        b, _, k = p.shape
+        o = jnp.einsum("bgrk,bkgd->bgrd", p.reshape(b, g, h // g, k), vc,
+                       preferred_element_type=jnp.float32)
+        return o.reshape(b, h, vc.shape[-1])
+    if h == g:
+        return jnp.einsum("bhck,bkhd->bhcd", p, vc,
+                          preferred_element_type=jnp.float32)
+    b, _, c, k = p.shape
+    o = jnp.einsum("bgrck,bkgd->bgrcd", p.reshape(b, g, h // g, c, k), vc,
+                   preferred_element_type=jnp.float32)
+    return o.reshape(b, h, c, vc.shape[-1])
+
+
+def _check_wrap(wrap: bool, n: int, axis: str) -> None:
+    """A window layer's cache is a ring over POSITIONS (position p at
+    row p mod T): it lives whole on one device, so a sequence ring of
+    more than one device cannot carry it."""
+    if wrap and n > 1:
+        raise ValueError(
+            f"a window-attention cache (position p at row p mod W) "
+            f"cannot shard over a sequence ring of {n} devices on mesh "
+            f"axis {axis!r} — serve window layers on a one-device seq "
+            f"mesh")
+
+
 def cache_sharding(mesh: Mesh, axis: str = meshlib.SEQ_AXIS) -> NamedSharding:
     """[B, T_max, H, D] cache layout — identical to the training-side
     q/k/v sharding (`mesh.batch_seq_sharding`, the one construction
@@ -77,7 +134,8 @@ def init_cache(mesh: Mesh, batch: int, t_max: int, heads: int, dim: int,
 
 
 def make_ring_decode(mesh: Mesh, *, axis: str = meshlib.SEQ_AXIS,
-                     scale: float | None = None, jit: bool = True):
+                     scale: float | None = None, jit: bool = True,
+                     wrap: bool = False):
     """Build ``fn(k_cache, v_cache, q_t, k_t, v_t, pos) ->
     (out_t, k_cache, v_cache)``.
 
@@ -92,15 +150,23 @@ def make_ring_decode(mesh: Mesh, *, axis: str = meshlib.SEQ_AXIS,
     trace it into a LARGER jitted program (the LM's fused scan decode
     loop, models/lm.py) — a nested jit would discard the donation with
     a warning, and the caller's top-level jit owns donation anyway.
-    Traced callers also own the `pos` bound (see below)."""
+    Traced callers also own the `pos` bound (see below).
+
+    The cache may hold FEWER heads than the query (grouped queries:
+    q_t [B, 1, H, D] over caches of G heads, H a multiple of G). With
+    ``wrap=True`` the cache is a window layer's ring over positions:
+    its T rows hold the latest T positions, position p at row p mod T,
+    so `pos` may exceed T and every row written so far is visible
+    (one-device rings only)."""
     n = mesh.shape[axis]
+    _check_wrap(wrap, n, axis)
 
     def per_device(kc, vc, q, kt, vt, pos):
         b, t_shard, h, d = kc.shape
         i = collectives.axis_index(axis)
         scale_ = scale if scale is not None else d ** -0.5
         pos = jnp.asarray(pos, jnp.int32)
-        owner = pos // t_shard
+        owner = 0 if wrap else pos // t_shard
         slot = pos % t_shard
         # 1. append — O(1) traffic: read the ONE slot, select the new
         # token on the owner (non-owners write their existing value
@@ -119,8 +185,7 @@ def make_ring_decode(mesh: Mesh, *, axis: str = meshlib.SEQ_AXIS,
         # (preferred_element_type, NOT astype: upcasting a 64k-slot bf16
         # cache would materialize a 2x-size f32 copy per step — the MXU
         # accumulates in f32 natively, same as ring_attention's blocks)
-        s = jnp.einsum("bhd,bkhd->bhk", q[:, 0], kc,
-                       preferred_element_type=jnp.float32) * scale_
+        s = _scores(q[:, 0], kc) * scale_
         visible = (i * t_shard + jnp.arange(t_shard)) <= pos
         s = jnp.where(visible[None, None, :], s, _MASKED)
         m_loc = jnp.max(s, axis=-1)                       # [B, H]
@@ -131,8 +196,7 @@ def make_ring_decode(mesh: Mesh, *, axis: str = meshlib.SEQ_AXIS,
         # underflow
         p = jnp.where(visible[None, None, :], p, 0.0)
         l_loc = jnp.sum(p, axis=-1)                       # [B, H]
-        acc_loc = jnp.einsum("bhk,bkhd->bhd", p, vc,
-                             preferred_element_type=jnp.float32)
+        acc_loc = _weighted(p, vc)
         # 3. one stable softmax merge across the ring
         m_glob = lax.pmax(m_loc, axis)
         corr = jnp.exp(m_loc - m_glob)
@@ -188,7 +252,8 @@ def make_ring_decode(mesh: Mesh, *, axis: str = meshlib.SEQ_AXIS,
                 concrete = int(pos)
             except jax.errors.ConcretizationTypeError:
                 pass   # traced: the caller's jit/scan owns the bound
-        if concrete is not None and not (0 <= concrete < kc.shape[1]):
+        if (concrete is not None and not wrap
+                and not (0 <= concrete < kc.shape[1])):
             raise ValueError(
                 f"pos {concrete} outside the cache (t_max {kc.shape[1]})"
                 f" — grow the cache at init/prefill time; decode cannot "
@@ -201,7 +266,8 @@ def make_ring_decode(mesh: Mesh, *, axis: str = meshlib.SEQ_AXIS,
 def make_batched_ring_decode(mesh: Mesh, *, axis: str = meshlib.SEQ_AXIS,
                              scale: float | None = None,
                              jit: bool = False,
-                             quantized: bool = False):
+                             quantized: bool = False,
+                             wrap: bool = False):
     """Per-slot decode fold for the continuous-batching engine
     (serve/engine.py): ``fn(k_cache, v_cache, q_t, k_t, v_t, pos, live)
     -> (out_t, k_cache, v_cache)`` where every batch row is an
@@ -233,8 +299,15 @@ def make_batched_ring_decode(mesh: Mesh, *, axis: str = meshlib.SEQ_AXIS,
     natural "finished" frontier); positions are clamped internally for
     the attend and the masked append never fires for them. Defaults to
     ``jit=False`` because the intended caller is the engine's fused
-    decode window, whose top-level jit owns donation."""
+    decode window, whose top-level jit owns donation.
+
+    Grouped queries and ``wrap=True`` (a window layer's ring over
+    positions) behave as in `make_ring_decode`; a wrapped cache takes
+    no int8 rows."""
     n = mesh.shape[axis]
+    _check_wrap(wrap, n, axis)
+    if wrap and quantized:
+        raise ValueError("a window-attention ring cache has no int8 form")
 
     def per_device(kc, vc, q, kt, vt, pos, live, k_scale=None,
                    v_scale=None):
@@ -245,9 +318,11 @@ def make_batched_ring_decode(mesh: Mesh, *, axis: str = meshlib.SEQ_AXIS,
         live = jnp.asarray(live, jnp.bool_)
         # finished rows legitimately sit at pos == t_max; clamp so the
         # owner/slot arithmetic and visibility mask stay in range (the
-        # append is gated on `live`, never on the clamp)
-        posc = jnp.clip(pos, 0, n * t_shard - 1)
-        owner = posc // t_shard
+        # append is gated on `live`, never on the clamp). A wrapped
+        # cache's positions run past its length by design.
+        posc = (jnp.maximum(pos, 0) if wrap
+                else jnp.clip(pos, 0, n * t_shard - 1))
+        owner = 0 if wrap else posc // t_shard
         slot = posc % t_shard
         mine = (owner == i) & live
 
@@ -274,8 +349,7 @@ def make_batched_ring_decode(mesh: Mesh, *, axis: str = meshlib.SEQ_AXIS,
         vc = jax.vmap(row_append)(vc, vt, slot, mine)
         # row-wise local attend + the same stable merge as the scalar
         # fold (see make_ring_decode); visibility is per ROW now
-        s = jnp.einsum("bhd,bkhd->bhk", q[:, 0], kc,
-                       preferred_element_type=jnp.float32) * scale_
+        s = _scores(q[:, 0], kc) * scale_
         if quantized:
             # dequantize by FACTORING the per-(row, head) scale out of
             # the contraction — no float copy of the cache exists
@@ -287,8 +361,7 @@ def make_batched_ring_decode(mesh: Mesh, *, axis: str = meshlib.SEQ_AXIS,
         p = jnp.exp(s - m_loc[..., None])
         p = jnp.where(visible[:, None, :], p, 0.0)
         l_loc = jnp.sum(p, axis=-1)
-        acc_loc = jnp.einsum("bhk,bkhd->bhd", p, vc,
-                             preferred_element_type=jnp.float32)
+        acc_loc = _weighted(p, vc)
         if quantized:
             acc_loc = acc_loc * v_scale[..., None]
         m_glob = lax.pmax(m_loc, axis)
@@ -332,7 +405,7 @@ def make_batched_ring_decode(mesh: Mesh, *, axis: str = meshlib.SEQ_AXIS,
                 f"({kc.shape[0]},); got {jnp.shape(pos)}")
         # reject concrete out-of-range LIVE positions, same contract as
         # the scalar path (a silently dropped append is the failure mode)
-        if (isinstance(pos, (np.ndarray, list, tuple))
+        if (not wrap and isinstance(pos, (np.ndarray, list, tuple))
                 and isinstance(live, (np.ndarray, list, tuple))):
             p_arr = np.asarray(pos)
             bad = p_arr[(np.asarray(live)) & ((p_arr < 0)
@@ -970,7 +1043,7 @@ def make_paged_batched_chunk_ring_decode(mesh: Mesh, *, page_size: int,
 
 def make_chunk_ring_decode(mesh: Mesh, *, axis: str = meshlib.SEQ_AXIS,
                            scale: float | None = None,
-                           jit: bool = False):
+                           jit: bool = False, wrap: bool = False):
     """Chunked-prefill fold (Sarathi-style): ``fn(k_cache, v_cache, q, k,
     v, start, p_end) -> (out, k_cache, v_cache)`` runs C prompt tokens
     at once against an EXISTING ring cache — the middle ground between
@@ -1003,10 +1076,62 @@ def make_chunk_ring_decode(mesh: Mesh, *, axis: str = meshlib.SEQ_AXIS,
     start + C <= t_max (the caller sizes chunks so a chunk never hangs
     past the cache). Defaults to ``jit=False`` for tracing into the
     chunk-prefill program (models/lm.py), whose top-level jit owns
-    donation."""
+    donation.
+
+    Grouped queries behave as in `make_ring_decode`. With ``wrap=True``
+    the cache is a window layer's ring of W rows (position p at row
+    p mod W) and a query at position p sees the W positions
+    (p - W, p]: the chunk attends over the ring AS IT STOOD before the
+    chunk (its rows hold the latest positions below `start`) joined
+    with the chunk's own keys, and only then do the chunk's last W real
+    positions overwrite their rows — a chunk of W or more tokens
+    replaces the whole ring. `start` may lie anywhere below t_max; the
+    ring's length bounds nothing."""
     n = mesh.shape[axis]
+    _check_wrap(wrap, n, axis)
+
+    def per_device_wrap(kc, vc, q, kt, vt, start, p_end):
+        w = kc.shape[1]
+        c, d = q.shape[1], q.shape[-1]
+        scale_ = scale if scale is not None else d ** -0.5
+        start = jnp.asarray(start, jnp.int32)
+        p_end = jnp.asarray(p_end, jnp.int32)
+        j = jnp.arange(w, dtype=jnp.int32)
+        ci = jnp.arange(c, dtype=jnp.int32)
+        # row j holds the latest position below `start` that is
+        # congruent to j mod W — or nothing yet (a negative position)
+        held = (start - 1) - ((start - 1 - j) % w)              # [W]
+        qpos = start + ci                                       # [C]
+        see_ring = ((held >= 0)[None, :]
+                    & (held[None, :] > qpos[:, None] - w))      # [C, W]
+        see_own = ((ci[None, :] <= ci[:, None])
+                   & (ci[None, :] > ci[:, None] - w))           # [C, C]
+        visible = jnp.concatenate([see_ring, see_own], axis=1)
+        k_all = jnp.concatenate([kc, kt.astype(kc.dtype)], axis=1)
+        v_all = jnp.concatenate([vc, vt.astype(vc.dtype)], axis=1)
+        s = _scores(q, k_all) * scale_
+        s = jnp.where(visible[None, None], s, _MASKED)
+        m = jnp.max(s, axis=-1)
+        p = jnp.where(visible[None, None], jnp.exp(s - m[..., None]), 0.0)
+        out = (_weighted(p, v_all)
+               / jnp.maximum(jnp.sum(p, axis=-1), 1e-37)[..., None])
+        # the write comes last: row j takes the latest REAL position of
+        # the chunk congruent to j, when the chunk has one
+        latest = (p_end - 1) - ((p_end - 1 - j) % w)            # [W]
+        take_new = latest >= start
+        src = jnp.clip(latest - start, 0, c - 1)
+
+        def splice(cache, tok):
+            gathered = jnp.take(tok, src, axis=1).astype(cache.dtype)
+            return jnp.where(take_new[None, :, None, None], gathered,
+                             cache)
+
+        return (jnp.moveaxis(out, 1, 2).astype(q.dtype),
+                splice(kc, kt), splice(vc, vt))
 
     def per_device(kc, vc, q, kt, vt, start, p_end):
+        if wrap:
+            return per_device_wrap(kc, vc, q, kt, vt, start, p_end)
         b, t_shard, h, d = kc.shape
         c = q.shape[1]
         i = collectives.axis_index(axis)
@@ -1030,16 +1155,14 @@ def make_chunk_ring_decode(mesh: Mesh, *, axis: str = meshlib.SEQ_AXIS,
         vc = splice(vc, vt)
         # 2. per-query local attend against the resident shard
         qpos = start + jnp.arange(c, dtype=jnp.int32)         # [C]
-        s = jnp.einsum("bchd,bkhd->bhck", q, kc,
-                       preferred_element_type=jnp.float32) * scale_
+        s = _scores(q, kc) * scale_
         visible = g[None, :] <= qpos[:, None]                 # [C, t_shard]
         s = jnp.where(visible[None, None], s, _MASKED)
         m_loc = jnp.max(s, axis=-1)                           # [B, H, C]
         p = jnp.exp(s - m_loc[..., None])
         p = jnp.where(visible[None, None], p, 0.0)
         l_loc = jnp.sum(p, axis=-1)                           # [B, H, C]
-        acc_loc = jnp.einsum("bhck,bkhd->bhcd", p, vc,
-                             preferred_element_type=jnp.float32)
+        acc_loc = _weighted(p, vc)
         # 3. one stable softmax merge across the ring (per chunk, not
         # per token)
         m_glob = lax.pmax(m_loc, axis)
@@ -1071,7 +1194,7 @@ def make_chunk_ring_decode(mesh: Mesh, *, axis: str = meshlib.SEQ_AXIS,
         # concrete out-of-range starts are caller bugs, same contract as
         # the scalar fold (a chunk hanging past t_max would silently
         # drop its tail's append)
-        if isinstance(start, (int, np.integer)):
+        if not wrap and isinstance(start, (int, np.integer)):
             if not 0 <= int(start) <= kc.shape[1] - q.shape[1]:
                 raise ValueError(
                     f"chunk start {int(start)} + chunk {q.shape[1]} "

@@ -84,14 +84,18 @@ class Result:
 
 
 class LMServer:
-    """Continuous-batching server over one `attention_lm` parameter
-    tree. Construction compiles (or reuses from the process-wide cache)
+    """Continuous-batching server over one parameter tree: an
+    `attention_lm`'s (embed_dim / num_heads / num_blocks) or, with
+    `spec=` (a `models.lm.ModelSpec`), any model the one serving
+    forward expresses. Construction compiles (or reuses from the process-wide cache)
     every program the serve loop touches when `warmup=True`, so the
     first request pays no XLA latency and later requests of ANY prompt
     length/budget compile nothing (gated by test)."""
 
-    def __init__(self, params, *, embed_dim: int, num_heads: int,
-                 num_blocks: int, t_max: int, n_slots: int = 4,
+    def __init__(self, params, *, embed_dim: int | None = None,
+                 num_heads: int | None = None,
+                 num_blocks: int | None = None, t_max: int,
+                 n_slots: int = 4, spec=None,
                  window: int = 8, mesh=None, cache_dtype=None,
                  block_impl: str = "jnp", temperature: float = 0.0,
                  top_k: int | None = None, pad_id: int = 0,
@@ -138,7 +142,8 @@ class LMServer:
         # the process-wide jit cache serves both, zero new compiles)
         self._clone_cfg = dict(
             embed_dim=embed_dim, num_heads=num_heads,
-            num_blocks=num_blocks, t_max=t_max, n_slots=n_slots,
+            num_blocks=num_blocks, spec=spec, t_max=t_max,
+            n_slots=n_slots,
             window=window, mesh=mesh, cache_dtype=cache_dtype,
             block_impl=block_impl, temperature=temperature,
             top_k=top_k, pad_id=pad_id, eos_id=eos_id,
@@ -216,8 +221,8 @@ class LMServer:
         self.tenancy = tenancy
         self.engine = SlotEngine(
             params, embed_dim=embed_dim, num_heads=num_heads,
-            num_blocks=num_blocks, t_max=t_max, n_slots=n_slots,
-            mesh=mesh,
+            num_blocks=num_blocks, spec=spec, t_max=t_max,
+            n_slots=n_slots, mesh=mesh,
             cache_dtype=(jnp.bfloat16 if cache_dtype is None
                          else cache_dtype),
             block_impl=block_impl, temperature=temperature, top_k=top_k,
@@ -261,6 +266,7 @@ class LMServer:
             tenancy=tenancy)
         self._results: dict[str, Result] = {}
         self._inflight: set[str] = set()
+        self.metrics.on_kv_layout(self.engine.kv_bytes_by_kind())
         if warmup:
             self.engine.warmup(window, compile_cache=compile_cache)
         if compile_cache is not None:

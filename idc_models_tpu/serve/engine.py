@@ -65,11 +65,11 @@ import numpy as np
 from jax import lax
 
 from idc_models_tpu import mesh as meshlib
-from idc_models_tpu.models import core
+from idc_models_tpu.models import moe
 from idc_models_tpu.observe import trace
 from idc_models_tpu.models.lm import (
-    _attn_residual, _chunk_batch_forward, _final_logits, _make_pick,
-    _mlp_residual, _place_params, _project_qkv, _serve_config,
+    _attn_residual, _chunk_batch_forward, _ffn_residual, _final_logits,
+    _make_pick, _place_params, _project_qkv, _serve_config,
     _serving_fns, _token_forward, check_prefill_chunk,
     make_adapter_head_hook, prefill_bucket, prefill_buckets,
 )
@@ -216,6 +216,10 @@ def _window_core(cfg, pick, pad_id, params, caches, logits, kd, pos,
     differs), so paged token streams are bit-identical to contiguous
     ones by construction rather than by parallel maintenance.
 
+    `step_fn` returns (logits, caches, expert-layer statistics of the
+    live rows); the window's last result is their sum over its steps
+    (`moe.window_stats`), () for a model without expert layers.
+
     `eff` (None = identity) maps each step's base logits to the
     EFFECTIVE pick logits — the per-tenant adapter hook
     (models/lm.make_adapter_head_hook): the delta is applied at the
@@ -244,20 +248,23 @@ def _window_core(cfg, pick, pad_id, params, caches, logits, kd, pos,
             # serial decode loop's one split per step
             kd = jnp.where(live[:, None],
                            jax.random.key_data(pair[:, 0]), kd)
-        new_logits, caches = step_fn(params, caches, toks, pos, live)
+        new_logits, caches, stats = step_fn(params, caches, toks, pos,
+                                            live)
         logits = jnp.where(live[:, None], new_logits, logits)
         pos = jnp.where(live, pos + 1, pos)
         remaining = jnp.where(live, remaining - 1, remaining)
         hit = live & (eos >= 0) & (toks == eos)
         remaining = jnp.where(hit, 0, remaining)
-        return (caches, logits, kd, pos, remaining), toks
+        return (caches, logits, kd, pos, remaining), (toks, stats)
 
-    (caches, logits, kd, pos, remaining), toks = lax.scan(
+    (caches, logits, kd, pos, remaining), (toks, stats) = lax.scan(
         body, (caches, logits, kd, pos, remaining), None,
         length=n_steps)
     caches, logits = pin_state(caches, logits)
+    # the expert layers' account of the window (() without them): what
+    # each held expert was sent, summed over the steps on the device
     return (jnp.moveaxis(toks, 0, 1), caches, logits, kd, pos,
-            remaining)
+            remaining, moe.window_stats(stats))
 
 
 def _verify_core(cfg, pick, pad_id, K, t_max, params, caches, logits,
@@ -350,8 +357,8 @@ def _verify_core(cfg, pick, pad_id, K, t_max, params, caches, logits,
     # the bonus token's own masked step (appends at pos + m)
     bonus_live = live & (n_f == m + 1)
     bpos = jnp.clip(pos + m, 0, t_max - 1)
-    b_logits, caches = tok_forward(params, caches, b, bpos,
-                                   bonus_live)
+    b_logits, caches, _ = tok_forward(params, caches, b, bpos,
+                                      bonus_live)
     after = jnp.take_along_axis(
         cand, jnp.clip(n_f, 0, K)[:, None, None], axis=1)[:, 0]
     new_logits = jnp.where(bonus_live[:, None],
@@ -382,10 +389,12 @@ def _engine_fns(cfg, pad_id: int, quant: bool = False,
     block): insert quantizes the prefilled float caches (absmax/127 per
     head) and the window's fold dequantizes by factoring the scales out
     of the contractions — see `ring_decode.make_batched_ring_decode`."""
-    mesh, t_max = cfg.mesh, cfg.t_max
-    head_dim = cfg.embed_dim // cfg.num_heads
-    fold = make_batched_ring_decode(mesh, jit=False, quantized=quant)
-    ln = core.layer_norm(cfg.embed_dim)
+    mesh, t_max, spec = cfg.mesh, cfg.t_max, cfg.spec
+    # one fold per cache class: a window layer's rows wrap (position p
+    # at row p mod W), a full layer's do not
+    wraps = [l.window is not None for l in spec.layers]
+    folds = {w: make_batched_ring_decode(mesh, jit=False, quantized=quant,
+                                         wrap=w) for w in set(wraps)}
     pick = _make_pick(cfg)
     # the TRAILING-NONE-FREE spelling of the ring cache layout: jit
     # normalizes trailing Nones out of output PartitionSpecs, and the
@@ -413,13 +422,17 @@ def _engine_fns(cfg, pad_id: int, quant: bool = False,
         # the engine's canonical (normalized) sharding spelling; int8
         # when quantized — HALF the HBM of the bf16 rows, which is what
         # lets n_slots scale at a fixed budget
-        def mk():
+        # a layer's rows follow its spec: t_max of them, or a window
+        # layer's ring; the layer's own count of cached heads
+        def mk(i, l):
             return meshlib.put_with_sharding(
-                np.zeros((n_slots, t_max, cfg.num_heads, head_dim),
+                np.zeros((n_slots, spec.cache_len(i, t_max), l.kv_heads,
+                          l.head_dim),
                          jnp.int8 if quant
                          else jnp.dtype(cfg.cache_dtype)), cache_sh)
 
-        return tuple((mk(), mk()) for _ in range(cfg.num_blocks))
+        return tuple((mk(i, l), mk(i, l))
+                     for i, l in enumerate(spec.layers))
 
     def init_scales(n_slots: int):
         # per-(slot, head) dequant scales, one (k, v) pair per block;
@@ -436,10 +449,10 @@ def _engine_fns(cfg, pad_id: int, quant: bool = False,
     def masked_step(params, caches, tok, pos, live, scales):
         def block_fold(i, kc, vc, q, k, v):
             extra = (scales[i] if quant else ())
-            return fold(kc, vc, q, k, v, pos, live, *extra)
+            return folds[wraps[i]](kc, vc, q, k, v, pos, live, *extra)
 
-        return _token_forward(cfg, ln, params, caches, tok, pos,
-                              block_fold)
+        return _token_forward(cfg, params, caches, tok, pos, block_fold,
+                              live)
 
     def window_body(params, caches, logits, kd, pos, remaining, eos,
                     scales, adapters, tslot, n_steps):
@@ -546,18 +559,18 @@ def _engine_fns(cfg, pad_id: int, quant: bool = False,
                     return chunk_fold(kc, vc, q, k, v, pos, live,
                                       *extra)
 
-                return _chunk_batch_forward(cfg, ln, params, caches,
+                return _chunk_batch_forward(cfg, params, caches,
                                             drafts, pos,
                                             block_chunk_fold)
 
             def tok_forward(params, caches, b, bpos, bonus_live):
                 def block_tok_fold(i, kc, vc, q, k, v):
                     extra = (scales[i] if quant else ())
-                    return fold(kc, vc, q, k, v, bpos, bonus_live,
-                                *extra)
+                    return folds[False](kc, vc, q, k, v, bpos,
+                                        bonus_live, *extra)
 
-                return _token_forward(cfg, ln, params, caches, b,
-                                      bpos, block_tok_fold)
+                return _token_forward(cfg, params, caches, b, bpos,
+                                      block_tok_fold)
 
             eff = (make_adapter_head_hook(*adapters, tslot)
                    if adapters else None)
@@ -613,7 +626,6 @@ def _drafter_fns(dcfg, pad_id: int, draft_k: int) -> _DrafterFns:
     K = int(draft_k)
     fold = make_batched_ring_decode(mesh, jit=False)
     chunk_fold = make_batched_chunk_ring_decode(mesh, jit=False)
-    ln = core.layer_norm(dcfg.embed_dim)
     cache_sh = meshlib.batch_seq_sharding(mesh, trailing=0)
 
     def pin(caches):
@@ -636,7 +648,7 @@ def _drafter_fns(dcfg, pad_id: int, draft_k: int) -> _DrafterFns:
         def block_fold(i, kc, vc, q, k, v):
             return chunk_fold(kc, vc, q, k, v, pos0, live)
 
-        return _chunk_batch_forward(dcfg, ln, params, caches, toks,
+        return _chunk_batch_forward(dcfg, params, caches, toks,
                                     pos0, block_fold)
 
     def ingest_body(params, caches, toks, pos0, live):
@@ -674,8 +686,8 @@ def _drafter_fns(dcfg, pad_id: int, draft_k: int) -> _DrafterFns:
             def block_fold(i, kc, vc, q, k, v):
                 return fold(kc, vc, q, k, v, p, live)
 
-            lg2, caches = _token_forward(dcfg, ln, params, caches,
-                                         cur, p, block_fold)
+            lg2, caches, _ = _token_forward(dcfg, params, caches,
+                                            cur, p, block_fold)
             return (caches, pick_tok(lg2)), cur
 
         (caches, last), ys = lax.scan(
@@ -729,7 +741,7 @@ def _paged_engine_fns(cfg, pad_id: int, quant: bool, draft_k,
                                           jit=False, quantized=quant)
     pchunk_fold = make_paged_chunk_ring_decode(
         mesh, page_size=page_size, jit=False, quantized=quant)
-    ln = core.layer_norm(cfg.embed_dim)
+    spec = cfg.spec
     pick = _make_pick(cfg)
     pool_sh = meshlib.sharding(mesh, meshlib.SEQ_AXIS)
     rep = meshlib.replicated(mesh)
@@ -776,8 +788,7 @@ def _paged_engine_fns(cfg, pad_id: int, quant: bool, draft_k,
             extra = (scales[i] if quant else ())
             return fold(kp, vp, pt, q, k, v, pos, live, *extra)
 
-        return _token_forward(cfg, ln, params, pools, tok, pos,
-                              block_fold)
+        return _token_forward(cfg, params, pools, tok, pos, block_fold)
 
     def window_body(params, pools, pt, logits, kd, pos, remaining,
                     eos, scales, adapters, tslot, n_steps):
@@ -870,10 +881,10 @@ def _paged_engine_fns(cfg, pad_id: int, quant: bool, draft_k,
                                            axis=0)
         h = jnp.take(params["embed"], tokens, axis=0) + pos_tab
         new_pools, new_scales = [], []
-        for i in range(cfg.num_blocks):
+        for i, l in enumerate(spec.layers):
             p = params[f"block{i}"]
             kp, vp = pools[i]
-            q, k, v = _project_qkv(cfg, ln, p, h, (c,))
+            q, k, v, _ = _project_qkv(spec, l, p, h, (c,), None)
             if quant:
                 ks, vs = scales[i]
                 o, kp, vp, ks, vs = pchunk_fold(kp, vp, pt_row, q, k,
@@ -883,12 +894,12 @@ def _paged_engine_fns(cfg, pad_id: int, quant: bool, draft_k,
             else:
                 o, kp, vp = pchunk_fold(kp, vp, pt_row, q, k, v,
                                         start, p_end)
-            h = _attn_residual(p, h, o.reshape(b, c, cfg.embed_dim))
-            h = _mlp_residual(ln, p, h)
+            h = _attn_residual(p, h, o)
+            h = _ffn_residual(spec, l, p, h)[0]
             new_pools.append((kp, vp))
         h_last = lax.dynamic_slice_in_dim(h, p_end - start - 1, 1,
                                           axis=1)[:, 0]
-        logits = _final_logits(ln, params, h_last)
+        logits = _final_logits(spec, params, h_last)
         pools, logits = pin_state(tuple(new_pools), logits)
         return (logits, pools,
                 pin_scales(tuple(new_scales)) if quant else ())
@@ -918,7 +929,7 @@ def _paged_engine_fns(cfg, pad_id: int, quant: bool, draft_k,
                     return pbchunk_fold(kp, vp, pt, q, k, v, pos,
                                         live, *extra)
 
-                return _chunk_batch_forward(cfg, ln, params, pools,
+                return _chunk_batch_forward(cfg, params, pools,
                                             drafts, pos,
                                             block_chunk_fold)
 
@@ -928,7 +939,7 @@ def _paged_engine_fns(cfg, pad_id: int, quant: bool, draft_k,
                     return fold(kp, vp, pt, q, k, v, bpos, bonus_live,
                                 *extra)
 
-                return _token_forward(cfg, ln, params, pools, b, bpos,
+                return _token_forward(cfg, params, pools, b, bpos,
                                       block_tok_fold)
 
             eff = (make_adapter_head_hook(*adapters, tslot)
@@ -961,8 +972,10 @@ class SlotEngine:
     exactly), so a window costs ONE host transfer: its tokens.
     """
 
-    def __init__(self, params, *, embed_dim: int, num_heads: int,
-                 num_blocks: int, t_max: int, n_slots: int = 4,
+    def __init__(self, params, *, embed_dim: int | None = None,
+                 num_heads: int | None = None,
+                 num_blocks: int | None = None, t_max: int,
+                 n_slots: int = 4, spec=None,
                  mesh=None, cache_dtype=jnp.bfloat16,
                  block_impl: str = "jnp", temperature: float = 0.0,
                  top_k: int | None = None, pad_id: int = 0,
@@ -1050,11 +1063,26 @@ class SlotEngine:
                 "a prefix cache needs chunked prefill (prefill_chunk=C):"
                 " snapshots live on chunk boundaries and only the chunk "
                 "program can extend a cached prefix")
+        # the model: attention_lm's three numbers, or a ModelSpec
+        # (models/lm.py) — one forward serves both
         self._cfg = _serve_config(
             params, embed_dim=embed_dim, num_heads=num_heads,
-            num_blocks=num_blocks, t_max=t_max, mesh=mesh,
+            num_blocks=num_blocks, spec=spec, t_max=t_max, mesh=mesh,
             cache_dtype=cache_dtype, block_impl=block_impl,
             temperature=temperature, top_k=top_k)
+        # what a spec beyond attention_lm's block cannot express on a
+        # path makes that path refuse, here, by name — none may run
+        # and answer wrongly
+        for armed, mechanism in (
+                (self.paged, "paged KV (kv_page_size)"),
+                (self.kv_int8, "int8 KV (kv_dtype='int8')"),
+                (draft_k is not None,
+                 "speculative decoding (draft_k / spec_decode)"),
+                (prefix_cache is not None, "the prefix cache"),
+                (prefill_chunk is None, "the monolithic ring prefill "
+                                        "(prefill_chunk=None)")):
+            if armed:
+                self._cfg.spec.require_classic(mechanism)
         self.prefill_chunk = (None if prefill_chunk is None
                               else check_prefill_chunk(prefill_chunk,
                                                        t_max))
@@ -1151,9 +1179,9 @@ class SlotEngine:
         # bounds proposed ids by it, the CLI's --draft-ckpt gate
         # compares against it
         self.vocab = int(vocab)
-        # dtype only — never np.asarray the head: on a real model that
-        # is a multi-hundred-MB device→host fetch per engine build
-        ldtype = jnp.result_type(params["head"]["kernel"].dtype)
+        # the head multiplies into float32 whatever the weights are
+        # (models/lm._final_logits)
+        ldtype = jnp.float32
         rep = meshlib.replicated(self._cfg.mesh)
         # per-tenant adapter bank (serve/tenancy.py, ISSUE 14): the
         # stacked [T, V, r]/[T, r, V] logit-adapter factors, placed
@@ -1277,6 +1305,15 @@ class SlotEngine:
         # ({drafted, accepted, emitted, slots}); None after a plain
         # window — the scheduler's metrics hook reads it per collect
         self.last_spec = None
+        # expert-layer accounts (models/moe.py; None for a model without
+        # expert layers): `last_moe` is the most recently COLLECTED
+        # window's {held, touched, assigned, steps} as host arrays — the
+        # scheduler's metrics hook reads it per collect, like last_spec —
+        # and the router's picks of the last window and the last prefill
+        # chunk stay on the device until `router_picks` asks for them
+        self.last_moe = None
+        self._moe_pending = None
+        self._picks = {"window": None, "prefill": None}
         # in-progress chunked prefills: slot -> _PendingPrefill. These
         # slots are RESERVED (excluded from free_slots, not yet decoded
         # by windows) until the final chunk lands and insert scatters
@@ -1356,7 +1393,8 @@ class SlotEngine:
         rows would pass back through the insert path's quantization —
         neither can honor the bit-identity contract, so a drain on them
         finishes requests in place instead of migrating."""
-        return not self.paged and not self.kv_int8
+        return (not self.paged and not self.kv_int8
+                and self._cfg.spec.classic)
 
     def export_slot(self, slot: int) -> dict:
         """Snapshot a RUNNING slot as host numpy — the prefix
@@ -1376,6 +1414,8 @@ class SlotEngine:
         donated device state by one window, and a snapshot taken in
         that gap would pair post-window caches with pre-window
         positions."""
+        self._cfg.spec.require_classic("slot migration (export_slot / "
+                                       "import_slot)")
         if not self.supports_slot_migration:
             raise RuntimeError(
                 "slot export needs a contiguous float-KV engine: paged "
@@ -1440,6 +1480,8 @@ class SlotEngine:
         for a fresh prefill's prompt length and its remaining budget
         for max_new_tokens; the raw key data resumes the rng chain
         mid-stream."""
+        self._cfg.spec.require_classic("slot migration (export_slot / "
+                                       "import_slot)")
         if not self.supports_slot_migration:
             raise RuntimeError(
                 "slot import needs a contiguous float-KV engine (same "
@@ -1881,7 +1923,8 @@ class SlotEngine:
                     if self.kv_int8:
                         self._scales = new_scales
                 else:
-                    pend.logits, pend.caches = self._sfns.prefill_chunk(
+                    (pend.logits, pend.caches,
+                     self._picks["prefill"]) = self._sfns.prefill_chunk(
                         self._params, pend.caches, padded,
                         np.int32(pend.next_start), np.int32(end))
                 pend.next_start = end
@@ -1946,16 +1989,21 @@ class SlotEngine:
                     self._eos_h.copy())
         if self.paged:
             (toks, self._caches, self._logits, self._kd, self._pos,
-             self._rem) = self._efns.window(
+             self._rem, _) = self._efns.window(
                 self._params, self._caches, self._pt, self._logits,
                 self._kd, self._pos, self._rem, self._eos,
                 self._scales, self._adapters, self._tslot, n_steps)
         else:
             (toks, self._caches, self._logits, self._kd, self._pos,
-             self._rem) = self._efns.window(
+             self._rem, stats) = self._efns.window(
                 self._params, self._caches, self._logits, self._kd,
                 self._pos, self._rem, self._eos, self._scales,
                 self._adapters, self._tslot, n_steps)
+            if stats:
+                # handed back with the window's tokens: collect()
+                # fetches the counts, the picks stay where they are
+                self._moe_pending = dict(stats)
+                self._picks["window"] = self._moe_pending.pop("picks")
         self._pending = (toks, snapshot)
 
     def spec_room(self, slot: int) -> bool:
@@ -2161,6 +2209,7 @@ class SlotEngine:
         forever. The host budget/position shadows keep their
         pre-dispatch values (the window never 'happened')."""
         self._pending = None
+        self._moe_pending = None
 
     def collect(self) -> dict[int, list[int]]:
         """Block on the in-flight window's tokens ({} if none) and
@@ -2174,10 +2223,12 @@ class SlotEngine:
         # verify would otherwise leak a zero-slot record into the
         # first real cycle's metrics
         self.last_spec = None
+        self.last_moe = None
         if self._pending is None:
             return {}
         toks, (rem_before, occupied, eos_h), *spec = self._pending
         self._pending = None
+        moe_stats, self._moe_pending = self._moe_pending, None
         # the ONE host transfer — and the point where the serve loop
         # BLOCKS on the in-flight window's device execution, so it is
         # bracketed as device.sync for step-time attribution
@@ -2185,6 +2236,8 @@ class SlotEngine:
         # tracer is armed)
         with trace.span("device.sync"):
             toks = np.asarray(toks)
+            if moe_stats is not None:
+                self.last_moe = jax.device_get(moe_stats)
             if spec:
                 n_emit = np.asarray(spec[0][0])
                 n_acc = np.asarray(spec[0][1])
@@ -2389,7 +2442,7 @@ class SlotEngine:
         elif self.prefill_chunk is not None:
             c = self.prefill_chunk
             caches1 = self._sfns.init_caches(1)
-            logits, _ = self._sfns.prefill_chunk(
+            logits, _, _ = self._sfns.prefill_chunk(
                 placed, caches1, np.zeros((1, c), np.int32),
                 np.int32(0), np.int32(c))
         else:
@@ -2587,6 +2640,7 @@ class SlotEngine:
             "embed_dim": self._cfg.embed_dim,
             "num_heads": self._cfg.num_heads,
             "num_blocks": self._cfg.num_blocks,
+            "spec": repr(self._cfg.spec),
             "t_max": self.t_max,
             "n_slots": self.n_slots,
             "vocab": int(self._logits.shape[1]),
@@ -2788,11 +2842,11 @@ class SlotEngine:
             # two chunk steps: the first consumes init_caches' arrays,
             # the second the chunk program's own (pinned) outputs — the
             # steady-state chain every multi-chunk prompt runs
-            logits1, caches1 = self._sfns.prefill_chunk(
+            logits1, caches1, _ = self._sfns.prefill_chunk(
                 self._params, caches1, np.zeros((1, c), np.int32),
                 np.int32(0), np.int32(c))
             if 2 * c <= self.t_max:
-                logits1, caches1 = self._sfns.prefill_chunk(
+                logits1, caches1, _ = self._sfns.prefill_chunk(
                     self._params, caches1, np.zeros((1, c), np.int32),
                     np.int32(c), np.int32(2 * c))
         else:
@@ -2895,6 +2949,34 @@ class SlotEngine:
             for s in pair:
                 per += s.nbytes // self.n_slots
         return per
+
+    def kv_bytes_by_kind(self) -> dict:
+        """HBM bytes of the cache rows of all slots, by the kind of
+        layer that owns them: ``full`` (t_max rows a slot) and
+        ``window`` (a ring of W rows a slot)."""
+        out = {"full": 0, "window": 0}
+        for l, (kc, vc) in zip(self._cfg.spec.layers, self._caches):
+            out["full" if l.window is None else "window"] += (
+                kc.nbytes + vc.nbytes)
+        return out
+
+    def slot_logits(self, slot: int) -> np.ndarray:
+        """The float32 logits `slot`'s next token will be picked from
+        (after its admission: the last prompt position's; after a
+        collected window: the last emitted token's). A device fetch, for
+        checks and debugging; no serving path calls it."""
+        return np.asarray(self._logits[slot])
+
+    def router_picks(self, where: str) -> np.ndarray | None:
+        """The experts the router chose (global ids), for a model with
+        expert layers, else None: ``"window"``, the last dispatched
+        window's, [steps, expert layers, n_slots, k]; ``"prefill"``, the
+        last prefill chunk's, [expert layers, 1, chunk, k] (the rows
+        past the prompt's end are padding's). A device fetch, for
+        checks and debugging."""
+        picks = self._picks[where]
+        return (None if picks is None or isinstance(picks, tuple)
+                else np.asarray(picks))
 
     def kv_page_bytes(self) -> int:
         """HBM bytes ONE page costs across every block's K + V pools,

@@ -23,6 +23,16 @@ def _pct(values, q) -> float | None:
     return float(np.percentile(np.asarray(values, np.float64), q))
 
 
+def _load_skew(load) -> float | None:
+    """Busiest held expert's assignments over the mean held expert's,
+    mean over the expert layers (`load` [layers, held experts]); None
+    until every layer has had a token."""
+    mean = load.mean(axis=1)
+    if not (mean > 0).all():
+        return None
+    return float((load.max(axis=1) / mean).mean())
+
+
 class ServingMetrics:
     """Per-request and per-cycle serving counters.
 
@@ -212,6 +222,13 @@ class ServingMetrics:
         self._reg = reg
         self.compile_cache_summary: dict | None = None
         self._g_cc: dict | None = None
+        # expert-layer rollup (on_moe; instruments registered on its
+        # first call) and the cache rows by layer kind (on_kv_layout)
+        self._moe: dict | None = None
+        self.moe_assigned = 0
+        self.moe_touched = 0
+        self.moe_layer_steps = 0
+        self.kv_bytes_by_kind: dict = {}
         # rollout rollup: stage trail + terminal outcomes
         self.rollout_stage: str | None = None
         self.rollout_outcomes: list[str] = []
@@ -495,6 +512,69 @@ class ServingMetrics:
         self.propose_s += float(seconds)
         self.propose_calls += 1
 
+    # -- expert layers ----------------------------------------------------
+
+    def on_moe(self, *, held, touched, assigned, steps) -> None:
+        """A decode window of a model with expert layers was collected
+        (engine.last_moe, models/moe.window_stats): `held` [layers,
+        count] assignments each held expert was sent, `touched`
+        [layers] held experts with a token, summed over the window's
+        `steps` live steps, `assigned` the assignments to all experts,
+        held or absent. The instruments are registered on the first
+        call, so a server of a model without expert layers exposes
+        none of them."""
+        held = np.asarray(held, np.int64)
+        if self._moe is None:
+            reg = self._reg
+            self._moe = {
+                "load": np.zeros_like(held),
+                "assigned": reg.counter(
+                    "serve_moe_assignments_total",
+                    "token-to-expert assignments the router made in "
+                    "decode windows, over ALL experts (held here or on "
+                    "another chip)"),
+                "held": reg.counter(
+                    "serve_moe_assignments_held_total",
+                    "of those, assignments to experts this chip holds "
+                    "(the rows of the grouped expert product)"),
+                "touched": reg.gauge(
+                    "serve_moe_experts_touched",
+                    "held experts that got at least one token, mean "
+                    "over the expert layers and token steps of the "
+                    "last decode window"),
+                "skew": reg.gauge(
+                    "serve_moe_load_max_over_mean",
+                    "busiest held expert's assignments over the mean "
+                    "held expert's, mean over the expert layers, since "
+                    "the server started"),
+            }
+        m = self._moe
+        m["load"] += held
+        m["assigned"].inc(int(assigned))
+        m["held"].inc(int(held.sum()))
+        self.moe_assigned += int(assigned)
+        self.moe_touched += int(np.sum(touched))
+        self.moe_layer_steps += int(steps) * held.shape[0]
+        if steps:
+            m["touched"].set(float(np.sum(touched))
+                             / (int(steps) * held.shape[0]))
+        skew = _load_skew(m["load"])
+        if skew is not None:
+            m["skew"].set(skew)
+
+    def on_kv_layout(self, by_kind: dict) -> None:
+        """The engine's cache rows by layer kind (engine.
+        kv_bytes_by_kind), once at construction. Gauges only for a
+        model that has window layers: every other server's exposition
+        stays as it was."""
+        self.kv_bytes_by_kind = dict(by_kind)
+        if by_kind.get("window"):
+            for kind, nbytes in by_kind.items():
+                self._reg.gauge(
+                    f"serve_kv_bytes_{kind}",
+                    f"HBM bytes of the {kind}-attention layers' cache "
+                    f"rows over all slots").set(nbytes)
+
     # -- paged KV ---------------------------------------------------------
 
     def on_pages(self, *, pages_total: int, pages_used: int,
@@ -709,6 +789,22 @@ class ServingMetrics:
                                       else None),
             "serve_rollout_stage": self.rollout_stage,
         }
+        if self._moe is not None:
+            # expert-layer rollup (additive; a model with expert layers
+            # only): assignments to all experts and to the held ones,
+            # held experts touched per expert layer and token step, and
+            # the busiest held expert's load over the mean one's
+            load = self._moe["load"]
+            out["serve_moe_assignments"] = self.moe_assigned
+            out["serve_moe_assignments_held"] = int(load.sum())
+            out["serve_moe_experts_held"] = int(load.shape[1])
+            out["serve_moe_experts_touched_mean"] = (
+                self.moe_touched / self.moe_layer_steps
+                if self.moe_layer_steps else None)
+            out["serve_moe_load_max_over_mean"] = _load_skew(load)
+        if self.kv_bytes_by_kind.get("window"):
+            out["serve_kv_bytes_full"] = self.kv_bytes_by_kind["full"]
+            out["serve_kv_bytes_window"] = self.kv_bytes_by_kind["window"]
         if self.tenancy is not None:
             # per-tenant rollup (additive key, ISSUE 14): one record
             # per REGISTERED tenant — zeros included, so "tenant B was

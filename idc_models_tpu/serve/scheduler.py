@@ -878,6 +878,11 @@ class Scheduler:
             # (drafted/accepted/emitted, fetched with the tokens)
             if spec and self.metrics:
                 self.metrics.on_spec(**spec)
+            # a collected window of a model with expert layers reports
+            # what its held experts were sent (fetched with the tokens)
+            moe_stats = getattr(self.engine, "last_moe", None)
+            if moe_stats is not None and self.metrics:
+                self.metrics.on_moe(**moe_stats)
         t_now = self.clock()
         got: list[tuple[Entry, list]] = []
         finished: list[Entry] = []
