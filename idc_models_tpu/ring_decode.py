@@ -98,6 +98,97 @@ def _weighted(p, vc):
     return o.reshape(b, h, c, vc.shape[-1])
 
 
+def _fold_block(t_shard: int, target: int) -> int:
+    """Rows a frontier fold reads at a time: the largest power of two
+    that divides the shard and is no more than `target`; the whole shard
+    when it is no longer than that (one block, no loop), or when no such
+    power of two of at least 8 rows divides it."""
+    if t_shard <= target:
+        return t_shard
+    blk = 1 << (target.bit_length() - 1)
+    while t_shard % blk:
+        blk //= 2
+    return blk if blk >= 8 else t_shard
+
+
+# rows a block holds in the one-token fold and in the chunk fold, fixed
+# from chip runs of both served models (PERF.md section 6, PR 28)
+_DECODE_BLOCK = 256
+_CHUNK_BLOCK = 512
+
+
+def _decode_frontier(posc, live):
+    """One past the furthest position a live row attends (its own, just
+    appended): 0 without a live row. A dead row may sit at t_max and
+    sets nothing."""
+    return jnp.max(jnp.where(live, posc, -1)) + 1
+
+
+def _trips(frontier, row0, t_shard: int, blk: int):
+    """Blocks of `blk` rows a shard that starts at global row `row0`
+    holds below `frontier`."""
+    return (jnp.clip(frontier - row0, 0, t_shard) + blk - 1) // blk
+
+
+def _attend_to_frontier(q, kc, vc, see, frontier, blk: int, *, scale,
+                        row0=0, k_scale=None):
+    """Local attend of q [B, H, D] or [B, C, H, D] over the resident
+    shard kc / vc [B, t_shard, G, D], read in blocks of `blk` rows up to
+    `frontier` (a global row count, traced; the shard starts at global
+    row `row0`): -> float32 partials (m, l, acc) for the ring merge.
+
+    `see(g)` gives the visibility of global rows g [blk], broadcastable
+    against the scores [B, H, (C,) blk]; rows at or beyond `frontier`
+    must be invisible to every query, so leaving them unread drops only
+    terms that are exactly 0. `k_scale` (int8 caches) multiplies each
+    block's scores. A shard no longer than `blk` is ONE pass over all of
+    it with no loop, whatever the frontier: the fold as it was before
+    blocks existed, bit for bit. Otherwise blocks are merged by the
+    algebra that merges the shards of a ring: running maximum, both
+    sides rescaled by exp(m - m_new)."""
+    t_shard = kc.shape[1]
+
+    def block(kb, vb, g):
+        # f32 accumulation by preferred_element_type, NOT astype:
+        # upcasting a bf16 cache would materialize a 2x-size f32 copy
+        s = _scores(q, kb) * scale
+        if k_scale is not None:
+            s = s * k_scale
+        vis = see(g)
+        s = jnp.where(vis, s, _MASKED)
+        m = jnp.max(s, axis=-1)
+        p = jnp.exp(s - m[..., None])
+        # a fully-masked block contributes p = exp(0) = 1 garbage: zero
+        # it explicitly so the merge is exact rather than relying on the
+        # exp(_MASKED - m) == 0 underflow
+        p = jnp.where(vis, p, 0.0)
+        return m, jnp.sum(p, axis=-1), _weighted(p, vb)
+
+    rows = jnp.arange(blk, dtype=jnp.int32)
+    if t_shard <= blk:
+        return block(kc, vc, row0 + rows)
+    if t_shard % blk:
+        raise ValueError(f"block of {blk} rows does not divide the "
+                         f"shard's {t_shard}")
+    trips = _trips(frontier, row0, t_shard, blk)
+
+    def body(j, carry):
+        m, l, acc = carry
+        kb = lax.dynamic_slice_in_dim(kc, j * blk, blk, axis=1)
+        vb = lax.dynamic_slice_in_dim(vc, j * blk, blk, axis=1)
+        mb, lb, ab = block(kb, vb, row0 + j * blk + rows)
+        m_new = jnp.maximum(m, mb)
+        old, new = jnp.exp(m - m_new), jnp.exp(mb - m_new)
+        return (m_new, l * old + lb * new,
+                acc * old[..., None] + ab * new[..., None])
+
+    lead = (q.shape[0], q.shape[-2]) + q.shape[1:-2]     # [B, H, (C)]
+    init = (jnp.full(lead, _MASKED, jnp.float32),
+            jnp.zeros(lead, jnp.float32),
+            jnp.zeros(lead + (vc.shape[-1],), jnp.float32))
+    return lax.fori_loop(0, trips, body, init)
+
+
 def _check_wrap(wrap: bool, n: int, axis: str) -> None:
     """A window layer's cache is a ring over POSITIONS (position p at
     row p mod T): it lives whole on one device, so a sequence ring of
@@ -292,8 +383,17 @@ def make_batched_ring_decode(mesh: Mesh, *, axis: str = meshlib.SEQ_AXIS,
     serving slot idle through decode windows without corrupting the
     cache a recycled request will overwrite. The attend/merge algebra is
     the scalar `make_ring_decode` fold applied row-wise (same einsums,
-    same masking, same two-collective softmax merge), so a live row's
-    output is bit-identical to the scalar path at the same position.
+    same masking, same two-collective softmax merge), and the attend
+    stops at the live frontier: each device reads its shard in blocks
+    (`_fold_block`: a power of two derived from the shard's length) up
+    to one past the furthest position of a LIVE row, not all of its
+    rows (`_attend_to_frontier`; `decode_rows_read` counts them). While
+    the shard fits one block a live row's output is bit-identical to
+    the scalar path at the same position; beyond that it is equal up to
+    the float32 rounding of the block merge, the merge the ring already
+    applies across devices. A dead row's output is whatever lies below
+    the frontier (zeros when no row is live) and is the caller's to
+    discard.
 
     Rows where live=False may carry pos == t_max (one past the end, the
     natural "finished" frontier); positions are clamped internally for
@@ -348,20 +448,19 @@ def make_batched_ring_decode(mesh: Mesh, *, axis: str = meshlib.SEQ_AXIS,
         kc = jax.vmap(row_append)(kc, kt, slot, mine)
         vc = jax.vmap(row_append)(vc, vt, slot, mine)
         # row-wise local attend + the same stable merge as the scalar
-        # fold (see make_ring_decode); visibility is per ROW now
-        s = _scores(q[:, 0], kc) * scale_
-        if quantized:
-            # dequantize by FACTORING the per-(row, head) scale out of
-            # the contraction — no float copy of the cache exists
-            s = s * k_scale[:, :, None]
-        visible = ((i * t_shard + jnp.arange(t_shard))[None, :]
-                   <= posc[:, None])                       # [B, t_shard]
-        s = jnp.where(visible[:, None, :], s, _MASKED)
-        m_loc = jnp.max(s, axis=-1)                        # [B, H]
-        p = jnp.exp(s - m_loc[..., None])
-        p = jnp.where(visible[:, None, :], p, 0.0)
-        l_loc = jnp.sum(p, axis=-1)
-        acc_loc = _weighted(p, vc)
+        # fold (see make_ring_decode); visibility is per ROW now. A
+        # wrapped ring is all live once it has wrapped: one pass over
+        # it. A contiguous shard is read in blocks up to the furthest
+        # live position of the batch. int8: dequantize by FACTORING the
+        # per-(row, head) scale out of the contractions — no float copy
+        # of the cache exists
+        m_loc, l_loc, acc_loc = _attend_to_frontier(
+            q[:, 0], kc, vc,
+            lambda g: (g[None, :] <= posc[:, None])[:, None, :],
+            _decode_frontier(posc, live),
+            t_shard if wrap else _fold_block(t_shard, _DECODE_BLOCK),
+            scale=scale_, row0=i * t_shard,
+            k_scale=k_scale[:, :, None] if quantized else None)
         if quantized:
             acc_loc = acc_loc * v_scale[..., None]
         m_glob = lax.pmax(m_loc, axis)
@@ -419,6 +518,25 @@ def make_batched_ring_decode(mesh: Mesh, *, axis: str = meshlib.SEQ_AXIS,
     if not jit:
         return checked
     return jax.jit(checked, donate_argnums=(0, 1))
+
+
+def decode_rows_read(mesh: Mesh, t_max: int, pos, live, *,
+                     axis: str = meshlib.SEQ_AXIS):
+    """Cache rows one token step of the contiguous batched fold
+    (`make_batched_ring_decode`, wrap=False) reads from a layer's
+    [B, t_max, ...] keys, over every batch row and ring device: the
+    fold's own frontier and block arithmetic (int32, traced with `pos`
+    and `live`), B x t_max when each shard is one block."""
+    n = mesh.shape[axis]
+    t_shard = t_max // n
+    blk = _fold_block(t_shard, _DECODE_BLOCK)
+    pos = jnp.asarray(pos, jnp.int32)
+    if blk == t_shard:
+        return jnp.int32(pos.shape[0] * t_max)
+    frontier = _decode_frontier(jnp.clip(pos, 0, t_max - 1), live)
+    trips = sum(_trips(frontier, i * t_shard, t_shard, blk)
+                for i in range(n))
+    return (pos.shape[0] * blk * trips).astype(jnp.int32)
 
 
 def make_batched_chunk_ring_decode(mesh: Mesh, *,
@@ -1061,11 +1179,15 @@ def make_chunk_ring_decode(mesh: Mesh, *, axis: str = meshlib.SEQ_AXIS,
        O(t_shard) traffic per chunk rather than the decode fold's O(1)
        per token, but it runs once per C tokens and XLA keeps the
        rewrite in place under donation;
-    2. attends every chunk query against the WHOLE updated cache with a
-       per-query causal visibility mask (cache position <= query
-       position — which covers both the already-cached prefix and
-       causality INSIDE the chunk, since the chunk's own K/V are in the
-       cache by step 1);
+    2. attends every chunk query against the updated cache UP TO THE
+       CHUNK'S END (start + C: no query sees beyond it), read in blocks
+       (`_attend_to_frontier`), with a per-query causal visibility mask
+       (cache position <= query position — which covers both the
+       already-cached prefix and causality INSIDE the chunk, since the
+       chunk's own K/V are in the cache by step 1). A shard that fits
+       one block is one pass over all of it, as before blocks existed;
+       a longer one equals that pass up to the float32 rounding of the
+       block merge;
     3. merges across the ring with the same stable (m, l, acc) softmax
        algebra as the decode folds — two collectives per CHUNK instead
        of per token.
@@ -1153,16 +1275,15 @@ def make_chunk_ring_decode(mesh: Mesh, *, axis: str = meshlib.SEQ_AXIS,
 
         kc = splice(kc, kt)
         vc = splice(vc, vt)
-        # 2. per-query local attend against the resident shard
+        # 2. per-query local attend against the resident shard, in
+        # blocks up to the chunk's last query: nothing beyond it is
+        # visible to any of them
         qpos = start + jnp.arange(c, dtype=jnp.int32)         # [C]
-        s = _scores(q, kc) * scale_
-        visible = g[None, :] <= qpos[:, None]                 # [C, t_shard]
-        s = jnp.where(visible[None, None], s, _MASKED)
-        m_loc = jnp.max(s, axis=-1)                           # [B, H, C]
-        p = jnp.exp(s - m_loc[..., None])
-        p = jnp.where(visible[None, None], p, 0.0)
-        l_loc = jnp.sum(p, axis=-1)                           # [B, H, C]
-        acc_loc = _weighted(p, vc)
+        m_loc, l_loc, acc_loc = _attend_to_frontier(          # [B, H, C]
+            q, kc, vc,
+            lambda rows: (rows[None, :] <= qpos[:, None])[None, None],
+            start + c, _fold_block(t_shard, _CHUNK_BLOCK),
+            scale=scale_, row0=i * t_shard)
         # 3. one stable softmax merge across the ring (per chunk, not
         # per token)
         m_glob = lax.pmax(m_loc, axis)

@@ -86,8 +86,10 @@ class CompileCache:
             # schema 2: entries are donation-free twins of the jitted
             # bodies (see SlotEngine._warm_aot) — blobs serialized
             # with donated buffers replay unsoundly cross-process on
-            # CPU, so they must key out, not load
-            "schema": 2,
+            # CPU, so they must key out, not load. Schema 3: the window
+            # program returns the attention's rows read as one more
+            # result
+            "schema": 3,
             "jax": jax.__version__,
             "jaxlib": jax.lib.__version__,
             "backend": jax.default_backend(),

@@ -74,7 +74,8 @@ from idc_models_tpu.models.lm import (
     make_adapter_head_hook, prefill_bucket, prefill_buckets,
 )
 from idc_models_tpu.ring_decode import (
-    make_batched_chunk_ring_decode, make_batched_ring_decode,
+    decode_rows_read, make_batched_chunk_ring_decode,
+    make_batched_ring_decode,
     make_paged_batched_chunk_ring_decode, make_paged_batched_ring_decode,
     make_paged_chunk_ring_decode,
 )
@@ -209,7 +210,7 @@ HEALTH_KINDS = {1: "nonfinite_logits", 2: "logit_magnitude"}
 
 def _window_core(cfg, pick, pad_id, params, caches, logits, kd, pos,
                  remaining, eos, n_steps, step_fn, pin_state,
-                 eff=None):
+                 eff=None, rows_read=None):
     """THE masked fused-window scan — sampling rule, rng advance,
     budget/EOS retirement — shared verbatim by the contiguous and the
     paged engines (only `step_fn`, the per-token forward + cache fold,
@@ -217,8 +218,11 @@ def _window_core(cfg, pick, pad_id, params, caches, logits, kd, pos,
     ones by construction rather than by parallel maintenance.
 
     `step_fn` returns (logits, caches, expert-layer statistics of the
-    live rows); the window's last result is their sum over its steps
-    (`moe.window_stats`), () for a model without expert layers.
+    live rows); the window's last result but one is their sum over its
+    steps (`moe.window_stats`), () for a model without expert layers.
+    The last is the cache rows the window's attention read, summed over
+    its steps: `rows_read(pos, live)` counts one step's (the contiguous
+    engine's `ring_decode.decode_rows_read`); () without it.
 
     `eff` (None = identity) maps each step's base logits to the
     EFFECTIVE pick logits — the per-tenant adapter hook
@@ -250,21 +254,23 @@ def _window_core(cfg, pick, pad_id, params, caches, logits, kd, pos,
                            jax.random.key_data(pair[:, 0]), kd)
         new_logits, caches, stats = step_fn(params, caches, toks, pos,
                                             live)
+        rows = rows_read(pos, live) if rows_read else ()
         logits = jnp.where(live[:, None], new_logits, logits)
         pos = jnp.where(live, pos + 1, pos)
         remaining = jnp.where(live, remaining - 1, remaining)
         hit = live & (eos >= 0) & (toks == eos)
         remaining = jnp.where(hit, 0, remaining)
-        return (caches, logits, kd, pos, remaining), (toks, stats)
+        return (caches, logits, kd, pos, remaining), (toks, stats, rows)
 
-    (caches, logits, kd, pos, remaining), (toks, stats) = lax.scan(
+    (caches, logits, kd, pos, remaining), (toks, stats, rows) = lax.scan(
         body, (caches, logits, kd, pos, remaining), None,
         length=n_steps)
     caches, logits = pin_state(caches, logits)
     # the expert layers' account of the window (() without them): what
     # each held expert was sent, summed over the steps on the device
     return (jnp.moveaxis(toks, 0, 1), caches, logits, kd, pos,
-            remaining, moe.window_stats(stats))
+            remaining, moe.window_stats(stats),
+            jnp.sum(rows) if rows_read else ())
 
 
 def _verify_core(cfg, pick, pad_id, K, t_max, params, caches, logits,
@@ -454,6 +460,11 @@ def _engine_fns(cfg, pad_id: int, quant: bool = False,
         return _token_forward(cfg, params, caches, tok, pos, block_fold,
                               live)
 
+    # what one token step's attention reads of a full layer's cache (the
+    # full layers all read alike; a window layer's ring is read whole)
+    rows_read = (None if all(wraps) else functools.partial(
+        decode_rows_read, mesh, t_max))
+
     def window_body(params, caches, logits, kd, pos, remaining, eos,
                     scales, adapters, tslot, n_steps):
         # the whole window is ONE device program, like the serial fused
@@ -472,7 +483,7 @@ def _engine_fns(cfg, pad_id: int, quant: bool = False,
                else None)
         return _window_core(cfg, pick, pad_id, params, caches, logits,
                             kd, pos, remaining, eos, n_steps, step_fn,
-                            pin_state, eff=eff)
+                            pin_state, eff=eff, rows_read=rows_read)
 
     # eos (argnum 6), the dequant scales (argnum 7), the adapter bank
     # (argnum 8) and the tenant-slot ids (argnum 9) are read-only
@@ -1313,6 +1324,14 @@ class SlotEngine:
         # chunk stay on the device until `router_picks` asks for them
         self.last_moe = None
         self._moe_pending = None
+        # cache rows the most recently COLLECTED window's attention read
+        # (one full layer's, summed over its steps, on the device:
+        # ring_decode.decode_rows_read) beside the rows a window that
+        # stopped nowhere would have read; None after a verify and on a
+        # paged engine. The scheduler's metrics hook reads it per
+        # collect, like last_moe
+        self.last_attn_rows = None
+        self._rows_pending = None
         self._picks = {"window": None, "prefill": None}
         # in-progress chunked prefills: slot -> _PendingPrefill. These
         # slots are RESERVED (excluded from free_slots, not yet decoded
@@ -1989,16 +2008,19 @@ class SlotEngine:
                     self._eos_h.copy())
         if self.paged:
             (toks, self._caches, self._logits, self._kd, self._pos,
-             self._rem, _) = self._efns.window(
+             self._rem, _, _) = self._efns.window(
                 self._params, self._caches, self._pt, self._logits,
                 self._kd, self._pos, self._rem, self._eos,
                 self._scales, self._adapters, self._tslot, n_steps)
         else:
             (toks, self._caches, self._logits, self._kd, self._pos,
-             self._rem, stats) = self._efns.window(
+             self._rem, stats, rows) = self._efns.window(
                 self._params, self._caches, self._logits, self._kd,
                 self._pos, self._rem, self._eos, self._scales,
                 self._adapters, self._tslot, n_steps)
+            if not isinstance(rows, tuple):   # () without full layers
+                self._rows_pending = (
+                    rows, n_steps * self.n_slots * self.t_max)
             if stats:
                 # handed back with the window's tokens: collect()
                 # fetches the counts, the picks stay where they are
@@ -2210,6 +2232,7 @@ class SlotEngine:
         pre-dispatch values (the window never 'happened')."""
         self._pending = None
         self._moe_pending = None
+        self._rows_pending = None
 
     def collect(self) -> dict[int, list[int]]:
         """Block on the in-flight window's tokens ({} if none) and
@@ -2224,11 +2247,13 @@ class SlotEngine:
         # first real cycle's metrics
         self.last_spec = None
         self.last_moe = None
+        self.last_attn_rows = None
         if self._pending is None:
             return {}
         toks, (rem_before, occupied, eos_h), *spec = self._pending
         self._pending = None
         moe_stats, self._moe_pending = self._moe_pending, None
+        rows, self._rows_pending = self._rows_pending, None
         # the ONE host transfer — and the point where the serve loop
         # BLOCKS on the in-flight window's device execution, so it is
         # bracketed as device.sync for step-time attribution
@@ -2238,6 +2263,8 @@ class SlotEngine:
             toks = np.asarray(toks)
             if moe_stats is not None:
                 self.last_moe = jax.device_get(moe_stats)
+            if rows is not None:
+                self.last_attn_rows = (int(rows[0]), rows[1])
             if spec:
                 n_emit = np.asarray(spec[0][0])
                 n_acc = np.asarray(spec[0][1])

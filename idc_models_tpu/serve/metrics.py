@@ -228,6 +228,10 @@ class ServingMetrics:
         self.moe_assigned = 0
         self.moe_touched = 0
         self.moe_layer_steps = 0
+        # decode attention's rows read of the rows there (on_attn_rows)
+        self._m_attn_share = None
+        self.attn_rows_read = 0
+        self.attn_rows_whole = 0
         self.kv_bytes_by_kind: dict = {}
         # rollout rollup: stage trail + terminal outcomes
         self.rollout_stage: str | None = None
@@ -562,6 +566,22 @@ class ServingMetrics:
         if skew is not None:
             m["skew"].set(skew)
 
+    def on_attn_rows(self, read: int, whole: int) -> None:
+        """A decode window of the contiguous engine was collected
+        (engine.last_attn_rows): `read` cache rows of one full layer its
+        attention read, summed over the window's steps and slots, of the
+        `whole` steps x slots x t_max a fold that reads every row of
+        every slot reads. The gauge is registered on the first call."""
+        if self._m_attn_share is None:
+            self._m_attn_share = self._reg.gauge(
+                "serve_attn_read_share",
+                "cache rows the decode windows' attention read (it "
+                "stops at the furthest live position of the batch) "
+                "over all rows of all slots, since the server started")
+        self.attn_rows_read += int(read)
+        self.attn_rows_whole += int(whole)
+        self._m_attn_share.set(self.attn_rows_read / self.attn_rows_whole)
+
     def on_kv_layout(self, by_kind: dict) -> None:
         """The engine's cache rows by layer kind (engine.
         kv_bytes_by_kind), once at construction. Gauges only for a
@@ -802,6 +822,11 @@ class ServingMetrics:
                 self.moe_touched / self.moe_layer_steps
                 if self.moe_layer_steps else None)
             out["serve_moe_load_max_over_mean"] = _load_skew(load)
+        if self.attn_rows_whole:
+            # how far the decode windows' attention read (additive; the
+            # contiguous engine only): 1.0 = every row of every slot
+            out["serve_attn_read_share"] = (self.attn_rows_read
+                                            / self.attn_rows_whole)
         if self.kv_bytes_by_kind.get("window"):
             out["serve_kv_bytes_full"] = self.kv_bytes_by_kind["full"]
             out["serve_kv_bytes_window"] = self.kv_bytes_by_kind["window"]

@@ -883,6 +883,11 @@ class Scheduler:
             moe_stats = getattr(self.engine, "last_moe", None)
             if moe_stats is not None and self.metrics:
                 self.metrics.on_moe(**moe_stats)
+            # a collected contiguous window reports how far its
+            # attention read (an on-device count, fetched with the tokens)
+            attn_rows = getattr(self.engine, "last_attn_rows", None)
+            if attn_rows is not None and self.metrics:
+                self.metrics.on_attn_rows(*attn_rows)
         t_now = self.clock()
         got: list[tuple[Entry, list]] = []
         finished: list[Entry] = []
