@@ -324,10 +324,11 @@ def main():
 
     import jax
 
-    import bench
+    from idc_models_tpu.observe.profile import roofline_for
 
     dev = jax.devices()[0]
-    peak = bench._peak_tflops(dev)
+    roof = roofline_for(dev)
+    peak = roof.peak_tflops if roof else None
     bw = _peak_gbps(dev)
     print(f"device: {dev.device_kind} peak={peak} TF/s bf16, "
           f"HBM {bw} GB/s; writing {OUT}", file=sys.stderr)

@@ -51,7 +51,8 @@ OUT = Path(__file__).resolve().parent / "mfu_matrix.jsonl"
 
 
 # ---------------------------------------------------------------------------
-# generic honest timing (host-fetch fence; see bench.py module docstring)
+# generic honest timing (every window ends with a host fetch that
+# data-depends on the result)
 # ---------------------------------------------------------------------------
 
 def _timed(dispatch, fence, *, min_seconds=1.0, start_steps=20,
@@ -399,10 +400,11 @@ def main():
 
     import jax
 
-    import bench
+    from idc_models_tpu.observe.profile import roofline_for
 
     dev = jax.devices()[0]
-    peak = bench._peak_tflops(dev)
+    roof = roofline_for(dev)
+    peak = roof.peak_tflops if roof else None
     print(f"device: {dev.device_kind} peak={peak} TF/s bf16; "
           f"writing {OUT}", file=sys.stderr)
     with OUT.open("a") as f:

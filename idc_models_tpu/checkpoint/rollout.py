@@ -40,7 +40,8 @@ LMServer.run loop with rollout routing — starting the rollout a
 configurable fraction into the trace so the live baseline has real
 TTFT samples to compare against. It is the acceptance drill (zero
 dropped or duplicated requests, NaN candidate auto-rolled-back with no
-client-visible error) in one call; bench.py asserts all of it.
+client-visible error) in one call; tests/test_rollout.py asserts all
+of it.
 """
 
 from __future__ import annotations

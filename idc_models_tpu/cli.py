@@ -778,8 +778,8 @@ def _parse(argv):
                     choices=("vgg", "mobile", "dense", "small", "serve",
                              "lm"),
                     help="which hot loop to profile: a backbone's "
-                         "fine-tune train step (vgg/mobile/dense, the "
-                         "bench.py configurations; `small` is the tiny "
+                         "fine-tune train step (vgg/mobile/dense, at "
+                         "configs.BENCH_TRAIN_CONFIGS; `small` is the tiny "
                          "CPU-smoke CNN), the continuous-batching "
                          "serve decode loop, or the LM train step "
                          "(`lm` — composes with --fsdp/--tp to "
@@ -800,8 +800,8 @@ def _parse(argv):
                          "accelerator, 4 on CPU)")
     sp.add_argument("--batch-size", type=int, default=None,
                     help="per-chip batch for the train loops (default: "
-                         "the bench.py batch on an accelerator, 8 on "
-                         "CPU — match bench to compare MFU)")
+                         "the configs.BENCH_TRAIN_CONFIGS batch on an "
+                         "accelerator, 8 on CPU)")
     sp.add_argument("--path", default=None,
                     help="artifact root (profile events stream to "
                          "<path>/logs/profile.jsonl)")
@@ -1167,14 +1167,15 @@ def _run_profile(ns):
 
 
 def _profile_train_step(ns, on_accel, dev):
-    """Profile one backbone's fine-tune train step at the bench.py
-    configuration (smoke scale on CPU). Two measured passes: a
-    bench-methodology throughput window (k dispatches, ONE data-
+    """Profile one backbone's fine-tune train step at its
+    `configs.BENCH_TRAIN_CONFIGS` entry (smoke scale on CPU). Two
+    measured passes: a throughput window (k dispatches, ONE data-
     dependent fence — per-step fencing would put a host round-trip
     into every step of the MFU number) for the roofline verdict, then
-    a FENCED pass
-    (one `device.sync` fetch per `profile.step`) for the device-wait
-    vs host-gap split."""
+    a FENCED pass (one `device.sync` fetch per `profile.step`) for the
+    device-wait vs host-gap split. The step re-fed one resident batch
+    is not what `fit()` delivers: the train cells of `benchmark/`
+    measure that (PERF.md)."""
     import time
 
     import jax
@@ -1199,9 +1200,7 @@ def _profile_train_step(ns, on_accel, dev):
         cfg = dict(model=None, image=10, outputs=1, ft=None,
                    lr=1e-3, batch=64)
     else:
-        # the SAME table bench.py times against — the acceptance bar
-        # is MFU agreement with bench's independently computed figure
-        # (within 5%), so the two surfaces must share one config
+        # the one table of per-backbone profile configurations
         name = {"vgg": "vgg16", "mobile": "mobilenet_v2",
                 "dense": "densenet201"}[ns.model]
         bc = BENCH_TRAIN_CONFIGS[name]
@@ -1975,8 +1974,8 @@ def _run_lm(ns):
               f"dispatch)")
         if logger:
             # generate_ms_per_token is END-TO-END (prefill dispatch +
-            # fused decode + host fetch) / tokens — NOT the same metric
-            # as bench.py's decode_ms_per_token (pure decode window)
+            # fused decode + host fetch) / tokens — not the pace of a
+            # decode window alone
             logger.log(event="generate", tokens=toks, matches=ok,
                        generate_ms_per_token=dt * 1e3 / n_gen)
     _finish_logger(logger)
@@ -2611,8 +2610,7 @@ def _serve_body(ns, mesh, params, logger, rules=None) -> int:
                 f" window dispatches)")
         if summary.get("serve_spec_propose_s") is not None:
             # the overhead speculation pays before any win: host+device
-            # seconds spent PROPOSING (the bench states it as a % of
-            # window time — serve_spec_nonrep_draft_overhead_pct)
+            # seconds spent PROPOSING
             line += f" propose_s={summary['serve_spec_propose_s']}"
         print(line)
     if slo is not None:

@@ -99,17 +99,14 @@ PRESETS = {
 }
 
 
-# Bench/profile train-step configurations: the MEASURED-optimum
-# per-chip batches and fine-tune settings the official benchmark
-# (bench.py) times each backbone's train step at. The `profile` CLI
-# verb reads the SAME table, because its acceptance bar is MFU
-# agreement with bench's independently computed figure — re-tune a
-# batch here and both surfaces move together. (Batch provenance:
-# VGG 2048 measures ~5% above 1024, fits 16 GB HBM with the frozen
-# backward DCE'd; mobile 4096 / dense 2048 are the
-# experiments/backbone_mfu.jsonl optima. `lr` is the rate handed to
-# rmsprop — the phase-2 client rate, preset lr / 10 for the BN
-# backbones.)
+# Train-step configurations of the `profile` CLI verb and of
+# experiments/: per-chip batches and fine-tune settings for one
+# backbone's step re-fed a resident batch. (Batch provenance: chosen in
+# rounds 3-4 through a runtime that no longer exists, from
+# experiments/backbone_mfu.jsonl; not in the ledger. The benchmark's
+# train cells run `fit()` at their own batch, benchmark/configs/.
+# `lr` is the rate handed to rmsprop — the phase-2 client rate, preset
+# lr / 10 for the BN backbones.)
 BENCH_TRAIN_CONFIGS = {
     "vgg16": dict(image_size=50, num_outputs=1, fine_tune_at=15,
                   lr=1e-4, batch_per_chip=2048),

@@ -25,8 +25,10 @@ updates that triggers), so the self-healing loop, round-latency SLOs,
 `fed.client` markers, checkpoints, and `round_health` events all apply
 unchanged. Under an injected straggler plan the sync round's wall is
 max(delay) per round and its latency SLO burns; the async round's wall
-is set by the K earliest arrivals and the same SLO stays silent —
-`bench_federated_robustness` asserts both.
+is set by the K earliest arrivals and the same SLO stays silent
+(tests/test_population.py holds the walls against each other on
+injected sleeps; examples/11_slo_alerts.py the alert under a straggler
+wave).
 
 Memory: in-flight state is (arrival, client id, version) tuples plus
 one retained param snapshot per server version still referenced —

@@ -230,7 +230,7 @@ def fsdp_tp_mesh(fsdp: int = 1, tp: int = 1, seq: int = 1) -> Mesh:
     design. Don't compare wall-clock against an all-devices
     `data_seq_mesh` run: the device counts differ; the sharded-config
     comparisons this mesh exists for are per-device CAPACITY
-    (peak_hbm_bytes) and same-mesh step time (bench_lm_sharded)."""
+    (peak_hbm_bytes) and same-mesh step time."""
     for name, v in (("fsdp", fsdp), ("tp", tp), ("seq", seq)):
         if v < 1:
             raise ValueError(f"{name} degree must be >= 1, got {v}")
@@ -313,7 +313,7 @@ def initialize_multihost(coordinator: str | None = None,
     reference never runs multi-node (SURVEY.md §4); here multi-host is
     first-class — after this call, `jax.devices()` spans the pod and every
     mesh built above rides ICI within a host and DCN across hosts.
-    No-ops when running single-process (e.g. tests, single-chip bench).
+    No-ops when running single-process (e.g. tests, a single-chip run).
     """
     if num_processes is None and coordinator is None:
         return  # single-process

@@ -172,11 +172,12 @@ def depthwise_conv2d(features: int, kernel_size: int | tuple = 3, *,
       A depthwise conv has no channel contraction, so there is nothing
       for the MXU's systolic array to reduce — this formulation hands
       XLA the pure-VPU form directly: kh*kw strided slices of one
-      padded copy of x, fused into one elementwise loop. Measured
-      (experiments/backbone_mfu.jsonl, MobileNetV2 fine-tune on TPU
-      v5e): the native grouped lowering WINS — 234k vs 138k patches/s
-      at batch 2048 — so "grouped" stays the default and "taps" remains
-      as the measured ablation that closed the question.
+      padded copy of x, fused into one elementwise loop. Measured in
+      round 4 through a runtime that no longer exists (not in the
+      ledger; experiments/backbone_mfu.jsonl, one MobileNetV2 step
+      re-fed a resident batch of 2048 on TPU v5e): the native grouped
+      lowering WINS — 234k vs 138k patches/s — so "grouped" stays the
+      default; "taps" is queued for deletion (ROADMAP.md C4).
     - "fused": the Pallas kernel (ops/fused_conv.py) — the taps math
       computed on a VMEM-resident tile (interpreted off-TPU, so the
       same code path runs in tier-1 on CPU). Standalone it runs with an

@@ -116,8 +116,7 @@ def _units(in_channels: int, bn_frozen_below: int,
       static channel ranges.)
     - "concat": the literal `concat(h, f(h))` — the parity reference
       the packed path is pinned bit-close against
-      (tests/test_fused_conv.py) and the bench_backbone_fused
-      baseline. Not for production use: it re-materializes the whole
+      (tests/test_fused_conv.py). Not for production use: it re-materializes the whole
       growing feature map every layer.
     """
     if block_impl not in ("packed", "concat"):

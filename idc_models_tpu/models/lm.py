@@ -828,8 +828,9 @@ def _serving_fns(cfg: _ServeConfig) -> _ServeFns:
     # one dispatch per token for callers driving single steps: without
     # this, every token pays ~15 eager host-side op dispatches per
     # block around the cache fold — each a host dispatch, together
-    # swamping the 0.15-0.35 ms device floor the decode bench
-    # measures. Caches are donated (a serving loop only ever holds the
+    # swamping the device's own time for the step (0.15-0.35 ms on a
+    # v5 lite chip in round 4, through a runtime that no longer exists;
+    # not in the ledger). Caches are donated (a serving loop only ever holds the
     # returned ones).
     step = jax.jit(step_body, donate_argnums=(1,))
 

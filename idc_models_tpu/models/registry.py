@@ -131,8 +131,8 @@ def get_model(name: str) -> ModelSpec:
 
 
 # ISSUE 16: the one place defining what "fused backbone" means per
-# model, so bench.py (bench_backbone_fused), the profile verb, and
-# experiments/fused_backbone.py build the same variants. For
+# model, so the profile verb, experiments/fused_backbone.py and
+# tests/test_fused_conv.py build the same variants. For
 # mobilenet the fused Pallas depthwise chain is OPT-IN (default
 # "grouped" until the TPU perf gate holds — ISSUE 16 acceptance);
 # for densenet the concat-free packed blocks ARE the default (parity

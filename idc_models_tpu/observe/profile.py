@@ -2,8 +2,8 @@
 roofline verdicts, and a compile-churn watchdog (ISSUE 9).
 
 The framework could MEASURE (PR 5 tracer/metrics) but not EXPLAIN: why
-is MobileNet at MFU 0.14 while VGG hits 0.62 (BENCH_r05)? Is a step
-compute-bound or bandwidth-bound, is the chip idling on host gaps, is
+does one backbone's step reach a quarter of another's utilization? Is a
+step compute-bound or bandwidth-bound, is the chip idling on host gaps, is
 something recompiling every call? This module turns the substrate into
 answers, in four pieces:
 
@@ -34,9 +34,8 @@ answers, in four pieces:
    the device fraction is a lower bound — documented, not hidden.)
 
 3. **Roofline verdicts** — `BACKEND_ROOFS` maps device_kind
-   substrings to (peak bf16 TFLOP/s, peak HBM GB/s), seeded from the
-   tables bench.py and experiments/backbone_mfu.py measured against
-   (both now delegate here). `roofline_verdict(cost, step_seconds)`
+   substrings to (peak bf16 TFLOP/s, peak HBM GB/s): the published
+   peaks, the one table `experiments/` reads too. `roofline_verdict(cost, step_seconds)`
    combines (1) + a measured step time into compute-bound vs
    bandwidth-bound with achieved-fraction-of-roof numbers. Unknown
    backends (CPU) verdict "unknown" unless `register_roof` (CLI:
@@ -57,8 +56,9 @@ answers, in four pieces:
 
 The `profile` CLI verb (cli.py) drives all four over any subsystem's
 hot loop and writes frozen-schema `profile_program`/`profile_step`
-jsonl events; `bench_profile_overhead` (bench.py) holds the armed
-cost under the house <2%-of-a-decode-window bar.
+jsonl events. What arming costs a decode cycle (one enabled
+`device.sync` span and one `naming_compiles` context) has not been
+measured on the chip; no test gates it.
 """
 
 from __future__ import annotations
@@ -468,10 +468,8 @@ class RooflineSpec:
         return self.peak_tflops * 1e12 / (self.peak_hbm_gbps * 1e9)
 
 
-# device_kind substring -> roof; longest matching key wins. Seeded from
-# the tables bench.py (_PEAK_BF16_TFLOPS) and
-# experiments/backbone_mfu.py (_PEAK_HBM_GBPS) measured against — both
-# now read THIS table.
+# device_kind substring -> roof; longest matching key wins. The
+# published peaks; experiments/ reads THIS table.
 BACKEND_ROOFS: dict[str, RooflineSpec] = {
     k: RooflineSpec(k, tf, bw) for k, tf, bw in (
         ("v2", 46.0, 700.0),

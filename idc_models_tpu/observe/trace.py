@@ -21,8 +21,12 @@ jsonl logs. Two export formats:
 
 The DISABLED mode is the production default and must cost ~nothing:
 `span()` with no active tracer returns a shared no-op handle — one
-global read, no allocation beyond the caller's kwargs. `bench.py`
-(`bench_tracer_overhead`) gates this on the serve decode hot loop.
+global read, no allocation beyond the caller's kwargs. On the chip
+(PERF.md §6, PR 24: the driver's parent-against-change runs) no cell
+moved with the tracer's call sites in and the tracer off; installed, the
+serve cells lose 1.4-3.1% of their tokens/s and the train cells
+nothing. No test times this: a tracer that got expensive shows in the
+serve cells' `tpot_p50_ms` and `setup_s`.
 
 Instrumented call sites use the module-level helper:
 
@@ -299,7 +303,7 @@ def get_tracer() -> Tracer | None:
 def span(name: str, parent=None, **attrs):
     """A span on the active tracer — or the shared no-op handle when
     tracing is disabled. THE instrumentation entry point for every hot
-    path; its disabled cost is gated by `bench_tracer_overhead`.
+    path (what it costs disabled and installed: the module docstring).
     `parent` (a span id) is used when the opening thread has no open
     span of its own — see `Tracer.span`."""
     tr = _ACTIVE

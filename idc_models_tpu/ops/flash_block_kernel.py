@@ -19,13 +19,13 @@ sentinel, same self-healing first-block property); the causal mask is
 reconstructed inside the kernel from two scalar offsets (global q / kv
 block starts) — no mask tensor is built or shipped.
 
-Measured on one TPU v5 lite chip (causal, B=1 H=8 D=64 bf16, ring of 1
-so t_local == T; 20 chained calls per timing window so per-call
-dispatch overhead is amortized out): t_local=4096
-1.07x (6.2 vs 6.7 ms/call), 8192 1.41x (10.2 vs 14.4 ms), 16384
-1.44-1.62x across rounds (25.5-38.4 vs ~41-55 ms; the shared chip
-drifts +/-10%, so bench.py records best AND median every round rather
-than a single headline) — the jnp path's t_local^2 f32 score tensor goes
+Measured in rounds 2-5 on one TPU v5 lite chip through a runtime that
+no longer exists (not in the ledger; no cell of `benchmark/` runs this
+kernel, so nothing has timed it since; causal, B=1 H=8 D=64 bf16, ring
+of 1 so t_local == T; 20 chained calls per timing window):
+t_local=4096 1.07x (6.2 vs 6.7 ms/call), 8192 1.41x (10.2 vs 14.4 ms),
+16384 1.44-1.62x across rounds (25.5-38.4 vs ~41-55 ms on a shared chip
+that drifted +/-10%) — the jnp path's t_local^2 f32 score tensor goes
 HBM-bound exactly where the fused kernel keeps scores in VMEM. The
 kernel is the right choice once t_local reaches the many-thousands;
 `block_impl="jnp"` stays the default for the moderate blocks typical
@@ -50,9 +50,10 @@ Gradients come in two tiers:
   TRAINING at long local blocks keeps the memory win — gated by a
   jaxpr test asserting no [t_local, t_local] intermediate exists.
 
-  Measured fwd+bwd on the v5 lite chip (causal, B=1 H=8 D=64 bf16,
-  ring of 1, chained-call amortization; `experiments/
-  flash_bwd_bench.py`): t_local=4096 19.6 vs 20.8 ms (1.06x), 8192
+  Measured fwd+bwd on the v5 lite chip in round 4, through a runtime
+  that no longer exists (not in the ledger; causal, B=1 H=8 D=64 bf16,
+  ring of 1, chained-call amortization; the flash-backward script
+  under `experiments/`): t_local=4096 19.6 vs 20.8 ms (1.06x), 8192
   32.8 vs 31.2 ms (0.95x) — time parity — and at 16384 the jnp path's
   f32 score tensor (8.6 GB, x2-3 live for autodiff) FAILS TPU
   compilation outright while the flash backward trains at 50.9 ms.

@@ -4,9 +4,10 @@ MobileNetV2's hot chains lower as three separate XLA ops — depthwise
 conv, batchnorm, relu6 — each materializing the full activation tensor
 in HBM between them. A depthwise conv does ~9 FLOPs per activation
 byte (no channel contraction, nothing for the MXU to reduce), so every
-unfused boundary roughly doubles the bytes per useful FLOP; the PR 14
-MFU attribution (docs/BENCHMARKS.md) measured the whole train step at
-arithmetic intensity 3.5 vs the v5e ridge of ~240 and named these
+unfused boundary roughly doubles the bytes per useful FLOP; XLA's cost
+account of the compiled step (`profile --model mobile`, PR 14: FLOPs
+over bytes) puts the whole train step at arithmetic intensity 3.5
+against the v5e ridge of ~240 and named these
 chains as the implicated lowering. This kernel keeps the activation
 tile in VMEM across all three ops: one grid cell loads an image's
 padded activation once, runs the kh*kw shifted multiply-accumulates

@@ -29,9 +29,10 @@ threefry below it and off-TPU. The round DEFAULT remains threefry
 because the masks are a privacy primitive and this hash PRG is not
 cryptographic (see make_secure_fedavg_round's threat-model note) —
 "auto" buys throughput where that trade is acceptable.
-The crossover is measured, not assumed
-(`experiments/mask_crossover.jsonl`, sweep with dispatch amortized
-inside one jit on a v5 lite chip): the fused pass never loses —
+The crossover was measured in round 4, through a runtime that no longer
+exists (not in the ledger; `experiments/mask_crossover.jsonl`, sweep
+with dispatch amortized inside one jit on a v5 lite chip): the fused
+pass never lost —
 1.04x at 262k elements, 1.48x at 4.2M, 1.89x at VGG16's 14.7M, 2.48x
 at 33.5M — but below the threshold the absolute win (~0.1 ms) is
 noise while the round pays one kernel call per local client, and

@@ -17,8 +17,10 @@ beyond-parity capability, designed TPU-first):
   tile alive per ring step on the default jnp block path (the blockwise
   tiling is across devices, not within a block). When local blocks grow
   long, pass ``block_impl="pallas"``: the fused flash kernel
-  (`ops.flash_block_kernel`) keeps scores in VMEM — measured 1.41x at
-  T/n=8k and 1.62x at 16k on a v5 lite chip. Either way a sequence n
+  (`ops.flash_block_kernel`) keeps scores in VMEM — 1.41x at T/n=8k
+  and 1.44-1.62x at 16k on a v5 lite chip in rounds 2-5, through a
+  runtime that no longer exists (not in the ledger: no cell of
+  `benchmark/` runs this path). Either way a sequence n
   times longer than one device could hold attends exactly.
   Comm/compute overlap within a step (the hop and the block attend read
   the same kc and are independent) is left to XLA's async collectives —
@@ -40,10 +42,12 @@ always-visible hi-vs-lo quarter; the lo-vs-hi quarter is provably empty
 and never computed), then exactly two fully-visible half-attends per
 ring hop. Total causal work drops from 4n quarter-blocks per device to
 2n+1 — the ~2x the contiguous docstring used to concede. Measured on a
-v5 lite chip (emulated ring-of-8 per-device schedule, pallas blocks,
-`experiments/zigzag_bench.py`): 1.52x at t_local=4096, 1.74x at 8192,
-1.76x at 16384 vs the contiguous schedule (ideal 4n/(2n+1) = 1.88x at
-n=8); the executed-FLOP ratio is gated by an XLA-cost-analysis test.
+v5 lite chip in round 4, through a runtime that no longer exists (not
+in the ledger; emulated ring-of-8 per-device schedule on ONE chip,
+pallas blocks, the zigzag script under `experiments/`): 1.52x at
+t_local=4096, 1.74x at 8192, 1.76x at 16384 vs the contiguous schedule
+(ideal 4n/(2n+1) = 1.88x at n=8); the executed-FLOP ratio is gated by
+an XLA-cost-analysis test.
 Without `causal` the layout changes nothing (dense attention is
 permutation-equivariant), so zigzag only matters for causal runs.
 

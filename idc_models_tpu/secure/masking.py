@@ -154,9 +154,10 @@ def first_fraction_selection(tree, percent: float,
 
 
 # Auto-selection threshold for the fused Pallas mask kernel (secure
-# fedavg mask_impl="auto"): measured on a v5 lite chip with dispatch
-# overhead amortized INSIDE one jit (experiments/mask_crossover.jsonl),
-# the fused kernel never loses — 1.04x at 262k elements rising to 2.48x
+# fedavg mask_impl="auto"): measured in round 4 on a v5 lite chip,
+# through a runtime that no longer exists (not in the ledger), with
+# dispatch overhead amortized INSIDE one jit
+# (experiments/mask_crossover.jsonl), the fused kernel never lost — 1.04x at 262k elements rising to 2.48x
 # at 33.5M — but below ~4M elements the win is ~0.1 ms (noise) while
 # the round path pays one kernel call per local client; above it the
 # win is >=1.5x of a cost that actually matters. Off-TPU, interpret

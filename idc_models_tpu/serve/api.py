@@ -100,8 +100,7 @@ class LMServer:
                  block_impl: str = "jnp", temperature: float = 0.0,
                  top_k: int | None = None, pad_id: int = 0,
                  eos_id: int | None = None, max_queue_depth: int = 64,
-                 max_prefills_per_cycle: int = 1,
-                 admit_after_collect: bool = True, logger=None,
+                 max_prefills_per_cycle: int = 1, logger=None,
                  warmup: bool = True, clock=time.monotonic,
                  prefill_chunk: int | None = None,
                  prefix_cache_mb: float = 0.0,
@@ -148,8 +147,7 @@ class LMServer:
             block_impl=block_impl, temperature=temperature,
             top_k=top_k, pad_id=pad_id, eos_id=eos_id,
             max_queue_depth=max_queue_depth,
-            max_prefills_per_cycle=max_prefills_per_cycle,
-            admit_after_collect=admit_after_collect, clock=clock,
+            max_prefills_per_cycle=max_prefills_per_cycle, clock=clock,
             prefill_chunk=prefill_chunk, kv_dtype=kv_dtype,
             spec_decode=spec_decode, draft_k=draft_k,
             draft_order=draft_order, drafter=drafter,
@@ -259,7 +257,6 @@ class LMServer:
         self.scheduler = Scheduler(
             self.engine, window=window, max_queue_depth=max_queue_depth,
             max_prefills_per_cycle=max_prefills_per_cycle,
-            admit_after_collect=admit_after_collect,
             metrics=self.metrics, clock=clock, retry=retry,
             fault_plan=fault_plan, health_checks=health_checks,
             journal=journal, brownout=brownout, drafter=drafter,

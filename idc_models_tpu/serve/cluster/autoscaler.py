@@ -36,7 +36,7 @@ bounded by                   ``min_replicas`` <= fleet <= ``max_replicas``
 `Autoscaler` wraps the pure function with the state threading and a
 frozen-schema ``autoscale_decision`` jsonl event per ACTION (holds are
 silent — drills replay the decision stream, not a heartbeat), which is
-what `bench_serving_elastic` and the drain drills assert against.
+what tests/test_elastic.py's drills assert against.
 """
 
 from __future__ import annotations

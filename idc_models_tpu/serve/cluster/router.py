@@ -1251,7 +1251,7 @@ class Router:
         """The cluster rollup: pooled per-request aggregates over
         every replica (serve/metrics.aggregate_summaries), the
         router's own counters, and the prefix registry's — the record
-        `bench_serving_cluster` and the CLI epilogue report."""
+        the CLI epilogue reports."""
         out = aggregate_summaries([r.server.metrics
                                    for r in self.replicas])
         # replica-level sheds (a straggling direct submit refused by a

@@ -14,7 +14,7 @@ operator's single pane:
   ``_total``, and per-tenant fleet totals. Because the rollups are
   sums over the very series the same exposition carries, "fleet rollup
   == sum of per-replica series" holds by construction at every
-  instant, which is exactly what the bench gate asserts.
+  instant, which is what tests/test_fleet_observability.py asserts.
 - `ClusterTelemetry.health()` is the fleet /healthz: every replica's
   health document embedded verbatim, plus fleet aggregates, the
   cluster SLO engine's state, the autoscaler's live hysteresis clocks
@@ -260,7 +260,7 @@ class ClusterWatchdog:
     (``replica`` null for fleet-wide kinds) — to the logger, bumps
     ``cluster_anomalies_total{kind}``, and records it in
     `self.anomalies`. `check()` returns the records fired by THAT
-    call, so a bench gate can assert fire-on-fault / silent-on-clean
+    call, so a drill can assert fire-on-fault / silent-on-clean
     directly."""
 
     KINDS = ("accept_collapse", "compile_churn", "migration_spike",

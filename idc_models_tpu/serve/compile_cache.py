@@ -1,8 +1,9 @@
 """Persistent compiled-program cache — warm replica spin-up.
 
-BENCH_r06 prices what a cold replica pays before it serves a single
-token: minutes of XLA compilation for programs this process (or a
-sibling) has compiled before. This module makes that cost durable-once
+A cold replica pays XLA compilation before it serves a single token,
+for programs this process (or a sibling) has compiled before (on the
+chip the `gpt2-large` cells' first set-up takes 155-191 s against
+35-38 s once compiled: PERF.md §6, PR 22, through jax's own cache). This module makes that cost durable-once
 per (program, config, mesh, toolchain): `SlotEngine._warm_aot` lowers
 each fixed-shape serve program AOT, and the resulting executable is
 serialized to disk (`jax.experimental.serialize_executable`); the next
@@ -38,8 +39,7 @@ store, which skips tracing/lowering too.
 
 Counters (hits/misses/stores/evictions, deserialize + compile seconds)
 feed the `serve_compile_cache_*` gauges (serve/metrics.py) and the
-`stats` CLI rollup, so warm-vs-cold is visible in the epilogue, not
-just in bench_serving_elastic.
+`stats` CLI rollup, so warm-vs-cold is visible in the epilogue.
 """
 
 from __future__ import annotations
@@ -61,7 +61,7 @@ class CompileCache:
     ready-to-call Compiled (hit) or None (miss); `compile_and_store()`
     finishes a miss by compiling the caller's Lowered and persisting
     the result. All counters are cumulative for the life of this
-    handle — `summary()` is what metrics/bench read."""
+    handle — `summary()` is what serve/metrics.py reads."""
 
     def __init__(self, path, *, logger=None):
         self.path = Path(path)
@@ -165,7 +165,7 @@ class CompileCache:
         return exe
 
     def summary(self) -> dict:
-        """The frozen-schema rollup metrics and bench read."""
+        """The frozen-schema rollup serve/metrics.py reads."""
         return {
             "hits": self.hits,
             "misses": self.misses,

@@ -92,7 +92,7 @@ class RequestJournal:
         this cycle ({rid: cumulative tokens}), written every
         `progress_every` calls — the stride and the batching keep the
         journal's clean-path cost to a fraction of a jsonl line per
-        cycle (bench_serving_resilience prices it)."""
+        cycle."""
         if not tokens_by_rid:
             return
         self._progress_skips += 1

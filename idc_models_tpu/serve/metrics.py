@@ -5,7 +5,8 @@ Counters accumulate in memory and stream — when a logger is given —
 through the same `observe.JsonlLogger` jsonl record shape every other
 loop in the framework writes, so a serving run's timeline sits next to
 its training runs' in one machine-comparable format. `summary()` is the
-record `bench.py` embeds in the official JSON line (`serve_*` fields).
+serving record (`serve_*` fields) the CLI epilogue prints and the
+benchmark's per-layer readers take their counts from.
 """
 
 from __future__ import annotations
@@ -662,7 +663,7 @@ class ServingMetrics:
         call registers the serve_compile_cache_* gauges (lazily — see
         `_g_cc`), every call re-reads `cache.summary()` into them and
         the rollup, so warm-vs-cold spin-up is visible in the `stats`
-        epilogue, not just in bench_serving_elastic."""
+        epilogue."""
         if self._g_cc is None:
             reg = self._reg
             self._g_cc = {
@@ -691,7 +692,7 @@ class ServingMetrics:
     def summary(self) -> dict:
         """The serving scenario record: aggregate throughput over the
         span from first submit to last finish, TTFT percentiles, and
-        mean queue/occupancy — the `serve_*` fields bench.py reports."""
+        mean queue/occupancy — the `serve_*` fields."""
         span = ((self._t_last - self._t_first)
                 if self._t_first is not None and self._t_last is not None
                 else None)
@@ -871,8 +872,7 @@ def _r(v, scale) -> float | None:
 
 def aggregate_summaries(metrics_list) -> dict:
     """The CLUSTER rollup over N replicas' `ServingMetrics` — the
-    record the router's `summary()` reports and `bench_serving_cluster`
-    compares across replica counts.
+    record the router's `summary()` reports.
 
     Percentiles are computed over the POOLED per-request samples (every
     replica's raw ttft/queue-wait lists concatenated), never by

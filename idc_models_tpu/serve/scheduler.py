@@ -213,8 +213,7 @@ class Scheduler:
 
     def __init__(self, engine, *, window: int = 8, max_queue_depth: int = 64,
                  max_prefills_per_cycle: int = 1, metrics=None,
-                 admit_after_collect: bool = True, clock=time.monotonic,
-                 retry=None, fault_plan=None,
+                 clock=time.monotonic, retry=None, fault_plan=None,
                  health_checks: bool | None = None, journal=None,
                  brownout=None, drafter=None, tenancy=None):
         if window < 1:
@@ -255,8 +254,8 @@ class Scheduler:
         self.health_checks = bool(health_checks)
         self._retrying: list[Entry] = []
         # cumulative wall seconds spent in the drafting pass (host
-        # scans + the learned drafter's batched device dispatch) — the
-        # numerator of the bench's draft-overhead-percent key
+        # scans + the learned drafter's batched device dispatch):
+        # summary()'s serve_spec_propose_s
         self.propose_seconds = 0.0
         self._cycle = 0
         self._closed = False
@@ -269,11 +268,6 @@ class Scheduler:
         # exhaustion this cycle, consumed (and cleared) by the
         # brownout evaluation — ISSUE 11's exhaustion -> brownout wire
         self._page_pressure = False
-        # refill slots the just-collected window freed before the next
-        # window dispatches (recycle idles one window, not two) — at the
-        # price of those prefills sitting in the device-idle gap instead
-        # of overlapping the in-flight window
-        self.admit_after_collect = admit_after_collect
         self.clock = clock
         self._running: dict[int, Entry] = {}
         # chunked-prefill engines: entries whose prompt is still being
@@ -823,7 +817,7 @@ class Scheduler:
         # no-recompile contract says NONE after warmup — is recorded
         # under this name; with no watchdog it is the shared no-op
         # handle (one module-global read, same cost class as a
-        # disabled trace span; charged in bench_profile_overhead)
+        # disabled trace span)
         with trace.span("serve.admit") as _sp, \
                 prof.naming_compiles("serve.admit"):
             try:
@@ -927,27 +921,25 @@ class Scheduler:
         #    prefill dispatches sit squarely in the device-idle gap, so
         #    its host time joins the measured decode stall (on a
         #    monolithic engine THIS is where recycle-refill prefills
-        #    land — omitting it would understate the baseline stall the
-        #    chunked-vs-monolithic bench comparison reports)
-        if self.admit_after_collect:
-            t_pf2 = self.clock()
-            try:
-                with trace.span("serve.refill") as _sp:
-                    n2 = self._admit_free_slots()
-                    _sp.set(admitted=n2)
-                admitted += n2
-            except Exception as e:
-                self._end_turnaround(turnaround, False, e)
-                # same salvage as a begin_window failure: the entries
-                # the just-collected window completed are real results
-                # — finalize them (and the step-1 expiries) into the
-                # pop_failed channel before aborting the rest
-                self._finalize_window(got, finished, cancelled, t_now,
-                                      now, self._failed)
-                self._failed.extend(done)
-                self._abort_running(e)
-                raise
-            prefill_stall_s += self.clock() - t_pf2
+        #    land)
+        t_pf2 = self.clock()
+        try:
+            with trace.span("serve.refill") as _sp:
+                n2 = self._admit_free_slots()
+                _sp.set(admitted=n2)
+            admitted += n2
+        except Exception as e:
+            self._end_turnaround(turnaround, False, e)
+            # same salvage as a begin_window failure: the entries
+            # the just-collected window completed are real results
+            # — finalize them (and the step-1 expiries) into the
+            # pop_failed channel before aborting the rest
+            self._finalize_window(got, finished, cancelled, t_now,
+                                  now, self._failed)
+            self._failed.extend(done)
+            self._abort_running(e)
+            raise
+        prefill_stall_s += self.clock() - t_pf2
         # 5.5 paged engines: grow page grants so every running slot
         #     can emit the next dispatch's worth of tokens; slots the
         #     pool cannot cover even after prefix-cache reclaim are
@@ -1008,8 +1000,7 @@ class Scheduler:
                             # the decode-window leg of each rid's
                             # lifecycle chain — the list is built only
                             # when a tracer is armed (disabled-path
-                            # cost stays one global read, gated by
-                            # bench_tracer_overhead)
+                            # cost stays one global read)
                             _wsp.set(rids=[e.rid for e
                                            in self._running.values()])
                         self.engine.begin_window(self.window)
@@ -1123,7 +1114,7 @@ class Scheduler:
         batch. Host drafters keep the per-slot scan. Either way, every
         proposal flows through the `_check_proposal` choke point, and
         the wall time of the whole drafting pass accrues to
-        `propose_seconds` (the bench's draft-overhead key)."""
+        `propose_seconds` (summary()'s `serve_spec_propose_s`)."""
         eng = self.engine
         k = eng.draft_k
         # room check FIRST, across every slot: one slot without room
@@ -1359,7 +1350,7 @@ class Scheduler:
         if progress:
             # one batched (and journal-strided) record per cycle — the
             # per-slot-per-cycle write pattern was the armed clean
-            # path's dominant cost (bench_serving_resilience)
+            # path's dominant cost
             self.journal.record_progress(progress)
         for e in finished:
             e.status, e.t_done = "ok", t_now

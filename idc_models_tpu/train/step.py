@@ -98,15 +98,13 @@ FOLLOW = "follow"
 
 
 def jit_data_parallel(step_fn, mesh: Mesh, *, donate_state: bool = True,
-                      extra_batch_args: int = 0, axis: str | None = None,
-                      state_shardings=None):
+                      axis: str | None = None, state_shardings=None):
     """Jit `step_fn(state, images, labels, *rest)` with DP shardings.
 
-    State replicated; images/labels (and `extra_batch_args` further
-    positional args) sharded on their leading axis over `axis` (default:
-    the mesh's "data" axis, or its only axis when 1-D — so eval works on
-    a "client" mesh too). This is the whole MirroredStrategy replacement
-    for D1.
+    State replicated; images/labels sharded on their leading axis over
+    `axis` (default: the mesh's "data" axis, or its only axis when 1-D —
+    so eval works on a "client" mesh too). This is the whole
+    MirroredStrategy replacement for D1.
 
     `state_shardings` overrides the state pin: a NamedSharding pytree
     (from `partition.PartitionRules.shardings`, resolved over the full
@@ -126,8 +124,7 @@ def jit_data_parallel(step_fn, mesh: Mesh, *, donate_state: bool = True,
         state_sh = (None if isinstance(state_shardings, str)
                     and state_shardings == FOLLOW else state_shardings)
     batch = meshlib.sharding(mesh, _batch_axis(mesh, axis))
-    n_batch = 2 + extra_batch_args
-    in_shardings = (state_sh,) + (batch,) * n_batch
+    in_shardings = (state_sh, batch, batch)
     # Pin the RETURNED state to the same layout as the input state:
     # without this, GSPMD may shard an updated param over whatever axis
     # its gradient arrived on (e.g. a positional embedding over "seq"

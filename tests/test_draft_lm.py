@@ -7,10 +7,11 @@ state — the ChainedDrafter composition rules, and the teaching errors
 at every misuse point (malformed `propose()` returns at the
 scheduler's one validation choke point, engine construction misfits).
 
-The drafter is deliberately left UNTRAINED in the serve tests: the
+The drafter is deliberately left UNTRAINED in most serve tests: the
 verify program makes any drafter sound, so parity/recompile gates must
-hold regardless of draft quality (bench.py's non-repetitive bench owns
-the accept-rate-with-a-TRAINED-drafter story).
+hold regardless of draft quality. One test trains it
+(test_distilled_drafter_is_accepted_where_lookup_is_inert): the
+accept-rate-with-a-TRAINED-drafter story on text that never repeats.
 """
 
 import jax
@@ -193,6 +194,87 @@ def test_learned_spec_parity_and_zero_recompile(devices, params,
         assert got is not None and got.status == "ok"
         want = _serial_tokens(gen, r.prompt, r.max_new_tokens)
         assert got.tokens == want, (r.id, got.tokens, want)
+
+
+def test_distilled_drafter_is_accepted_where_lookup_is_inert(tmp_path):
+    """Text that never repeats: a full-period LCG (next = 5 tok + 3 mod
+    vocab) visits every token once before it returns, so in a stream
+    shorter than the vocabulary no trailing n-gram has occurred before
+    and the lookup drafter has nothing to propose (it drafts at most 2%
+    of the tokens). A student distilled from the target's own greedy
+    streams, through the checkpoint round trip, does propose and has
+    drafts accepted; all three servers (off, lookup, learned) emit the
+    same streams."""
+    import types
+
+    from idc_models_tpu.models.lm import next_token_loss
+    from idc_models_tpu.train import TrainState, make_train_step, rmsprop
+
+    vocab, t_max, k = 64, 64, 4
+
+    def orbit(starts, length):
+        seq = np.empty((len(starts), length), np.int64)
+        seq[:, 0] = starts
+        for t in range(1, length):
+            seq[:, t] = (5 * seq[:, t - 1] + 3) % vocab
+        return seq
+
+    model = attention_lm(vocab, t_max, embed_dim=E, num_heads=HEADS,
+                         mlp_dim=MLP, num_blocks=BLOCKS)
+    p0 = model.init(jax.random.key(7)).params
+    opt = rmsprop(3e-3)
+    state = TrainState(step=jnp.zeros((), jnp.int32), params=p0,
+                       model_state={}, opt_state=opt.init(p0))
+    step = jax.jit(make_train_step(model, opt, next_token_loss))
+    rng = np.random.default_rng(11)
+    key = jax.random.key(12)
+    for _ in range(300):
+        seqs = jnp.asarray(orbit(rng.integers(0, vocab, 8), t_max),
+                           jnp.int32)
+        key, sub = jax.random.split(key)
+        state, _ = step(state, seqs, seqs, sub)
+    target = jax.device_get(state.params)
+    variables = types.SimpleNamespace(params=target, state={})
+
+    cfg = dlm.draft_config(vocab, t_max)
+    streams = dlm.greedy_streams(
+        model, variables, orbit(rng.integers(0, vocab, 32), 4), t_max)
+    _, dstate, _ = dlm.distill_draft_lm(
+        model, variables, streams, config=cfg,
+        mesh=meshlib.data_seq_mesh(1, 1), epochs=20, batch_size=8,
+        lr=1e-2, seed=13)
+    dlm.save_draft_lm(tmp_path / "d", jax.device_get(dstate.params),
+                      config=cfg).wait()
+    learned = dlm.DraftLM(k, *dlm.load_draft_lm(tmp_path / "d"))
+
+    reqs = []
+    for i in range(6):
+        p_len = int(rng.integers(6, 12))
+        reqs.append(Request(
+            id=f"n{i}",
+            prompt=tuple(int(t) for t in
+                         orbit([int(rng.integers(0, vocab))], p_len)[0]),
+            max_new_tokens=min(int(rng.integers(30, 44)),
+                               t_max - p_len - 1)))
+
+    def serve(mode):
+        server = LMServer(target, embed_dim=E, num_heads=HEADS,
+                          num_blocks=BLOCKS, t_max=t_max,
+                          cache_dtype=jnp.bfloat16, n_slots=4, window=8,
+                          max_prefills_per_cycle=4,
+                          spec_decode=(mode != "off"), draft_k=k,
+                          drafter=learned if mode == "learned" else None)
+        out = server.run([(0.0, r) for r in reqs])
+        assert all(r.status == "ok" for r in out)
+        return {r.id: tuple(r.tokens) for r in out}, server.summary()
+
+    tok_l, s_l = serve("learned")
+    tok_o, _ = serve("off")
+    tok_n, s_n = serve("ngram")
+    assert tok_l == tok_o == tok_n
+    assert s_n["serve_spec_drafted"] <= 0.02 * s_l["serve_tokens"], s_n
+    assert s_l["serve_spec_drafted"] > 0
+    assert s_l["serve_spec_accept_rate"] > 0, s_l
 
 
 def test_chained_drafter_serves_with_batched_learned_member(

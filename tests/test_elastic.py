@@ -428,32 +428,46 @@ def test_add_replica_rejects_duplicate_id(devices, params):
 # -- 5. the elastic loop end to end -----------------------------------------
 
 
-def test_router_autoscales_up_then_down_with_fake_clock(devices,
-                                                        params):
+@pytest.mark.parametrize("cached", [False, True],
+                         ids=["no_cache", "shared_compile_cache"])
+def test_router_autoscales_up_then_down_with_fake_clock(devices, params,
+                                                        tmp_path, cached):
     """The full control loop on a deterministic clock: a burst trips
     the up signal (replica_factory builds 'auto0'), the drained queue
     trips the down signal (the least-loaded replica drains WITH
     migration), every request finishes ok, and the fleet lands back at
-    min_replicas."""
+    min_replicas. Over a shared compile cache the replica spun up
+    mid-trace opens WARM: it deserializes what the first replica
+    stored and stores nothing (a cache key pins its devices, so the
+    warm replica opens on the device the entries were compiled for)."""
     t = [0.0]
 
     def clock():
         t[0] += 0.25
         return t[0]
 
+    cache = CompileCache(tmp_path / "cc") if cached else None
     built = []
 
     def factory(rid):
-        rep = _replica(params, rid, device=devices[1])
+        stored = cache.stores if cached else None
+        rep = _replica(params, rid, device=devices[0 if cached else 1],
+                       compile_cache=cache)
+        if cached:
+            assert cache.hits > 0 and cache.stores == stored, (
+                cache.summary())
         built.append(rid)
         return rep
 
     auto = Autoscaler(AutoscaleConfig(
         min_replicas=1, max_replicas=2, queue_high=2.0, queue_low=1.0,
         dwell_s=0.4, cooldown_s=1.0))
-    router = Router([_replica(params, "r0", device=devices[0])],
+    router = Router([_replica(params, "r0", device=devices[0],
+                              compile_cache=cache)],
                     clock=clock, autoscaler=auto,
                     replica_factory=factory)
+    if cached:
+        assert cache.stores > 0 and cache.hits == 0
     rng = np.random.default_rng(13)
     reqs = [Request(id=f"e{i}",
                     prompt=tuple(int(x) for x in
@@ -531,9 +545,8 @@ def test_sigterm_handler_unwinds_to_drain():
 
 
 def test_docs_cover_elasticity():
-    """Satellite doc gate: the ROBUSTNESS "Elasticity" section, the
-    BENCHMARKS elastic keys, and the README flags must all exist so
-    the elastic layer stays discoverable."""
+    """Satellite doc gate: the ROBUSTNESS "Elasticity" section and the
+    README flags must exist so the elastic layer stays discoverable."""
     from pathlib import Path
 
     root = Path(__file__).parent.parent
@@ -542,12 +555,6 @@ def test_docs_cover_elasticity():
     for needle in ("dwell", "cooldown", "compile_cache",
                    "slot migration", "SIGTERM"):
         assert needle in robust, f"docs/ROBUSTNESS.md missing {needle}"
-    bench_md = (root / "docs" / "BENCHMARKS.md").read_text()
-    for needle in ("`elastic_tokens_per_sec`",
-                   "`elastic_spinup_speedup`",
-                   "`elastic_scale_ups`",
-                   "`elastic_slot_migrations`"):
-        assert needle in bench_md, f"docs/BENCHMARKS.md missing {needle}"
     readme = (root / "README.md").read_text()
     for needle in ("--autoscale-max", "--compile-cache", "SIGTERM"):
         assert needle in readme, f"README.md missing {needle}"

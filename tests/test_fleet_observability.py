@@ -504,6 +504,101 @@ def test_watchdog_detectors_fire_once_and_stay_silent_when_clean(
     assert len(wd.anomalies) == 6
 
 
+def test_watchdog_on_a_real_fleet_clean_then_each_fault(devices, params,
+                                                       tmp_path):
+    """The same detectors wired to a REAL two-replica journaled fleet
+    (the router drives one `check()` per step): a clean burst and a
+    quiet fleet fire nothing; then each kind fires exactly once on its
+    own fault under a fake watchdog clock — the cumulative counters the
+    serve hooks maintain (accept collapse, compile churn), a real
+    rollout whose canary alone is burn-breached, and a real kill whose
+    journaled in-flight work migrates onto the survivor, which still
+    finishes every request."""
+    wt = [0.0]
+
+    def mk(rid, i):
+        return _replica(params, rid, device=devices[i],
+                        max_queue_depth=64,
+                        journal_path=str(tmp_path / f"{rid}.jsonl"))
+
+    def burst(tag, n=8):
+        rng = np.random.default_rng(len(tag))
+        return [Request(id=f"{tag}{i}",
+                        prompt=tuple(int(x) for x in
+                                     rng.integers(0, VOCAB, 3 + i % 4)),
+                        max_new_tokens=10) for i in range(n)]
+
+    router = Router([mk("w0", 0), mk("w1", 1)])
+    cfg = WatchdogConfig(window_s=5.0, accept_min_drafted=64,
+                         accept_rate_floor=0.2, compile_churn_limit=8,
+                         migration_spike_limit=2)
+    wd = ClusterWatchdog(router, cfg, clock=lambda: wt[0])
+    router.watchdog = wd
+
+    # clean: an armed healthy fleet stays silent, busy and idle
+    out = router.run([(0.0, r) for r in burst("c")])
+    assert all(r.status == "ok" for r in out)
+    assert all(wd.check() == [] for _ in range(20))
+    assert wd.anomalies == []
+
+    # fault 1: speculative accept-rate collapse (5% << the 20% floor)
+    wt[0] += 10.0
+    wd.check()                              # rebase every window
+    m0 = router.replicas[0].server.metrics
+    m0.spec_drafted += 200
+    m0.spec_accepted += 10
+    wt[0] += 1.0
+    assert [a["kind"] for a in wd.check()] == ["accept_collapse"]
+    assert wd.check() == []                 # no re-fire while anomalous
+
+    # fault 2: compile churn on one replica
+    router.replicas[1].server.metrics.compiles_observed += 20
+    wt[0] += 1.0
+    assert [(a["kind"], a["replica"]) for a in wd.check()] == [
+        ("compile_churn", "w1")]
+
+    # fault 3: a real rollout whose canary ALONE burns its TTFT budget
+    canary_slo = SLOEngine([SLO.latency("ttft", threshold_s=1e-4)],
+                           short_window_s=60.0, long_window_s=300.0,
+                           min_samples=1, registry=MetricsRegistry())
+    assert router.start_rollout(params, replica_id="w1") == "w1"
+    router.replicas[1].server.metrics.slo = canary_slo
+    for _ in range(8):
+        canary_slo.observe("ttft", 1.0)
+    canary_slo.evaluate()
+    assert canary_slo.breached()
+    wt[0] += 1.0
+    assert [(a["kind"], a["replica"]) for a in wd.check()] == [
+        ("canary_divergence", "w1")]
+    router.finish_rollout()
+    # detached, or placement would avoid the breached replica and
+    # leave the kill below nothing to strand
+    router.replicas[1].server.metrics.slo = None
+
+    # fault 4: a real kill of a loaded replica
+    wt[0] += 10.0
+    wd.check()
+    reqs = burst("m")
+    for q in reqs:
+        assert router.submit(q)
+    router.step()
+    n_before = len(wd.anomalies)
+    migrated = router.kill_replica("w1")
+    assert len(migrated) > cfg.migration_spike_limit, migrated
+    wt[0] += 1.0
+    router.drain()                          # step() drives wd.check()
+    assert [a["kind"] for a in wd.anomalies[n_before:]] == [
+        "migration_spike"]
+    for q in reqs:
+        got = router.poll(q.id)
+        assert got is not None and got.status == "ok", q.id
+        assert got.tokens == _serial_tokens(params, q.prompt, 10), q.id
+    assert [a["kind"] for a in wd.anomalies] == [
+        "accept_collapse", "compile_churn", "canary_divergence",
+        "migration_spike"]
+    router.close()
+
+
 def test_watchdog_config_validates():
     with pytest.raises(ValueError, match="window_s"):
         WatchdogConfig(window_s=0)

@@ -246,38 +246,13 @@ def test_densenet_rejects_unknown_block_impl():
 
 
 # ---------------------------------------------------------------------------
-# bench + docs structural gates
+# docs structural gate
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.slow
-def test_bench_backbone_fused_structural():
-    """The bench function itself on CPU: keys present, parity gate
-    inside it passes, hbm_utilization correctly withheld (no roofline
-    for a CPU device kind)."""
-    import bench
-
-    out = bench.bench_backbone_fused(False)
-    for tag in ("mobile", "dense"):
-        assert out[f"{tag}_fused_patches_per_sec"] > 0
-        assert out[f"{tag}_fused_speedup"] > 0
-        assert f"{tag}_fused_hbm_utilization" not in out
-        assert f"{tag}_fused_patches_per_sec" in bench.HIGHER_IS_BETTER
-        assert f"{tag}_fused_speedup" in bench.HIGHER_IS_BETTER
-        assert (f"{tag}_fused_hbm_utilization"
-                in bench.HIGHER_IS_BETTER)
-
-
 def test_docs_cover_fused_kernels():
-    """Satellite doc gate: the DESIGN section and the BENCHMARKS
-    attribution update must exist (bench-key backtick coverage is
-    enforced separately by test_observability's doc gate)."""
+    """Satellite doc gate: the DESIGN section must exist."""
     root = Path(__file__).parent.parent
     design = (root / "docs" / "DESIGN.md").read_text()
     assert "Fused backbone kernels" in design
     assert "interpret" in design
-    bench_md = (root / "docs" / "BENCHMARKS.md").read_text()
-    for needle in ("`mobile_fused_patches_per_sec`",
-                   "`dense_fused_speedup`",
-                   "depthwise_chain_cost"):
-        assert needle in bench_md, f"docs/BENCHMARKS.md missing {needle}"

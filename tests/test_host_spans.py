@@ -282,13 +282,16 @@ def test_prefetch_abandoned_iterator_stops_every_thread(devices,
     if maybe_tracer is not None:
         assert "idc-prefetch-watch" in {t.name for t in threading.enumerate()}
     it.close()  # abandon early
+    ours = {"idc-prefetch", "idc-prefetch-watch"}
+    # wait on the pipeline's OWN threads by name: a count alone can be
+    # satisfied by an earlier test's thread ending while the watcher is
+    # still on its way out (seen under xdist load)
     for _ in range(50):
-        if threading.active_count() <= n_before:
+        if not ours & {t.name for t in threading.enumerate()}:
             break
         time.sleep(0.1)
+    assert not ours & {t.name for t in threading.enumerate()}
     assert threading.active_count() <= n_before
-    assert not {"idc-prefetch", "idc-prefetch-watch"} & {
-        t.name for t in threading.enumerate()}
 
 
 def test_prefetch_propagates_errors_traced_and_untraced(devices,

@@ -301,7 +301,7 @@ def test_generator_reuses_compilation(devices):
 def test_generator_chained_decode_windows(devices):
     """decode() windows chain exactly: two back-to-back windows through
     the returned (logits, caches) equal one window of the combined
-    length — the contract the serving bench leans on."""
+    length — the contract the serve path leans on."""
     params = _model(None).init(jax.random.key(35)).params
     kw = dict(embed_dim=E, num_heads=HEADS, num_blocks=BLOCKS,
               t_max=SEQ, cache_dtype=jnp.float32)

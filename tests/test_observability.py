@@ -608,109 +608,6 @@ def test_stats_covers_train_and_fed_jsonl(tmp_path):
     assert s["requests"] == {}        # nothing serve-shaped in the log
 
 
-def test_bench_compare_flags_directional_regressions(tmp_path):
-    """ISSUE-7 satellite: bench_compare diffs the two newest
-    BENCH_rNN.json records, honoring each key's good direction and the
-    10% tolerance; under two files is loud."""
-    import sys as _sys
-    from pathlib import Path as _Path
-
-    _sys.path.insert(0, str(_Path(__file__).parent.parent))
-    try:
-        import bench
-    finally:
-        _sys.path.pop(0)
-
-    def rec(**kw):
-        return {"metric": "x", **kw}
-
-    old = rec(value=100.0, serve_ttft_ms_p95=100.0, fed_round_s=1.0,
-              mfu=0.6)
-    # throughput -20% (regression), ttft +50% (regression), round -30%
-    # (improvement), mfu +5% (inside tolerance)
-    new = rec(value=80.0, serve_ttft_ms_p95=150.0, fed_round_s=0.7,
-              mfu=0.63)
-    (tmp_path / "BENCH_r01.json").write_text(json.dumps(old))
-    # the driver-record shape (bench line inside `tail`) parses too
-    (tmp_path / "BENCH_r02.json").write_text(json.dumps(
-        {"n": 2, "rc": 0, "tail": "noise\n" + json.dumps(new) + "\n"}))
-    out = bench.bench_compare(tmp_path)
-    assert out["new"].endswith("BENCH_r02.json")
-    assert set(out["regressions"]) == {"value", "serve_ttft_ms_p95"}
-    assert out["keys"]["fed_round_s"]["regressed"] is False
-    assert out["keys"]["mfu"]["regressed"] is False
-    assert out["keys"]["value"]["ratio"] == pytest.approx(0.8)
-    with pytest.raises(ValueError):
-        bench.bench_compare(tmp_path / "empty")
-    # every documented headline key really is documented
-    docs = (_Path(__file__).parent.parent / "docs"
-            / "BENCHMARKS.md").read_text()
-    for key in bench.HIGHER_IS_BETTER + bench.LOWER_IS_BETTER:
-        assert f"`{key}`" in docs, (
-            f"bench_compare headline key {key!r} missing from "
-            f"docs/BENCHMARKS.md")
-
-
-def test_bench_keys_all_classified_directional_or_neutral():
-    """ISSUE-20 satellite: every constant key a bench_* function returns
-    must be classified — either in a direction table (and therefore
-    documented, via the gate above) or in bench.NEUTRAL_KEYS with a
-    rationale.  A new bench metric that lands unclassified fails here
-    instead of silently dropping out of bench_compare; a NEUTRAL_KEYS
-    entry whose bench went away fails the stale check."""
-    import ast
-    import sys as _sys
-    from pathlib import Path as _Path
-
-    repo = _Path(__file__).parent.parent
-    _sys.path.insert(0, str(repo))
-    try:
-        import bench
-    finally:
-        _sys.path.pop(0)
-
-    tree = ast.parse((repo / "bench.py").read_text())
-    emitted = set()
-    for fn in ast.walk(tree):
-        if not (isinstance(fn, ast.FunctionDef)
-                and fn.name.startswith("bench_")):
-            continue
-        # dict literals assigned to a local that is later returned count
-        # the same as a literal `return {...}`
-        assigned: dict[str, ast.Dict] = {}
-        for node in ast.walk(fn):
-            if (isinstance(node, ast.Assign)
-                    and isinstance(node.value, ast.Dict)
-                    and len(node.targets) == 1
-                    and isinstance(node.targets[0], ast.Name)):
-                assigned[node.targets[0].id] = node.value
-        for node in ast.walk(fn):
-            if not isinstance(node, ast.Return):
-                continue
-            val = node.value
-            if isinstance(val, ast.Name):
-                val = assigned.get(val.id)
-            if not isinstance(val, ast.Dict):
-                continue
-            for key in val.keys:
-                if isinstance(key, ast.Constant) and isinstance(
-                        key.value, str):
-                    emitted.add(key.value)
-    assert len(emitted) > 100, "bench.py key scan came back implausibly thin"
-
-    directional = set(bench.HIGHER_IS_BETTER) | set(bench.LOWER_IS_BETTER)
-    neutral = set(bench.NEUTRAL_KEYS)
-    assert not (directional & neutral), sorted(directional & neutral)
-    unclassified = emitted - directional - neutral
-    assert not unclassified, (
-        f"bench keys missing a direction (add to HIGHER_IS_BETTER / "
-        f"LOWER_IS_BETTER + docs, or to NEUTRAL_KEYS): "
-        f"{sorted(unclassified)}")
-    stale = neutral - emitted
-    assert not stale, (
-        f"NEUTRAL_KEYS entries no bench emits any more: {sorted(stale)}")
-
-
 def test_profile_program_jsonl_schema_frozen(tmp_path, devices):
     """ISSUE-9: the `profile_program` event's key set is frozen from
     day one (NEW event; the ten historical event schemas are gated

@@ -639,6 +639,52 @@ def test_paged_validation_errors(devices, params):
         PagedPrefixCache(CHUNK, max_pages=4, budget_mb=1.0)
 
 
+def test_paged_pool_at_equal_hbm_holds_more_residents(devices):
+    """The capacity claim by its arithmetic and its counts: a page pool
+    of exactly the bytes the contiguous engine reserves for 4 slots
+    (pages x page bytes == 4 x bytes per slot), shared by 16 slots,
+    holds at least 1.5x as many requests of mixed lengths at once as
+    the contiguous engine can (4, whatever their lengths), and every
+    stream is the same in both."""
+    vocab, e, heads, blocks, mlp = 32, 32, 2, 2, 64
+    t_max, s_contig, window, chunk, ps = 128, 4, 4, 16, 16
+    n_req = 24
+    model = attention_lm(vocab, t_max, embed_dim=e, num_heads=heads,
+                         mlp_dim=mlp, num_blocks=blocks)
+    params = model.init(jax.random.key(0)).params
+    kw = dict(embed_dim=e, num_heads=heads, num_blocks=blocks,
+              t_max=t_max, cache_dtype=jnp.bfloat16, prefill_chunk=chunk,
+              max_queue_depth=2 * n_req, max_prefills_per_cycle=4,
+              window=window)
+    n_pages = s_contig * (t_max // ps)
+    rng = np.random.default_rng(11)
+    trace = [(0.0, Request(
+        id=f"r{i}",
+        prompt=tuple(int(x) for x in
+                     rng.integers(0, vocab, int(rng.integers(3, 16)))),
+        max_new_tokens=int(rng.integers(4, 24)))) for i in range(n_req)]
+
+    def run(paged):
+        server = LMServer(
+            params, n_slots=4 * s_contig if paged else s_contig,
+            kv_page_size=ps if paged else None,
+            kv_pages=n_pages if paged else None, **kw)
+        results = server.run(trace)
+        assert all(r.status == "ok" for r in results)
+        peak = round(max(server.metrics.occupancies)
+                     * server.engine.n_slots)
+        return server, {r.id: tuple(r.tokens) for r in results}, peak
+
+    contig, tok_c, peak_c = run(False)
+    paged, tok_p, peak_p = run(True)
+    assert (paged.engine.kv_pages * paged.engine.kv_page_bytes()
+            == s_contig * contig.engine.kv_bytes_per_slot())
+    assert peak_c <= s_contig
+    assert tok_p == tok_c
+    assert peak_p >= 1.5 * peak_c, (peak_p, peak_c)
+    assert paged.engine._alloc.used_count() == 0
+
+
 def test_paged_kv_resident_accounting(devices, params):
     """kv_bytes_resident tracks pages, not slots: a short resident
     request costs its pages only, and the tokens-per-HBM-byte figure

@@ -275,9 +275,17 @@ def test_streamed_aggregator_build_teaching_errors():
 
 
 def test_streamed_crash_fault_equals_manual_mask(devices):
-    """A population-plan crash on a cohort member is bit-identical to
-    zeroing that member's participation mask: the virtual-id fault
-    lands on exactly the right positional slot."""
+    """A population-plan crash on a cohort member equals zeroing that
+    member's participation mask: the virtual-id fault lands on exactly
+    the right positional slot. Held to 1e-6 absolute, not bitwise: the
+    round built WITH a fault plan and the round built without one are
+    two different XLA programs whose reductions may associate
+    differently (on the CPU backend they differ by one or two ulp: at
+    most 3.0e-8 on weights of 0.1-0.25, 2.3e-10 on values of 1e-3),
+    while a victim that was NOT dropped moves these parameters by
+    3.4e-4 at the median and 7.9e-4 at most. Bitwise equality is owed
+    between runs of ONE program, which is what every `_assert_bitwise`
+    in this file compares."""
     pop = _population()
     sampler = CohortSampler(pop, C, seed=5)
     mesh = meshlib.client_mesh(1)
@@ -297,8 +305,49 @@ def test_streamed_crash_fault_equals_manual_mask(devices):
                           wave=C)
     s_m, _ = plain(initialize_server(_model(), jax.random.key(0)),
                    None, None, mask, rng, round_idx=0)
-    _assert_bitwise(s_f.params, s_m.params)
+    for a, b in zip(_leaves(s_f.params), _leaves(s_m.params)):
+        np.testing.assert_allclose(a, b, rtol=0, atol=1e-6)
     assert int(m_f["clients_dropped"]) == 0    # crash != divergence
+
+
+def test_streamed_round_allocates_by_the_wave_not_the_population(devices):
+    """The O(wave) memory contract, by the host allocations a warm
+    round makes (tracemalloc sees every numpy buffer the population
+    and the round build): the same cohort of 256 in waves of 32 peaks
+    at the same few megabytes over 10,000 virtual clients as over
+    1,000. One float per client and shard example would be 190 MB at
+    10,000; a whole cohort's shards are 4.9 MB, a wave's 0.6 MB."""
+    import tracemalloc
+
+    cohort, wave = 256, 32
+    mesh = meshlib.client_mesh(8)
+
+    def build(n):
+        pop = _population(n, seed=0)
+        return _stream_round(pop, CohortSampler(pop, cohort, seed=0),
+                             mesh, wave)
+
+    def peak_mb(rnd, round_idx):
+        srv = jax.device_put(initialize_server(_model(),
+                                               jax.random.key(0)),
+                             meshlib.replicated(mesh))
+        tracemalloc.start()
+        try:
+            srv, m = rnd(srv, None, None, None, jax.random.key(1),
+                         round_idx=round_idx)
+            jax.block_until_ready(srv.params)
+            peak = tracemalloc.get_traced_memory()[1]
+        finally:
+            tracemalloc.stop()
+        assert int(m["participants"]) == cohort
+        assert int(m["waves"]) == cohort // wave
+        return peak / 2**20
+
+    rnd_1k, rnd_10k = build(1_000), build(10_000)
+    peak_mb(rnd_1k, 0)                       # cold: pays the compiles
+    p_1k, p_10k = peak_mb(rnd_1k, 5), peak_mb(rnd_10k, 5)
+    assert p_10k < 32.0, p_10k
+    assert p_10k < max(2.0 * p_1k, 8.0), (p_10k, p_1k)
 
 
 def test_streamed_through_driver_checkpoint_resume(devices, tmp_path):
@@ -444,6 +493,48 @@ def test_async_absorbs_straggler_wall_clock():
     async_wall = time.monotonic() - t0
     assert m["participants"] == C
     assert async_wall < sync_wall, (async_wall, sync_wall)
+
+
+def test_async_reaches_the_sync_loss_under_stragglers():
+    """Under one straggler plan (a quarter of the population two lag
+    units late) the buffered-async server reaches the evaluation loss
+    the synchronous streamed round has after six rounds, within four
+    times as many rounds: discounting stale updates slows learning per
+    completion, it does not stop it. Virtual time, no sleeps."""
+    from idc_models_tpu.federated import make_federated_eval
+
+    pop = _population(seed=0)
+    mesh = meshlib.client_mesh(1)
+    plan = faults_lib.PopulationFaultPlan(
+        pop.size, [faults_lib.PopulationFault("straggler", fraction=0.25,
+                                              staleness=2)], seed=3)
+    ev = make_federated_eval(_model(), binary_cross_entropy, mesh)
+    e_imgs, e_labels, e_w = pop.materialize(
+        CohortSampler(pop, 8, seed=999).cohort(0))
+
+    def loss(server):
+        return float(ev(server, e_imgs, e_labels, e_w)["loss"])
+
+    w = np.ones((C,), np.float32)
+    sync = run_rounds(
+        _stream_round(pop, CohortSampler(pop, C, seed=11), mesh, wave=C,
+                      faults=plan),
+        jax.device_put(initialize_server(_model(), jax.random.key(0)),
+                       meshlib.replicated(mesh)),
+        None, None, w, config=DriverConfig(rounds=6), seed=1)
+    target = loss(sync.server)
+
+    rf = _async_round(pop, CohortSampler(pop, C, seed=11), faults=plan,
+                      seed=1)
+    server, rounds = initialize_server(_model(), jax.random.key(0)), 0
+    while rounds < 24:
+        server = run_rounds(rf, server, None, None, w,
+                            config=DriverConfig(rounds=rounds + 1),
+                            seed=1).server
+        rounds += 1
+        if loss(server) <= target:
+            break
+    assert loss(server) <= target, (loss(server), target, rounds)
 
 
 def test_async_crash_clients_are_refilled():

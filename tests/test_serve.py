@@ -263,7 +263,7 @@ def test_admit_rejections(devices, params):
 
 
 def test_metrics_summary_and_jsonl(devices, params, tmp_path):
-    """The serving metrics roll up into the bench-record fields and
+    """The serving metrics roll up into the `serve_*` summary fields and
     stream through JsonlLogger in the standard record shape."""
     import json
 
@@ -305,9 +305,9 @@ def test_trace_roundtrip_and_poisson(devices, tmp_path):
 
 def test_trace_generation_is_byte_deterministic(devices, tmp_path):
     """ISSUE 12 satellite: same seed => byte-identical trace FILE. The
-    cluster bench replays one trace against 1 vs 2 replica fleets; the
-    comparison is meaningless if trace generation drifts between the
-    passes, so determinism is gated at the byte level — generation,
+    cluster drills replay one trace against fleets of different sizes;
+    the comparison is meaningless if trace generation drifts between
+    the passes, so determinism is gated at the byte level — generation,
     serialization, and the save->load->save fixpoint."""
     kw = dict(rate_per_s=75.0, vocab=VOCAB, t_max=SEQ, eos_id=2,
               deadline_s=5.0, sampled=True)

@@ -564,6 +564,52 @@ def test_brownout_sheds_submits_and_clamps_budget(devices, params):
         server.submit(Request(id="s2", prompt=(1,), max_new_tokens=2))
 
 
+def test_every_feature_armed_and_no_fault_is_a_clean_run(devices, params,
+                                                        tmp_path):
+    """The clean-path half of the drills: retry policy, an EMPTY fault
+    plan (which arms the per-cycle slot health checks), the journal, a
+    brownout controller and a TTFT SLO all on, nothing injected — every
+    request finishes ok with its serial stream, no slot is ever
+    quarantined, the health codes of a running engine read all-clear,
+    and the journal holds nothing pending at drain."""
+    from idc_models_tpu.observe import SLO, SLOEngine
+    from idc_models_tpu.observe.metrics_registry import MetricsRegistry
+
+    wal = tmp_path / "wal.jsonl"
+    server = LMServer(
+        params, n_slots=2, window=4, retry=RetryPolicy(max_retries=2),
+        fault_plan=ServeFaultPlan([]), journal=str(wal),
+        brownout=BrownoutController(queue_high=10_000),
+        slo=SLOEngine([SLO.latency("ttft", threshold_s=60.0)],
+                      registry=MetricsRegistry()), **_kw())
+    assert server.scheduler.health_checks
+    gen = Generator(params, **_kw())
+    reqs = [Request(id=f"c{i}", prompt=(1 + i, 2, 3),
+                    max_new_tokens=5 + i % 3) for i in range(6)]
+    results = server.run([(0.0, r) for r in reqs])
+    assert len(results) == 6
+    for r in reqs:
+        got = server.poll(r.id)
+        assert got.status == "ok"
+        assert got.tokens == _serial_tokens(gen, r.prompt,
+                                            r.max_new_tokens), r.id
+    s = server.summary()
+    assert s["serve_slot_faults"] == 0 and s["serve_retries"] == 0
+    assert s["serve_shed"] == 0
+    # mid-decode, every slot of the armed engine reads healthy
+    for i in range(2):
+        server.submit(Request(id=f"w{i}", prompt=(1, 2, 3, 4),
+                              max_new_tokens=SEQ - 8))
+    server.step()
+    server.step()
+    eng = server.engine
+    assert eng.slot_health().tolist() == [0, 0]
+    assert all(eng.slot_invariants_ok(i) for i in range(2))
+    server.drain()
+    server.close()
+    assert pending_requests(str(wal)) == []
+
+
 def test_burst_fault_floods_and_brownout_sheds(devices, params):
     """End to end: declarative burst arrivals flood the queue, the
     watermark brownout escalates to shed, and every refused request is

@@ -2,10 +2,13 @@
 
 1. TOKEN PARITY — greedy speculative output is bit-identical to the
    serial `Generator`, at EVERY accepted-prefix length (0, 1, k-1, k,
-   driven by a scripted drafter), across slot recycling, with int8 KV
-   caches, with chunked-prefill admission interleaved in the same
-   cycle, and under seeded top-k sampling (the verify consumes the
-   request's key chain exactly as the fused window would).
+   driven by a scripted drafter), across slot recycling, with
+   chunked-prefill admission interleaved in the same cycle, and under
+   seeded top-k sampling (the verify consumes the request's key chain
+   exactly as the fused window would). Over int8 KV caches the oracle
+   is the int8 server WITHOUT speculation: quantisation may flip a
+   near-tie argmax against the float path, speculation may not change
+   what plain decode over the same cache emits.
 2. DRAFTS ARE UNTRUSTED — any `propose` output is sound: the verify
    accepts only what the model itself would have emitted, so garbage
    drafts cost acceptance rate, never correctness.
@@ -259,26 +262,41 @@ def test_spec_parity_on_ring_sharded_cache(devices, params):
 def test_int8_kv_speculative_parity(devices, params):
     """Spec decode over int8 KV caches: the verify's chunk fold
     dequantizes by the same factored per-(slot, head) scales as the
-    decode fold, and greedy output still tracks the serial (float)
-    path exactly at this scale — the PR-4 drift bound holds through
-    speculation."""
+    decode fold, so the speculative server emits exactly what an int8
+    server of the same settings emits without speculation — the
+    invariant speculation owes. The float serial `Generator` is NOT
+    the oracle here: int8 rounding flips one near-tie argmax of these
+    random weights (request i2, token 5) with or without speculation;
+    the drift bound against float is test_serve.py's
+    test_int8_kv_capacity_and_bounded_drift."""
     gen = Generator(params, **_kw())
     rng = np.random.default_rng(31)
     prompts = [tuple(int(x) for x in rng.integers(0, VOCAB, 4 + 3 * i))
                for i in range(3)]
     budgets = [6, 8, 7]
+    # drafts scripted from the float stream: accepted where int8 agrees
+    # with it, rejected where it does not — sound either way
     plans = [(p, _serial_tokens(gen, p, b), 4)
              for p, b in zip(prompts, budgets)]
-    server = LMServer(params, n_slots=2, window=4, kv_dtype="int8",
-                      spec_decode=True, draft_k=4,
-                      drafter=ScriptedDrafter(4, plans), **_kw())
     reqs = [Request(id=f"i{i}", prompt=p, max_new_tokens=b)
             for i, (p, b) in enumerate(zip(prompts, budgets))]
-    server.run([(0.0, r) for r in reqs])
-    for r, (_, s, _) in zip(reqs, plans):
-        got = server.poll(r.id)
-        assert got.status == "ok" and got.tokens == s, r.id
-    assert server.summary()["serve_spec_verify_dispatches"] > 0
+
+    def serve(**spec):
+        server = LMServer(params, n_slots=2, window=4, kv_dtype="int8",
+                          **spec, **_kw())
+        server.run([(0.0, r) for r in reqs])
+        return server
+
+    plain = serve(spec_decode=False)
+    server = serve(spec_decode=True, draft_k=4,
+                   drafter=ScriptedDrafter(4, plans))
+    for r in reqs:
+        got, want = server.poll(r.id), plain.poll(r.id)
+        assert got.status == want.status == "ok"
+        assert got.tokens == want.tokens, r.id
+    s = server.summary()
+    assert s["serve_spec_verify_dispatches"] > 0
+    assert s["serve_spec_accepted"] > 0
 
 
 def test_spec_with_chunked_prefill_same_cycle(devices, params):
@@ -309,6 +327,59 @@ def test_spec_with_chunked_prefill_same_cycle(devices, params):
     assert server.summary()["serve_spec_verify_dispatches"] > before
     assert server.poll("run").tokens == s_run
     assert server.poll("long").tokens == s_long
+
+
+def test_lookup_drafts_are_accepted_on_a_model_that_counts(devices):
+    """What speculation is for, by its counts: a model trained to count
+    (next = tok + 1 mod vocab) serves counting prompts longer than the
+    vocabulary, so every trailing n-gram has occurred before and the
+    lookup drafter always proposes what the model will say. At least
+    half of the drafts are accepted, a verify advances a slot by more
+    than 1.5 tokens, and the streams are those of plain decode."""
+    from idc_models_tpu.models.lm import next_token_loss
+    from idc_models_tpu.train import TrainState, make_train_step, rmsprop
+
+    vocab, t_max, k = 16, 256, 16
+    model = attention_lm(vocab, t_max, embed_dim=E, num_heads=HEADS,
+                         mlp_dim=MLP, num_blocks=BLOCKS)
+    p0 = model.init(jax.random.key(0)).params
+    opt = rmsprop(3e-3)
+    state = TrainState(step=jnp.zeros((), jnp.int32), params=p0,
+                       model_state={}, opt_state=opt.init(p0))
+    step = jax.jit(make_train_step(model, opt, next_token_loss))
+    rng = np.random.default_rng(3)
+    key = jax.random.key(4)
+    for _ in range(300):
+        seqs = jnp.asarray((rng.integers(0, vocab, (8, 1))
+                            + np.arange(t_max)) % vocab, jnp.int32)
+        key, sub = jax.random.split(key)
+        state, _ = step(state, seqs, seqs, sub)
+    trained = jax.device_get(state.params)
+
+    reqs = []
+    for i in range(8):
+        p_len = int(rng.integers(vocab + 4, vocab * 2))
+        start = int(rng.integers(0, vocab))
+        reqs.append(Request(
+            id=f"s{i}",
+            prompt=tuple((start + j) % vocab for j in range(p_len)),
+            max_new_tokens=int(rng.integers(150, 180))))
+
+    def serve(spec):
+        server = LMServer(trained, embed_dim=E, num_heads=HEADS,
+                          num_blocks=BLOCKS, t_max=t_max,
+                          cache_dtype=jnp.bfloat16, n_slots=4, window=8,
+                          max_prefills_per_cycle=4, spec_decode=spec,
+                          draft_k=k, draft_order=2)
+        out = server.run([(0.0, r) for r in reqs])
+        assert all(r.status == "ok" for r in out)
+        return {r.id: tuple(r.tokens) for r in out}, server.summary()
+
+    tok_spec, s = serve(True)
+    tok_plain, _ = serve(False)
+    assert tok_spec == tok_plain
+    assert s["serve_spec_accept_rate"] >= 0.5, s
+    assert s["serve_spec_tokens_per_dispatch"] > 1.5, s
 
 
 def test_spec_ledger_counts_only_real_proposals(devices, params):

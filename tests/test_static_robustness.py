@@ -399,12 +399,12 @@ def _scan_serve_handlers(path: Path):
 # XLA cost/memory accounting goes through ONE normalizing extraction
 # point — observe.profile.program_report — which handles the backend
 # quirks (list-vs-dict cost_analysis returns, absent memory_analysis)
-# and degrades loudly-but-gracefully. Before this PR the parsing was
-# copy-pasted across bench.py, two experiments files, and a test; this
-# scan keeps the invariant from regressing: a direct
-# `.cost_analysis()` / `.memory_analysis()` attribute call anywhere in
-# the repo's python (package, bench.py, experiments/, tests/) outside
-# the documented allowlist fails.
+# and degrades loudly-but-gracefully. Before PR 9 the parsing was
+# copy-pasted across two experiments files and a test; this scan keeps
+# the invariant from regressing: a direct `.cost_analysis()` /
+# `.memory_analysis()` attribute call anywhere in the repo's python
+# (package, experiments/, tests/) outside the documented allowlist
+# fails.
 
 REPO = Path(__file__).parent.parent
 
@@ -441,11 +441,10 @@ def _scan_xla_analysis_calls(path: Path):
 
 
 def _xla_analysis_files():
-    files = [REPO / "bench.py"]
-    for sub in ("idc_models_tpu", "experiments", "tests"):
-        files.extend(sorted((REPO / sub).rglob("*.py")))
     me = Path(__file__).resolve()
-    return [f for f in files if f.resolve() != me]
+    return [f for sub in ("idc_models_tpu", "experiments", "tests")
+            for f in sorted((REPO / sub).rglob("*.py"))
+            if f.resolve() != me]
 
 
 def test_single_cost_analysis_extraction_point():
@@ -480,8 +479,7 @@ CONCAT_ALLOWLIST = {
     ("idc_models_tpu/models/densenet.py", "dense_layer_concat"):
         "the block_impl=\"concat\" parity reference: the ONE place the "
         "literal concat semantics live, pinned bit-close against the "
-        "packed path by tests/test_fused_conv.py and used as the "
-        "bench_backbone_fused baseline",
+        "packed path by tests/test_fused_conv.py",
 }
 
 

@@ -317,6 +317,50 @@ def test_queue_quota_rejects_flood_without_touching_neighbors(params):
     assert s["globex"]["requests"] == 1
 
 
+def test_flood_is_refused_by_quota_compiles_nothing_and_spares_neighbor(
+        params):
+    """The noisy-neighbor drill by its counts: acme's background traffic
+    plus a flood far past its quota (2 of 6 slots, 8 queued) arrives
+    beside globex's trace and is replayed by a client that takes a
+    refusal for an answer. The flood is refused at acme's own quota,
+    every globex request finishes ok, no globex request is refused, and
+    the whole mixed run compiles nothing after the warm wave (tenant
+    mixes, refusals and quota stalls are values, not shapes)."""
+    server = LMServer(
+        params, n_slots=6, window=4, max_prefills_per_cycle=6,
+        tenancy=_registry(quotas={"acme": TenantQuota(
+            max_resident_slots=2, max_queued=8)}), **_kw())
+    rng = np.random.default_rng(5)
+
+    def reqs(tag, tenant, n, budget):
+        return [Request(id=f"{tag}{i}",
+                        prompt=tuple(int(x) for x in
+                                     rng.integers(0, VOCAB, 3 + i % 4)),
+                        max_new_tokens=budget, tenant=tenant)
+                for i in range(n)]
+
+    server.run([(0.0, r) for r in reqs("w", "acme", 2, 4)
+                + reqs("v", "globex", 2, 4)])
+    sizes = server.engine.cache_sizes()
+    globex = reqs("b", "globex", 12, 6)
+    flood = reqs("a", "acme", 6, 6) + reqs("f", "acme", 40, SEQ // 2)
+    # the flood first: every globex request arrives behind it
+    results = server.run([(0.0, r) for r in flood + globex],
+                         on_full="reject")
+    assert server.engine.cache_sizes() == sizes, (
+        server.engine.cache_sizes(), sizes)
+    by_id = {r.id: r for r in results}
+    assert all(by_id[r.id].status == "ok" for r in globex)
+    refused = [r for r in results if r.status == "rejected"]
+    assert refused and all(r.id[0] in "af" for r in refused)
+    s = server.summary()["serve_tenants"]
+    assert s["acme"]["quota_rejections"] == len(refused)
+    assert s["globex"]["quota_rejections"] == 0
+    assert s["globex"]["requests"] == len(globex) + 2
+    slots, pages = server.scheduler._tenant_residency()
+    assert slots == {} and pages == {}      # all released at drain
+
+
 def test_page_quota_bounds_tenant_kv_reservations(params):
     """Paged engine: acme's admissions may hold at most 3 pool pages;
     its second request waits for its own releases while globex keeps
