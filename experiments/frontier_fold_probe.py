@@ -54,7 +54,7 @@ def probe_decode(out, name, *, slots, t_max, h, g, d, qdtype, layers,
                  frontiers, variants, steps=8):
     mesh = meshlib.seq_mesh(1)
     rng = np.random.default_rng(0)
-    caches = _caches(layers, (slots, t_max, g, d))
+    caches = _caches(layers, rd.cache_shape(slots, t_max, g, d))
     for vname, blk in variants:
         rd._DECODE_BLOCK = blk
         fold = rd.make_batched_ring_decode(mesh, jit=False)
@@ -100,7 +100,7 @@ def probe_chunk(out, name, *, c, t_max, h, g, d, qdtype, layers, starts,
                 variants):
     mesh = meshlib.seq_mesh(1)
     rng = np.random.default_rng(1)
-    caches = _caches(layers, (1, t_max, g, d))
+    caches = _caches(layers, rd.cache_shape(1, t_max, g, d))
     for vname, blk in variants:
         rd._CHUNK_BLOCK = blk
         fold = rd.make_chunk_ring_decode(mesh, jit=False)

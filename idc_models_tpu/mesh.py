@@ -185,7 +185,8 @@ def batch_seq_spec(mesh: Mesh, axis: str = SEQ_AXIS,
     `trailing` unsharded dims after it. Shared by the ring op's
     shard_map specs ([B,T,H,D]: trailing=2), the attention model's
     residual-stream pin ([B,T,E]: trailing=1), and the decode cache
-    sharding — one definition so the three surfaces cannot diverge.
+    sharding (trailing=0: either stored form, the rest whole) — one
+    definition so the three surfaces cannot diverge.
 
     The "model" axis is excluded from the batch group: it is reserved
     for WEIGHT sharding (tp.py, partition.py rules), so activations

@@ -45,7 +45,8 @@ from idc_models_tpu.models import core, moe
 from idc_models_tpu.models.attention import _seq_pin, transformer_block
 from idc_models_tpu.observe import trace
 from idc_models_tpu.ring_decode import (
-    cache_sharding, init_cache, make_chunk_ring_decode, make_ring_decode,
+    as_cache, cache_sharding, init_cache, make_chunk_ring_decode,
+    make_ring_decode,
 )
 
 
@@ -873,8 +874,7 @@ def _serving_fns(cfg: _ServeConfig) -> _ServeFns:
         def to_cache(x):                 # K/V -> fresh ring cache slot
             # zero pad positions (traced mask): decode's visibility
             # masking relies on slots past the prompt staying zero
-            x = jnp.where(keep, x, 0).astype(cfg.cache_dtype)
-            x = jnp.pad(x, ((0, 0), (0, t_max - p_pad), (0, 0), (0, 0)))
+            x = as_cache(jnp.where(keep, x, 0), t_max, cfg.cache_dtype)
             return lax.with_sharding_constraint(x, sh)
 
         return logits, tuple((to_cache(k), to_cache(v)) for k, v in kvs)

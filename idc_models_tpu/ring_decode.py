@@ -23,13 +23,25 @@ through a fixed schedule, decode holds K/V still and reduces the
 per-shard partials — the right shape for one-token queries, where a
 rotating ring would serialize n hops for no reuse.
 
-The cache layout IS the training layout (contiguous "seq" sharding of
-[B, T, H, D]), so a trained model's prompt K/V can be placed directly:
-pad to t_max, `jax.device_put` under `cache_sharding`, and decode
-continues from there — `prefill` does exactly this and is pinned
-bit-identical to decoding the prompt token by token. The zigzag layout
-is a TRAINING optimization (balancing a causal ring schedule that
-decode does not run) and deliberately has no decode counterpart.
+The cache's sharding IS the training sharding (contiguous "seq" sharding
+of the positions, dimension 1), so a trained model's prompt K/V can be
+placed directly: pad to t_max, `jax.device_put` under `cache_sharding`,
+and decode continues from there — `prefill` does exactly this and is
+pinned bit-identical to decoding the prompt token by token. The zigzag
+layout is a TRAINING optimization (balancing a causal ring schedule
+that decode does not run) and deliberately has no decode counterpart.
+
+What follows the positions is the cache's STORED FORM, declared in one
+place, `cache_shape`, from the width of a head: heads narrower than a
+TPU tile's 128 lanes are merged into rows `[B, T, G*D]` (kept apart they
+are padded wherever a program indexes them, and re-laid between that
+form and the runtime's compact one at every program's edge); heads of
+whole tiles stay `[B, T, G, D]`. New keys and values always arrive as
+`[B, C, G, D]`, and `_scores` / `_weighted` contract each form as the
+chip reads it fastest: one token over merged rows block-diagonally and
+in place, everything else head by head. `init_cache`, `prefill`,
+`as_cache` and `grow_cache` build and pad either form. The paged pools
+(`make_paged_*`) keep `[n_pages, page_size, H, D]`.
 
 Exactness: every step equals the last row of full causal attention over
 the sequence so far, fp tolerance, pinned by tests/test_ring_decode.py.
@@ -53,33 +65,152 @@ from idc_models_tpu import mesh as meshlib
 _MASKED = -1e30  # same finite sentinel as ring_attention._MASKED
 
 
+# lanes of a TPU tile: a head of a multiple of them fills whole tiles by
+# itself, a narrower one only together with its neighbours
+_LANES = 128
+
+
+def cache_shape(batch: int, t_max: int, heads: int, dim: int) -> tuple:
+    """The declared shape of a contiguous cache of `heads` cached heads
+    of `dim`: THE one place that decides the stored form, from the one
+    thing that decides what the chip reads in place, the width of a head
+    (PERF.md section 6, PR 30, has the readings):
+
+    - narrower than a tile's 128 lanes: `[batch, t_max, heads * dim]`,
+      the heads merged into rows that fill whole tiles. Kept apart,
+      (heads, dim) = (20, 64) is padded to (24, 128) wherever a program
+      indexes it, 2.4 times the bytes, and re-laid between that form and
+      the compact one the runtime keeps at every program's edge;
+    - whole tiles: `[batch, t_max, heads, dim]`. Nothing is padded, and
+      the products over (head, dim) read it faster than any contraction
+      of merged rows measured.
+
+    Positions are dimension 1 either way (the sequence ring shards it, a
+    window layer wraps over it), and every program that carries a cache
+    across its edge declares this shape, so none re-lays what another
+    wrote."""
+    if dim % _LANES:
+        return (batch, t_max, heads * dim)
+    return (batch, t_max, heads, dim)
+
+
+def grow_cache(c, t_max: int):
+    """A cache of either stored form, zeros appended behind its rows up
+    to `t_max` positions."""
+    return jnp.pad(c, ((0, 0), (0, t_max - c.shape[1]))
+                   + ((0, 0),) * (c.ndim - 2))
+
+
+def as_cache(kv, t_max: int, dtype):
+    """Keys or values [B, P, G, D] as the first P rows of a fresh cache
+    of `cache_shape(B, t_max, G, D)`, zeros behind them."""
+    return grow_cache(
+        jnp.asarray(kv, dtype).reshape(cache_shape(*kv.shape)), t_max)
+
+
+def _rows(t, cache):
+    """New keys or values [B, C, G, D], as a layer's projection hands
+    them over, as C rows of `cache`: the one reshape between heads and
+    merged rows, of the new tokens and never of the cache."""
+    return t.reshape(*t.shape[:2], *cache.shape[2:])
+
+
+def _append_rows(c, t, slot, mine):
+    """Per-row O(1) append of one token: row b of the cache `c` reads
+    its ONE position `slot[b]` and writes `t[b]` ([1, ...]) back there
+    only where `mine[b]`; any other row is bit-untouched. (XLA expands
+    this into a loop of one trip a row, four small operations a trip:
+    a third of `gpt2-large`'s window once the cache copies were gone.
+    One scatter that drops the rows that write nothing is measured and
+    waits for the yardstick: PERF.md section 6, PR 30.)"""
+    def row_append(c, t, s, m):
+        at = (s,) + (0,) * (c.ndim - 1)
+        old = lax.dynamic_slice(c, at, t.shape)
+        return lax.dynamic_update_slice(
+            c, jnp.where(m, t.astype(c.dtype), old), at)
+
+    return jax.vmap(row_append)(c, t, slot, mine)
+
+
+def _splice_rows(cache, tok, src, take_new):
+    """One request's chunk into its cache: row j takes the chunk's token
+    `src[j]` where `take_new[j]` ([T] each), else keeps what it holds."""
+    gathered = jnp.take(_rows(tok, cache), src, axis=1).astype(cache.dtype)
+    return jnp.where(take_new.reshape((1, -1) + (1,) * (cache.ndim - 2)),
+                     gathered, cache)
+
+
+def _own_lanes(h: int, g: int):
+    """[H, G] bool: query head h reads cached head h // (H / G), the
+    lanes [that * D, that * D + D) of a merged row of G * D lanes. A
+    numpy constant of the program: traced as an iota compare it becomes
+    a kernel that jaxlib's CPU loader does not find again in a
+    deserialized executable (serve/compile_cache.py)."""
+    return (np.arange(h)[:, None] // (h // g)) == np.arange(g)[None, :]
+
+
+def _by_head(c, d: int):
+    """Rows kept by head [B, K, G, D] as they are; merged rows
+    [B, K, G*D] (a block of the cache, never the cache) split."""
+    return c if c.ndim == 4 else c.reshape(*c.shape[:2], -1, d)
+
+
 def _scores(q, kc):
     """q.k over the cache in float32: q [B, H, D] or [B, C, H, D] against
-    kc [B, K, G, D] -> [B, H, K] or [B, H, C, K]. With G < H (grouped
-    queries) query head h reads KV head h // (H / G); the cache is
-    never repeated to H heads."""
+    kc [B, K, G, D] or merged rows [B, K, G*D] -> [B, H, K] or
+    [B, H, C, K]. With G < H (grouped queries) query head h reads cached
+    head h // (H / G); the cache is never repeated to H heads.
+
+    ONE token over merged rows is spread block-diagonally over the row's
+    lanes, its D values in its cached head's lanes and exact zeros in
+    the others, and contracted against the rows as they are stored: the
+    matrix unit multiplies by zeros G times over, which a fold bound by
+    the bytes of the cache does not feel, and nothing is re-laid. A
+    CHUNK of C queries would feel it (C * H query rows for every row
+    read: compute binds), so the merged rows it was handed, one block,
+    are split into heads and contracted head by head like rows kept by
+    head."""
+    d = q.shape[-1]
+    if q.ndim == 3 and kc.ndim == 3:
+        h, g = q.shape[1], kc.shape[-1] // d
+        qx = jnp.where(_own_lanes(h, g)[:, :, None], q[:, :, None, :], 0)
+        return jnp.einsum("bhc,bkc->bhk", qx.reshape(q.shape[0], h, g * d),
+                          kc, preferred_element_type=jnp.float32)
+    kc = _by_head(kc, d)
     h, g = q.shape[-2], kc.shape[-2]
     if q.ndim == 3:
         if h == g:
             return jnp.einsum("bhd,bkhd->bhk", q, kc,
                               preferred_element_type=jnp.float32)
-        b, _, d = q.shape
+        b = q.shape[0]
         s = jnp.einsum("bgrd,bkgd->bgrk", q.reshape(b, g, h // g, d), kc,
                        preferred_element_type=jnp.float32)
         return s.reshape(b, h, kc.shape[1])
     if h == g:
         return jnp.einsum("bchd,bkhd->bhck", q, kc,
                           preferred_element_type=jnp.float32)
-    b, c, _, d = q.shape
+    b, c = q.shape[:2]
     s = jnp.einsum("bcgrd,bkgd->bgrck", q.reshape(b, c, g, h // g, d), kc,
                    preferred_element_type=jnp.float32)
     return s.reshape(b, h, c, kc.shape[1])
 
 
-def _weighted(p, vc):
+def _weighted(p, vc, d: int):
     """Probabilities times cached values in float32: p [B, H, K] or
-    [B, H, C, K] against vc [B, K, G, D] -> [B, H, D] or [B, H, C, D];
-    the grouped-query counterpart of `_scores`."""
+    [B, H, C, K] against vc [B, K, G, D] or merged rows [B, K, G*D] of
+    heads of `d` -> [B, H, D] or [B, H, C, D]; the counterpart of
+    `_scores`, case by case. One token's product against merged rows is
+    taken over all G * D lanes and each head keeps the D lanes of its
+    own cached head (the others, another head's values under this
+    head's probabilities, are dropped)."""
+    if p.ndim == 3 and vc.ndim == 3:
+        h, g = p.shape[1], vc.shape[-1] // d
+        o = jnp.einsum("bhk,bkc->bhc", p, vc,
+                       preferred_element_type=jnp.float32)
+        return jnp.sum(jnp.where(_own_lanes(h, g)[:, :, None],
+                                 o.reshape(p.shape[0], h, g, d), 0.0),
+                       axis=2)
+    vc = _by_head(vc, d)
     h, g = p.shape[1], vc.shape[-2]
     if p.ndim == 3:
         if h == g:
@@ -88,14 +219,14 @@ def _weighted(p, vc):
         b, _, k = p.shape
         o = jnp.einsum("bgrk,bkgd->bgrd", p.reshape(b, g, h // g, k), vc,
                        preferred_element_type=jnp.float32)
-        return o.reshape(b, h, vc.shape[-1])
+        return o.reshape(b, h, d)
     if h == g:
         return jnp.einsum("bhck,bkhd->bhcd", p, vc,
                           preferred_element_type=jnp.float32)
     b, _, c, k = p.shape
     o = jnp.einsum("bgrck,bkgd->bgrcd", p.reshape(b, g, h // g, c, k), vc,
                    preferred_element_type=jnp.float32)
-    return o.reshape(b, h, c, vc.shape[-1])
+    return o.reshape(b, h, c, d)
 
 
 def _fold_block(t_shard: int, target: int) -> int:
@@ -133,9 +264,10 @@ def _trips(frontier, row0, t_shard: int, blk: int):
 def _attend_to_frontier(q, kc, vc, see, frontier, blk: int, *, scale,
                         row0=0, k_scale=None):
     """Local attend of q [B, H, D] or [B, C, H, D] over the resident
-    shard kc / vc [B, t_shard, G, D], read in blocks of `blk` rows up to
-    `frontier` (a global row count, traced; the shard starts at global
-    row `row0`): -> float32 partials (m, l, acc) for the ring merge.
+    shard kc / vc [B, t_shard, ...] (either form of `cache_shape`), read
+    in blocks of `blk` rows up to `frontier` (a global row count,
+    traced; the shard starts at global row `row0`): -> float32 partials
+    (m, l, acc) for the ring merge.
 
     `see(g)` gives the visibility of global rows g [blk], broadcastable
     against the scores [B, H, (C,) blk]; rows at or beyond `frontier`
@@ -146,7 +278,7 @@ def _attend_to_frontier(q, kc, vc, see, frontier, blk: int, *, scale,
     blocks existed, bit for bit. Otherwise blocks are merged by the
     algebra that merges the shards of a ring: running maximum, both
     sides rescaled by exp(m - m_new)."""
-    t_shard = kc.shape[1]
+    t_shard, d = kc.shape[1], q.shape[-1]
 
     def block(kb, vb, g):
         # f32 accumulation by preferred_element_type, NOT astype:
@@ -162,7 +294,7 @@ def _attend_to_frontier(q, kc, vc, see, frontier, blk: int, *, scale,
         # it explicitly so the merge is exact rather than relying on the
         # exp(_MASKED - m) == 0 underflow
         p = jnp.where(vis, p, 0.0)
-        return m, jnp.sum(p, axis=-1), _weighted(p, vb)
+        return m, jnp.sum(p, axis=-1), _weighted(p, vb, d)
 
     rows = jnp.arange(blk, dtype=jnp.int32)
     if t_shard <= blk:
@@ -185,7 +317,7 @@ def _attend_to_frontier(q, kc, vc, see, frontier, blk: int, *, scale,
     lead = (q.shape[0], q.shape[-2]) + q.shape[1:-2]     # [B, H, (C)]
     init = (jnp.full(lead, _MASKED, jnp.float32),
             jnp.zeros(lead, jnp.float32),
-            jnp.zeros(lead + (vc.shape[-1],), jnp.float32))
+            jnp.zeros(lead + (d,), jnp.float32))
     return lax.fori_loop(0, trips, body, init)
 
 
@@ -202,15 +334,18 @@ def _check_wrap(wrap: bool, n: int, axis: str) -> None:
 
 
 def cache_sharding(mesh: Mesh, axis: str = meshlib.SEQ_AXIS) -> NamedSharding:
-    """[B, T_max, H, D] cache layout — identical to the training-side
-    q/k/v sharding (`mesh.batch_seq_sharding`, the one construction
-    site), so trained K/V drops in with no relayout."""
-    return meshlib.batch_seq_sharding(mesh, axis, trailing=2)
+    """Sharding of a cache of either stored form (`cache_shape`): batch
+    and positions as in the training-side q/k/v sharding
+    (`mesh.batch_seq_sharding`, the one construction site), so trained
+    K/V drops in with no relayout across devices; whatever follows the
+    positions (merged rows, or heads and their width) stays whole."""
+    return meshlib.batch_seq_sharding(mesh, axis, trailing=0)
 
 
 def init_cache(mesh: Mesh, batch: int, t_max: int, heads: int, dim: int,
                *, dtype=jnp.bfloat16, axis: str = meshlib.SEQ_AXIS):
-    """Zero-initialized (k, v) caches, sharded over the ring."""
+    """Zero-initialized (k, v) caches of `cache_shape(batch, t_max,
+    heads, dim)`, sharded over the ring."""
     n = mesh.shape[axis]
     if t_max % n:
         raise ValueError(f"t_max {t_max} not divisible by the ring size "
@@ -218,7 +353,7 @@ def init_cache(mesh: Mesh, batch: int, t_max: int, heads: int, dim: int,
     sh = cache_sharding(mesh, axis)
     # put_with_sharding, not device_put: on a multi-host mesh each
     # process materializes only its addressable shards (mesh.py)
-    mk = functools.partial(np.zeros, (batch, t_max, heads, dim),
+    mk = functools.partial(np.zeros, cache_shape(batch, t_max, heads, dim),
                            jnp.dtype(dtype))
     return (meshlib.put_with_sharding(mk(), sh),
             meshlib.put_with_sharding(mk(), sh))
@@ -253,7 +388,8 @@ def make_ring_decode(mesh: Mesh, *, axis: str = meshlib.SEQ_AXIS,
     _check_wrap(wrap, n, axis)
 
     def per_device(kc, vc, q, kt, vt, pos):
-        b, t_shard, h, d = kc.shape
+        t_shard, d = kc.shape[1], q.shape[-1]
+        kt, vt = _rows(kt, kc), _rows(vt, vc)
         i = collectives.axis_index(axis)
         scale_ = scale if scale is not None else d ** -0.5
         pos = jnp.asarray(pos, jnp.int32)
@@ -264,14 +400,13 @@ def make_ring_decode(mesh: Mesh, *, axis: str = meshlib.SEQ_AXIS,
         # back), one single-slot update that donation lowers in place —
         # never a whole-shard copy
         mine = (owner == i)
-        old_k = lax.dynamic_slice(kc, (0, slot, 0, 0), kt.shape)
-        old_v = lax.dynamic_slice(vc, (0, slot, 0, 0), vt.shape)
+        at = (0, slot) + (0,) * (kc.ndim - 2)
+        old_k = lax.dynamic_slice(kc, at, kt.shape)
+        old_v = lax.dynamic_slice(vc, at, vt.shape)
         kc = lax.dynamic_update_slice(
-            kc, jnp.where(mine, kt.astype(kc.dtype), old_k),
-            (0, slot, 0, 0))
+            kc, jnp.where(mine, kt.astype(kc.dtype), old_k), at)
         vc = lax.dynamic_update_slice(
-            vc, jnp.where(mine, vt.astype(vc.dtype), old_v),
-            (0, slot, 0, 0))
+            vc, jnp.where(mine, vt.astype(vc.dtype), old_v), at)
         # 2. local attend against the resident shard, f32 accumulation
         # (preferred_element_type, NOT astype: upcasting a 64k-slot bf16
         # cache would materialize a 2x-size f32 copy per step — the MXU
@@ -287,7 +422,7 @@ def make_ring_decode(mesh: Mesh, *, axis: str = meshlib.SEQ_AXIS,
         # underflow
         p = jnp.where(visible[None, None, :], p, 0.0)
         l_loc = jnp.sum(p, axis=-1)                       # [B, H]
-        acc_loc = _weighted(p, vc)
+        acc_loc = _weighted(p, vc, d)
         # 3. one stable softmax merge across the ring
         m_glob = lax.pmax(m_loc, axis)
         corr = jnp.exp(m_loc - m_glob)
@@ -297,7 +432,7 @@ def make_ring_decode(mesh: Mesh, *, axis: str = meshlib.SEQ_AXIS,
         return out[:, None].astype(q.dtype), kc, vc  # [B,1,H,D]
 
     bo = meshlib.batch_axes(mesh, axis)   # "model" stays weight-only
-    cache_spec = P(bo, axis, None, None)
+    cache_spec = P(bo, axis)      # either stored form: the rest whole
     tok_spec = P(bo, None, None, None)
     mapped = shard_map(
         per_device, mesh=mesh,
@@ -411,7 +546,7 @@ def make_batched_ring_decode(mesh: Mesh, *, axis: str = meshlib.SEQ_AXIS,
 
     def per_device(kc, vc, q, kt, vt, pos, live, k_scale=None,
                    v_scale=None):
-        b, t_shard, h, d = kc.shape
+        t_shard, d = kc.shape[1], q.shape[-1]
         i = collectives.axis_index(axis)
         scale_ = scale if scale is not None else d ** -0.5
         pos = jnp.asarray(pos, jnp.int32)
@@ -437,16 +572,8 @@ def make_batched_ring_decode(mesh: Mesh, *, axis: str = meshlib.SEQ_AXIS,
                 vt.astype(jnp.float32) / v_scale[:, None, :, None]),
                 -127, 127)
 
-        # per-row O(1) append: each row reads its ONE slot and writes the
-        # new token back only when this shard owns the row's position AND
-        # the row is live — a dead row's shard is bit-untouched
-        def row_append(c, t, s, m):
-            old = lax.dynamic_slice(c, (s, 0, 0), t.shape)
-            return lax.dynamic_update_slice(
-                c, jnp.where(m, t.astype(c.dtype), old), (s, 0, 0))
-
-        kc = jax.vmap(row_append)(kc, kt, slot, mine)
-        vc = jax.vmap(row_append)(vc, vt, slot, mine)
+        kc = _append_rows(kc, _rows(kt, kc), slot, mine)
+        vc = _append_rows(vc, _rows(vt, vc), slot, mine)
         # row-wise local attend + the same stable merge as the scalar
         # fold (see make_ring_decode); visibility is per ROW now. A
         # wrapped ring is all live once it has wrapped: one pass over
@@ -471,7 +598,7 @@ def make_batched_ring_decode(mesh: Mesh, *, axis: str = meshlib.SEQ_AXIS,
         return out[:, None].astype(q.dtype), kc, vc
 
     bo = meshlib.batch_axes(mesh, axis)   # "model" stays weight-only
-    cache_spec = P(bo, axis, None, None)
+    cache_spec = P(bo, axis)      # either stored form: the rest whole
     tok_spec = P(bo, None, None, None)
     # scales are per (row, head): the batch dim shards with the caches'
     # over the non-seq axes (P() would mis-shape the per-device divide
@@ -587,8 +714,7 @@ def make_batched_chunk_ring_decode(mesh: Mesh, *,
 
     def per_device(kc, vc, q, kt, vt, pos, live, k_scale=None,
                    v_scale=None):
-        b, t_shard, h, d = kc.shape
-        c = q.shape[1]
+        t_shard, c, d = kc.shape[1], q.shape[1], q.shape[-1]
         i = collectives.axis_index(axis)
         scale_ = scale if scale is not None else d ** -0.5
         pos = jnp.asarray(pos, jnp.int32)
@@ -616,27 +742,23 @@ def make_batched_chunk_ring_decode(mesh: Mesh, *,
         src = jnp.clip(g[None, :] - posc[:, None], 0, c - 1)
 
         def splice(cache, tok):
+            tail = (1,) * (cache.ndim - 2)
             gathered = jnp.take_along_axis(
-                tok, src[:, :, None, None], axis=1).astype(cache.dtype)
-            return jnp.where(take_new[:, :, None, None], gathered,
-                             cache)
+                _rows(tok, cache), src.reshape(src.shape + tail),
+                axis=1).astype(cache.dtype)
+            return jnp.where(take_new.reshape(take_new.shape + tail),
+                             gathered, cache)
 
         kc = splice(kc, kt)
         vc = splice(vc, vt)
-        # 2. per-row, per-query local attend against the resident shard
+        # 2. per-row, per-query local attend against the resident
+        # shard: one pass over all of it (the shard is the block)
         qpos = posc[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :]
-        s = jnp.einsum("bchd,bkhd->bhck", q, kc,
-                       preferred_element_type=jnp.float32) * scale_
-        if quantized:
-            s = s * k_scale[:, :, None, None]
-        visible = g[None, None, :] <= qpos[:, :, None]  # [B, C, t_shard]
-        s = jnp.where(visible[:, None], s, _MASKED)
-        m_loc = jnp.max(s, axis=-1)                       # [B, H, C]
-        p = jnp.exp(s - m_loc[..., None])
-        p = jnp.where(visible[:, None], p, 0.0)
-        l_loc = jnp.sum(p, axis=-1)
-        acc_loc = jnp.einsum("bhck,bkhd->bhcd", p, vc,
-                             preferred_element_type=jnp.float32)
+        m_loc, l_loc, acc_loc = _attend_to_frontier(      # [B, H, C]
+            q, kc, vc,
+            lambda rows: (rows[None, None, :] <= qpos[:, :, None])[:, None],
+            n * t_shard, t_shard, scale=scale_, row0=i * t_shard,
+            k_scale=k_scale[:, :, None, None] if quantized else None)
         if quantized:
             acc_loc = acc_loc * v_scale[:, :, None, None]
         # 3. one stable softmax merge across the ring (per chunk)
@@ -648,7 +770,7 @@ def make_batched_chunk_ring_decode(mesh: Mesh, *,
         return jnp.moveaxis(out, 1, 2).astype(q.dtype), kc, vc
 
     bo = meshlib.batch_axes(mesh, axis)   # "model" stays weight-only
-    cache_spec = P(bo, axis, None, None)
+    cache_spec = P(bo, axis)      # either stored form: the rest whole
     tok_spec = P(bo, None, None, None)
     scale_specs = (P(bo, None), P(bo, None)) if quantized else ()
     mapped = shard_map(
@@ -1229,13 +1351,15 @@ def make_chunk_ring_decode(mesh: Mesh, *, axis: str = meshlib.SEQ_AXIS,
         see_own = ((ci[None, :] <= ci[:, None])
                    & (ci[None, :] > ci[:, None] - w))           # [C, C]
         visible = jnp.concatenate([see_ring, see_own], axis=1)
-        k_all = jnp.concatenate([kc, kt.astype(kc.dtype)], axis=1)
-        v_all = jnp.concatenate([vc, vt.astype(vc.dtype)], axis=1)
+        k_all = jnp.concatenate([kc, _rows(kt, kc).astype(kc.dtype)],
+                                axis=1)
+        v_all = jnp.concatenate([vc, _rows(vt, vc).astype(vc.dtype)],
+                                axis=1)
         s = _scores(q, k_all) * scale_
         s = jnp.where(visible[None, None], s, _MASKED)
         m = jnp.max(s, axis=-1)
         p = jnp.where(visible[None, None], jnp.exp(s - m[..., None]), 0.0)
-        out = (_weighted(p, v_all)
+        out = (_weighted(p, v_all, d)
                / jnp.maximum(jnp.sum(p, axis=-1), 1e-37)[..., None])
         # the write comes last: row j takes the latest REAL position of
         # the chunk congruent to j, when the chunk has one
@@ -1243,19 +1367,14 @@ def make_chunk_ring_decode(mesh: Mesh, *, axis: str = meshlib.SEQ_AXIS,
         take_new = latest >= start
         src = jnp.clip(latest - start, 0, c - 1)
 
-        def splice(cache, tok):
-            gathered = jnp.take(tok, src, axis=1).astype(cache.dtype)
-            return jnp.where(take_new[None, :, None, None], gathered,
-                             cache)
-
         return (jnp.moveaxis(out, 1, 2).astype(q.dtype),
-                splice(kc, kt), splice(vc, vt))
+                _splice_rows(kc, kt, src, take_new),
+                _splice_rows(vc, vt, src, take_new))
 
     def per_device(kc, vc, q, kt, vt, start, p_end):
         if wrap:
             return per_device_wrap(kc, vc, q, kt, vt, start, p_end)
-        b, t_shard, h, d = kc.shape
-        c = q.shape[1]
+        t_shard, c, d = kc.shape[1], q.shape[1], q.shape[-1]
         i = collectives.axis_index(axis)
         scale_ = scale if scale is not None else d ** -0.5
         start = jnp.asarray(start, jnp.int32)
@@ -1268,13 +1387,8 @@ def make_chunk_ring_decode(mesh: Mesh, *, axis: str = meshlib.SEQ_AXIS,
         take_new = (g >= start) & (g < p_end)                 # [t_shard]
         src = jnp.clip(g - start, 0, c - 1)                   # [t_shard]
 
-        def splice(cache, tok):
-            gathered = jnp.take(tok, src, axis=1).astype(cache.dtype)
-            return jnp.where(take_new[None, :, None, None], gathered,
-                             cache)
-
-        kc = splice(kc, kt)
-        vc = splice(vc, vt)
+        kc = _splice_rows(kc, kt, src, take_new)
+        vc = _splice_rows(vc, vt, src, take_new)
         # 2. per-query local attend against the resident shard, in
         # blocks up to the chunk's last query: nothing beyond it is
         # visible to any of them
@@ -1294,7 +1408,7 @@ def make_chunk_ring_decode(mesh: Mesh, *, axis: str = meshlib.SEQ_AXIS,
         return jnp.moveaxis(out, 1, 2).astype(q.dtype), kc, vc  # [B,C,H,D]
 
     bo = meshlib.batch_axes(mesh, axis)   # "model" stays weight-only
-    cache_spec = P(bo, axis, None, None)
+    cache_spec = P(bo, axis)      # either stored form: the rest whole
     tok_spec = P(bo, None, None, None)
     mapped = shard_map(
         per_device, mesh=mesh,
@@ -1330,12 +1444,13 @@ def make_chunk_ring_decode(mesh: Mesh, *, axis: str = meshlib.SEQ_AXIS,
 def prefill(mesh: Mesh, k_prompt, v_prompt, t_max: int, *,
             axis: str = meshlib.SEQ_AXIS, dtype=jnp.bfloat16):
     """Place a prompt's [B, P, H, D] K/V directly into a fresh ring
-    cache (pad to t_max, shard) — bit-identical to decoding the prompt
+    cache of `cache_shape(B, t_max, H, D)` (pad to t_max, shard) —
+    bit-identical to decoding the prompt
     token by token (pinned by test), without the O(P) python loop.
     Returns (k_cache, v_cache); attention outputs for the prompt itself
     come from the training ring (`make_ring_attention`), which shares
-    this layout."""
-    b, p_len, h, d = k_prompt.shape
+    this sharding."""
+    p_len = k_prompt.shape[1]
     if p_len > t_max:
         raise ValueError(f"prompt length {p_len} exceeds t_max {t_max}")
     sh = cache_sharding(mesh, axis)
@@ -1343,8 +1458,7 @@ def prefill(mesh: Mesh, k_prompt, v_prompt, t_max: int, *,
     if t_max % n:
         raise ValueError(f"t_max {t_max} not divisible by the ring size "
                          f"{n} over mesh axis {axis!r}")
-    pad = ((0, 0), (0, t_max - p_len), (0, 0), (0, 0))
-    kc = jnp.pad(jnp.asarray(k_prompt, dtype), pad)
-    vc = jnp.pad(jnp.asarray(v_prompt, dtype), pad)
+    kc = as_cache(k_prompt, t_max, dtype)
+    vc = as_cache(v_prompt, t_max, dtype)
     return (meshlib.put_with_sharding(kc, sh),
             meshlib.put_with_sharding(vc, sh))

@@ -88,8 +88,9 @@ class CompileCache:
             # with donated buffers replay unsoundly cross-process on
             # CPU, so they must key out, not load. Schema 3: the window
             # program returns the attention's rows read as one more
-            # result
-            "schema": 3,
+            # result. Schema 4: the programs take their caches in the
+            # stored form `ring_decode.cache_shape` declares
+            "schema": 4,
             "jax": jax.__version__,
             "jaxlib": jax.lib.__version__,
             "backend": jax.default_backend(),

@@ -12,7 +12,8 @@ vacated row while the other rows keep decoding — the batch never drains
 to refill.
 
 ALL per-slot state is device-resident and donated through the whole
-serve loop: per-block ring caches `[S, t_max, H, D]` (one row per slot,
+serve loop: per-block ring caches `[S, t_max, ...]` (one row per slot,
+in the stored form `ring_decode.cache_shape` gives the layer's heads,
 the training-layout ring sharding), last-token logits `[S, V]`, rng key
 data `[S, 2]`, positions `[S]`, remaining token budgets `[S]`, and stop
 ids `[S]`. The host keeps a SHADOW of positions/budgets it can update by
@@ -33,7 +34,7 @@ Three compiled programs drive the device:
   compiles nothing new after warmup AND a request's prefill is
   bit-identical to a serial call's.
 - **insert** — a jitted batch-axis scatter admitting one request: the
-  fresh `[1, t_max, H, D]` caches, `[1, V]` logits, and the slot's
+  fresh `[1, t_max, ...]` caches, `[1, V]` logits, and the slot's
   position/budget/stop-id/key rows all land via `dynamic_update_slice`
   with the slot index TRACED — one executable for every slot, zero
   recompilation on recycle.
@@ -74,7 +75,8 @@ from idc_models_tpu.models.lm import (
     make_adapter_head_hook, prefill_bucket, prefill_buckets,
 )
 from idc_models_tpu.ring_decode import (
-    decode_rows_read, make_batched_chunk_ring_decode,
+    cache_shape, decode_rows_read, grow_cache,
+    make_batched_chunk_ring_decode,
     make_batched_ring_decode,
     make_paged_batched_chunk_ring_decode, make_paged_batched_ring_decode,
     make_paged_chunk_ring_decode,
@@ -429,11 +431,11 @@ def _engine_fns(cfg, pad_id: int, quant: bool = False,
         # when quantized — HALF the HBM of the bf16 rows, which is what
         # lets n_slots scale at a fixed budget
         # a layer's rows follow its spec: t_max of them, or a window
-        # layer's ring; the layer's own count of cached heads
+        # layer's ring; stored as the width of its own heads asks
         def mk(i, l):
             return meshlib.put_with_sharding(
-                np.zeros((n_slots, spec.cache_len(i, t_max), l.kv_heads,
-                          l.head_dim),
+                np.zeros(cache_shape(n_slots, spec.cache_len(i, t_max),
+                                     l.kv_heads, l.head_dim),
                          jnp.int8 if quant
                          else jnp.dtype(cfg.cache_dtype)), cache_sh)
 
@@ -512,10 +514,9 @@ def _engine_fns(cfg, pad_id: int, quant: bool = False,
                 out_scales.append((
                     lax.with_sharding_constraint(ks_row, rep),
                     lax.with_sharding_constraint(vs_row, rep)))
-            kc = lax.dynamic_update_slice(kc, nk.astype(kc.dtype),
-                                          (slot, 0, 0, 0))
-            vc = lax.dynamic_update_slice(vc, nv.astype(vc.dtype),
-                                          (slot, 0, 0, 0))
+            at = (slot,) + (0,) * (kc.ndim - 1)
+            kc = lax.dynamic_update_slice(kc, nk.astype(kc.dtype), at)
+            vc = lax.dynamic_update_slice(vc, nv.astype(vc.dtype), at)
             out.append((kc, vc))
         logits = lax.dynamic_update_slice(
             logits, new_logits.astype(logits.dtype), (slot, 0))
@@ -529,15 +530,17 @@ def _engine_fns(cfg, pad_id: int, quant: bool = False,
                 tuple(out_scales) if quant else ())
 
     def _quantize_row(x):
-        # [1, t_max, H, D] float -> (int8 values, [H] per-head scale):
-        # absmax/127 over every (position, dim) of the row, clamped so
-        # an all-zero row (fresh cache tail) divides safely
-        xf = x.astype(jnp.float32)
+        # one request's row of a cache, float -> (int8 values, [H]
+        # per-head scale): absmax/127 over every (position, dim) of the
+        # row's heads (a [1, t_max, H, D] view of this one row, not of
+        # the batch cache), clamped so an all-zero row (fresh cache
+        # tail) divides safely
+        xf = x.astype(jnp.float32).reshape(*x.shape[:2], cfg.num_heads, -1)
         s = jnp.maximum(jnp.max(jnp.abs(xf), axis=(0, 1, 3)),
                         1e-8) / 127.0                      # [H]
         q = jnp.clip(jnp.round(xf / s[None, None, :, None]),
                      -127, 127).astype(jnp.int8)
-        return q, s
+        return q.reshape(x.shape), s
 
     insert = jax.jit(insert_body,
                      donate_argnums=(0, 1, 2, 3, 4, 5, 6, 7))
@@ -608,7 +611,7 @@ class _DrafterFns(NamedTuple):
 def _drafter_fns(dcfg, pad_id: int, draft_k: int) -> _DrafterFns:
     """Compile-once LEARNED-DRAFTER programs (models/draft_lm.py) — the
     device half of batched proposal. The drafter keeps its own small
-    per-slot ring KV caches ([S, t_max, Hd, Dd] at the DRAFT model's
+    per-slot ring KV caches (`cache_shape` at the DRAFT model's
     dims, positions mirroring the target's), and `propose` turns every
     running slot's un-ingested emitted tokens into `draft_k` greedy
     proposals in ONE dispatch: a chunk ingest of the pending tokens
@@ -650,7 +653,8 @@ def _drafter_fns(dcfg, pad_id: int, draft_k: int) -> _DrafterFns:
     def init_caches(n_slots: int):
         def mk():
             return meshlib.put_with_sharding(
-                np.zeros((n_slots, t_max, dcfg.num_heads, head_dim),
+                np.zeros(cache_shape(n_slots, t_max, dcfg.num_heads,
+                                     head_dim),
                          jnp.dtype(dcfg.cache_dtype)), cache_sh)
 
         return tuple((mk(), mk()) for _ in range(dcfg.num_blocks))
@@ -715,10 +719,9 @@ def _drafter_fns(dcfg, pad_id: int, draft_k: int) -> _DrafterFns:
         # every slot, the same recycle discipline as the target insert
         out = []
         for (kc, vc), (nk, nv) in zip(caches, new_caches):
-            kc = lax.dynamic_update_slice(kc, nk.astype(kc.dtype),
-                                          (slot, 0, 0, 0))
-            vc = lax.dynamic_update_slice(vc, nv.astype(vc.dtype),
-                                          (slot, 0, 0, 0))
+            at = (slot,) + (0,) * (kc.ndim - 1)
+            kc = lax.dynamic_update_slice(kc, nk.astype(kc.dtype), at)
+            vc = lax.dynamic_update_slice(vc, nv.astype(vc.dtype), at)
             out.append((kc, vc))
         return pin(tuple(out))
 
@@ -1001,7 +1004,7 @@ class SlotEngine:
                  draft_model=None, draft_partition_rules=None):
         if n_slots < 1:
             raise ValueError(f"need n_slots >= 1, got {n_slots}")
-        # paged KV mode (ISSUE 11): the per-slot [t_max, H, D] ring
+        # paged KV mode (ISSUE 11): the per-slot [t_max, ...] ring
         # rows are replaced by a pool of kv_pages fixed-size pages plus
         # per-slot page tables — HBM holds tokens actually resident,
         # not slots' worst cases. kv_decode_reserve bounds how many
@@ -1128,10 +1131,8 @@ class SlotEngine:
 
             def _unpack(caches):
                 def grow(a):
-                    a = jnp.asarray(a)
-                    a = jnp.pad(a, ((0, 0), (0, pad_to - a.shape[1]),
-                                    (0, 0), (0, 0)))
-                    return meshlib.put_with_sharding(a, sh)
+                    return meshlib.put_with_sharding(
+                        grow_cache(jnp.asarray(a), pad_to), sh)
 
                 return jax.tree.map(grow, caches)
 
@@ -1569,10 +1570,8 @@ class SlotEngine:
         sh = cache_sharding(self._cfg.mesh)
 
         def _grow(a):
-            a = jnp.pad(jnp.asarray(np.asarray(a), self._cfg.cache_dtype),
-                        ((0, 0), (0, self.t_max - a.shape[1]),
-                         (0, 0), (0, 0)))
-            return meshlib.put_with_sharding(a, sh)
+            a = jnp.asarray(np.asarray(a), self._cfg.cache_dtype)
+            return meshlib.put_with_sharding(grow_cache(a, self.t_max), sh)
 
         caches1 = tuple((_grow(kc), _grow(vc))
                         for kc, vc in snap["caches"])
@@ -1592,11 +1591,9 @@ class SlotEngine:
         self._occupied[slot] = True
         if dsnap is not None:
             def _dgrow(a):
-                a = jnp.pad(
-                    jnp.asarray(np.asarray(a), self._dcfg.cache_dtype),
-                    ((0, 0), (0, self.t_max - a.shape[1]),
-                     (0, 0), (0, 0)))
-                return meshlib.put_with_sharding(a, sh)
+                a = jnp.asarray(np.asarray(a), self._dcfg.cache_dtype)
+                return meshlib.put_with_sharding(
+                    grow_cache(a, self.t_max), sh)
 
             drow = tuple((_dgrow(kc), _dgrow(vc))
                          for kc, vc in dsnap["caches"])

@@ -1,0 +1,112 @@
+"""The contiguous window program reads its caches where they rest (PR 30).
+
+Compiled here for a described TPU v5e (no chip: the TPU's compiler is
+installed, `jax.experimental.topologies` describes the device), at a
+rehearsal size with `gpt2-large`'s 20 heads of 64, a width
+`ring_decode.cache_shape` merges into rows. Kept apart, (heads, 64) is
+padded to whole (8, 128) tiles inside the program and re-laid at its
+edges: one whole-cache `copy` in and one out for every cache array, and
+temporaries larger than the caches. This test reads the compiled
+program itself, which is what stops a later change to the window from
+losing that again (PERF.md section 6, PR 28 and PR 30).
+
+The topology is described inside a fixture, never at import: only one
+process may hold the TPU's library, and every xdist worker imports every
+test file.
+"""
+
+import math
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from idc_models_tpu import mesh as meshlib
+from idc_models_tpu import ring_decode as rd
+from idc_models_tpu.models.lm import (_serve_config, _serving_fns,
+                                      attention_lm)
+from idc_models_tpu.observe import profile as prof
+from idc_models_tpu.serve.engine import _engine_fns
+
+# gpt2-large's heads (20 of 64: padded to 24 of 128 when kept apart) and
+# cache length, under a rehearsal-sized model
+VOCAB, T_MAX, HEADS, HEAD_DIM, BLOCKS, SLOTS, WINDOW, CHUNK = (
+    512, 1024, 20, 64, 2, 8, 8, 128)
+
+
+@pytest.fixture(scope="module")
+def chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described device is written to the persistent
+    # cache but cannot be read back without a chip: keep it out
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield Mesh([topo.devices[0]], (meshlib.SEQ_AXIS,))
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def programs(chip):
+    """(cfg, params, caches(n)) as shapes placed on the described chip."""
+    rep = NamedSharding(chip, P())
+    model = attention_lm(VOCAB, T_MAX, embed_dim=HEADS * HEAD_DIM,
+                         num_heads=HEADS, mlp_dim=512, num_blocks=BLOCKS)
+    shapes = jax.eval_shape(lambda k: model.init(k).params,
+                            jax.random.key(0))
+
+    def sds(shape, dtype, sh=rep):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+    cfg = _serve_config(shapes, embed_dim=HEADS * HEAD_DIM,
+                        num_heads=HEADS, num_blocks=BLOCKS, t_max=T_MAX,
+                        mesh=chip, cache_dtype=jnp.bfloat16)
+
+    def caches(n):
+        c = sds(rd.cache_shape(n, T_MAX, HEADS, HEAD_DIM), jnp.bfloat16,
+                rd.cache_sharding(chip))
+        return tuple((c, c) for _ in range(BLOCKS))
+
+    return cfg, jax.tree.map(lambda a: sds(a.shape, a.dtype), shapes), \
+        caches, sds
+
+
+def _whole_cache_copies(text, n):
+    """`copy` instructions of the compiled program whose result holds a
+    whole cache array's elements, in whatever shape."""
+    want = n * T_MAX * HEADS * HEAD_DIM
+    return [m.group(0) for m in
+            re.finditer(r"= \w+\[([\d,]+)\]\S* copy\(", text)
+            if math.prod(int(x) for x in m.group(1).split(",")) == want]
+
+
+def _cache_bytes(n):
+    return 2 * BLOCKS * n * T_MAX * HEADS * HEAD_DIM * 2
+
+
+def test_serve_programs_copy_no_cache_and_stage_none(programs):
+    """The decode window and the prefill chunk, in ONE test: whichever
+    xdist worker runs it is the one process that loads the TPU's
+    compiler."""
+    cfg, params, caches, sds = programs
+    i32 = sds((SLOTS,), jnp.int32)
+    win = _engine_fns(cfg, 0).window.lower(
+        params, caches(SLOTS), sds((SLOTS, VOCAB), jnp.float32),
+        sds((SLOTS, 2), jnp.uint32), i32, i32, i32, (), (), i32,
+        WINDOW).compile()
+    assert _whole_cache_copies(win.as_text(), SLOTS) == []
+    temp = prof.program_report(win, name="serve.window").temp_bytes
+    assert temp < _cache_bytes(SLOTS) / 10, (temp, _cache_bytes(SLOTS))
+    chunk = _serving_fns(cfg).prefill_chunk.lower(
+        params, caches(1), sds((1, CHUNK), jnp.int32), sds((), jnp.int32),
+        sds((), jnp.int32)).compile()
+    assert _whole_cache_copies(chunk.as_text(), 1) == []

@@ -97,7 +97,8 @@ def test_generator_prefill_and_decode_equal_the_reference(model, chunk, p_len):
     np.testing.assert_allclose(np.stack(got), want, atol=2e-4, rtol=0)
     # window layers keep a ring of W rows, full layers t_max
     assert [c[0].shape[1] for c in caches] == [T_MAX, WINDOW, WINDOW, T_MAX]
-    assert [c[0].shape[2] for c in caches] == [2, 2, 2, 2]
+    # each row the layer's 2 cached heads of 8, merged (narrow heads)
+    assert [c[0].shape[2:] for c in caches] == [(2 * 8,)] * 4
 
 
 def test_every_prompt_position_matches_the_reference(model):

@@ -200,3 +200,51 @@ def test_batched_decode_rowwise_bit_parity(devices):
              np.array([True, True]))
     with pytest.raises(ValueError, match="one position per row"):
         bdec(kc_b2, vc_b2, q, k, v, np.int32(3), np.ones(B, bool))
+
+
+@pytest.mark.parametrize("h,g,d,wrap", [
+    (20, 20, 64, False), (6, 2, 128, False), (6, 2, 8, False),
+    (20, 20, 64, True), (6, 2, 128, True),
+], ids=["gpt2_large_heads", "laguna_heads_grouped", "narrow_grouped",
+        "gpt2_large_heads_wrapped", "laguna_heads_wrapped"])
+def test_decode_over_the_stored_form_of_each_head_width(devices, h, g, d,
+                                                        wrap):
+    """The scalar fold over caches in their declared stored form
+    (`cache_shape`: heads narrower than 128 lanes merged into rows, wider
+    ones kept apart), grouped queries included, against plain causal
+    attention in float32 with the cached heads repeated; on a wrapped
+    ring of 8 rows (a window layer's: position p at row p mod 8) against
+    attention over the last 8 positions."""
+    from idc_models_tpu.ring_decode import cache_shape
+
+    t, w = 14, 8
+    rng = np.random.default_rng(h + d)
+    q = jnp.asarray(rng.normal(0, 1, (B, t, h, d)), jnp.float32)
+    k, v = (jnp.asarray(rng.normal(0, 1, (B, t, g, d)), jnp.float32)
+            for _ in range(2))
+    mesh = meshlib.seq_mesh(1 if wrap else 2)
+    rows = w if wrap else t
+    kc, vc = init_cache(mesh, B, rows, g, d, dtype=jnp.float32)
+    assert kc.shape == cache_shape(B, rows, g, d)
+    step = make_ring_decode(mesh, wrap=wrap)
+    outs = []
+    for pos in range(t):
+        tok = slice(pos, pos + 1)
+        out, kc, vc = step(kc, vc, q[:, tok], k[:, tok], v[:, tok], pos)
+        outs.append(np.asarray(out[:, 0]))
+    kr, vr = (np.repeat(np.asarray(a, np.float64), h // g, axis=2)
+              for a in (k, v))
+    for pos in range(t):
+        lo = max(0, pos - w + 1) if wrap else 0
+        s = np.einsum("bhd,bkhd->bhk", np.asarray(q[:, pos], np.float64),
+                      kr[:, lo:pos + 1]) * d ** -0.5
+        p = np.exp(s - s.max(-1, keepdims=True))
+        ref = np.einsum("bhk,bkhd->bhd", p / p.sum(-1, keepdims=True),
+                        vr[:, lo:pos + 1])
+        np.testing.assert_allclose(outs[pos], ref, rtol=1e-5, atol=1e-5)
+    # the rows hold the positions they were given, bit for bit
+    last = np.asarray(k, np.float32)[:, t - rows:]
+    got = np.asarray(kc).reshape(B, rows, g, d)
+    if wrap:
+        got = np.roll(got, -(t % w), axis=1)
+    np.testing.assert_array_equal(got, last)

@@ -525,9 +525,10 @@ def test_densenet_is_concat_free():
 # The paged engine exists so HBM stops being reserved per slot's worst
 # case; a new serve-side `zeros((..., t_max, ...))`-style KV allocation
 # would quietly reintroduce the reservation the pool replaced. The scan
-# flags allocation calls (zeros/ones/full/empty) whose literal shape
-# tuple has rank >= 3 (KV-shaped — token-id buffers are 2-D) and
-# mentions t_max anywhere inside it.
+# flags allocation calls (zeros/ones/full/empty) whose shape is a
+# literal tuple of rank >= 3 (KV-shaped — token-id buffers are 2-D) or
+# `ring_decode.cache_shape(...)`, the declared shape of a contiguous
+# cache, and mentions t_max anywhere inside it.
 
 _ALLOC_CALLS = {"zeros", "ones", "full", "empty"}
 
@@ -562,6 +563,14 @@ def _mentions_t_max(node) -> bool:
     return False
 
 
+def _kv_shaped(node) -> bool:
+    if isinstance(node, ast.Tuple):
+        return len(node.elts) >= 3
+    return (isinstance(node, ast.Call)
+            and getattr(node.func, "id", getattr(node.func, "attr", None))
+            == "cache_shape")
+
+
 def _scan_tmax_kv_allocs(path: Path):
     tree = ast.parse(path.read_text(), filename=str(path))
     rel = str(path.relative_to(REPO)).replace("\\", "/")
@@ -573,8 +582,7 @@ def _scan_tmax_kv_allocs(path: Path):
                     and isinstance(child.func, ast.Attribute)
                     and child.func.attr in _ALLOC_CALLS
                     and child.args
-                    and isinstance(child.args[0], ast.Tuple)
-                    and len(child.args[0].elts) >= 3
+                    and _kv_shaped(child.args[0])
                     and _mentions_t_max(child.args[0])):
                 key = (rel, _enclosing_path(stack))
                 live.add(key)
