@@ -15,13 +15,18 @@ contracted head by head over the block, split as the chunk fold splits
 it); `flat_heads` (merged rows, each cached head's lanes sliced out in
 place and contracted with its own query heads). The last two are what a
 single stored form would have meant for 128-wide heads, and are why
-there are two. `drop` is this tree's own stored form at each width with
-the batched fold's append spelled as ONE scatter that drops the rows
-that write nothing (no read back, no select), in place of the per-row
-read, select and update under `vmap` the tree has. us a layer and token step (decode) or a layer and chunk,
-median of 8 calls. Lines go to chiprun_out/cache_layout_probe.jsonl
-(PERF.md section 6, PR 30, has the readings). `--tiny` rehearses the
-control flow on the CPU; its times mean nothing.
+there are two. Those three run with the append PR 30 had
+(`per_row_vmap_append`), so they reproduce its table. `drop` is this
+tree as it is: its own stored form at each width and the batched fold's
+append as ONE scatter that drops the rows that write nothing (no read
+back, no select; `ring_decode._append_rows` since PR 33); `vmap` is the
+same stored form with the old append, each row's read, select and
+`dynamic_update_slice` under `vmap`: the pair is PR 33's table.
+`--variants a,b` runs only those, `decode` / `chunk` only that fold. us a
+layer and token step (decode) or a layer and chunk, median of 8 calls.
+Lines go to chiprun_out/cache_layout_probe.jsonl (PERF.md section 6,
+PRs 30 and 33, has the readings). `--tiny` rehearses the control flow
+on the CPU; its times mean nothing.
 """
 
 from __future__ import annotations
@@ -103,14 +108,19 @@ def sliced_lanes():
 
 
 @contextlib.contextmanager
-def dropping_append():
-    """Trace the batched fold with its append as one scatter whose index
-    is out of bounds for the rows that write nothing."""
+def per_row_vmap_append():
+    """Trace the batched fold with the append it had until PR 33: each
+    row reads its one position back, selects between old and new and
+    `dynamic_update_slice`s, under `vmap` (XLA expands the resulting
+    scatter into a loop of one trip a row)."""
     def append(c, t, slot, mine):
-        at = jnp.where(mine, slot, c.shape[1])
-        return c.at[np.arange(c.shape[0]), at].set(
-            t[:, 0].astype(c.dtype), mode="drop", unique_indices=True,
-            indices_are_sorted=True)
+        def row_append(c, t, s, m):
+            at = (s,) + (0,) * (c.ndim - 1)
+            old = lax.dynamic_slice(c, at, t.shape)
+            return lax.dynamic_update_slice(
+                c, jnp.where(m, t.astype(c.dtype), old), at)
+
+        return jax.vmap(row_append)(c, t, slot, mine)
 
     was, rd._append_rows = rd._append_rows, append
     try:
@@ -119,22 +129,37 @@ def dropping_append():
         rd._append_rows = was
 
 
-def variants(parent):
+def with_old_append(contraction=contextlib.nullcontext):
+    """`contraction` traced together with PR 30's append."""
+    @contextlib.contextmanager
+    def ctx():
+        with contraction(), per_row_vmap_append():
+            yield
+
+    return ctx
+
+
+def variants(parent, only=None, chunk=False):
     """(name, module, cache shape of (b, t, g, d), tracing context). The
     folds take the contraction from the cache's rank, so merged rows
-    are tried at every head width."""
+    are tried at every head width. The chunk fold splices and does not
+    append, so it skips the variants that differ in one token's path
+    alone."""
+    def flat(b, t, g, d):
+        return (b, t, g * d)
+
     out = []
     if parent is not None:
         out.append(("4d", parent, lambda b, t, g, d: (b, t, g, d),
                     contextlib.nullcontext))
-    def flat(b, t, g, d):
-        return (b, t, g * d)
-
-    out.append(("flat", rd, flat, contextlib.nullcontext))
-    out.append(("flat_heads", rd, flat, sliced_lanes))
-    out.append(("drop", rd, rd.cache_shape, dropping_append))
-    out.append(("flat_split", rd, flat, one_token_split))
-    return out
+    out.append(("flat", rd, flat, with_old_append()))
+    out.append(("flat_heads", rd, flat, with_old_append(sliced_lanes)))
+    out.append(("drop", rd, rd.cache_shape, contextlib.nullcontext))
+    if not chunk:
+        out.append(("vmap", rd, rd.cache_shape, with_old_append()))
+        out.append(("flat_split", rd, flat,
+                    with_old_append(one_token_split)))
+    return [v for v in out if only is None or v[0] in only]
 
 
 def _caches(layers, shape):
@@ -165,9 +190,9 @@ def emit(out, row):
 
 
 def probe_decode(out, parent, name, *, slots, t_max, h, g, d, qdtype,
-                 layers, frontiers, wrap=False, steps=8):
+                 layers, frontiers, wrap=False, steps=8, only=None):
     mesh = meshlib.seq_mesh(1)
-    for vname, mod, shape, ctx in variants(parent):
+    for vname, mod, shape, ctx in variants(parent, only):
         rng = np.random.default_rng(0)
         caches = _caches(layers, shape(slots, t_max, g, d))
         fold = mod.make_batched_ring_decode(mesh, jit=False, wrap=wrap)
@@ -208,9 +233,9 @@ def probe_decode(out, parent, name, *, slots, t_max, h, g, d, qdtype,
 
 
 def probe_chunk(out, parent, name, *, c, t_max, h, g, d, qdtype, layers,
-                starts, wrap=False):
+                starts, wrap=False, only=None):
     mesh = meshlib.seq_mesh(1)
-    for vname, mod, shape, ctx in variants(parent)[:-1]:  # no token here
+    for vname, mod, shape, ctx in variants(parent, only, chunk=True):
         rng = np.random.default_rng(1)
         caches = _caches(layers, shape(1, t_max, g, d))
         fold = mod.make_chunk_ring_decode(mesh, jit=False, wrap=wrap)
@@ -243,7 +268,18 @@ def main():
     parent = None
     if "--parent" in argv:
         parent = load_parent(argv[argv.index("--parent") + 1])
+    only = None
+    if "--variants" in argv:
+        only = argv[argv.index("--variants") + 1].split(",")
     models = [a for a in argv if a in ("gpt2", "laguna")]
+    folds = [a for a in argv if a in ("decode", "chunk")] or [
+        "decode", "chunk"]
+    run = {"decode": probe_decode, "chunk": probe_chunk}
+
+    def probe(fold, *a, **kw):
+        if fold in folds:
+            run[fold](*a, only=only, **kw)
+
     dev = jax.devices()[0]
     print(json.dumps({"platform": dev.platform, "kind": dev.device_kind}),
           flush=True)
@@ -256,12 +292,12 @@ def main():
             k = 16 if tiny else 1
             shape = (dict(t_max=64, h=4, g=4, d=8, layers=2) if tiny
                      else dict(t_max=1024, h=20, g=20, d=64, layers=4))
-            probe_decode(
-                out, parent, "gpt2-large", qdtype=jnp.float32,
+            probe(
+                "decode", out, parent, "gpt2-large", qdtype=jnp.float32,
                 slots=2 if tiny else 10, **shape,
                 frontiers=[f // k for f in (0, 100, 250, 400, 600, 1024)])
-            probe_chunk(
-                out, parent, "gpt2-large", qdtype=jnp.float32,
+            probe(
+                "chunk", out, parent, "gpt2-large", qdtype=jnp.float32,
                 c=8 if tiny else 128, **shape,
                 starts=[s // k for s in (0, 256, 512, 896)])
         if "laguna" in models:
@@ -270,21 +306,25 @@ def main():
                     else dict(t_max=8192, h=48, g=8, d=128, layers=2))
             ring = (dict(t_max=16, h=6, g=2, d=8, layers=1) if tiny
                     else dict(t_max=512, h=72, g=8, d=128, layers=3))
-            probe_decode(
-                out, parent, "laguna-s-2.1 full", qdtype=jnp.bfloat16,
+            probe(
+                "decode", out, parent, "laguna-s-2.1 full",
+                qdtype=jnp.bfloat16,
                 slots=2 if tiny else 48, **full,
                 frontiers=[f // k for f in (0, 1500, 4000, 5770, 8192)])
             # a ring that has wrapped is read whole: one reading
-            probe_decode(
-                out, parent, "laguna-s-2.1 window", qdtype=jnp.bfloat16,
+            probe(
+                "decode", out, parent, "laguna-s-2.1 window",
+                qdtype=jnp.bfloat16,
                 slots=2 if tiny else 48, **ring, wrap=True,
                 frontiers=[ring["t_max"] * 4])
-            probe_chunk(
-                out, parent, "laguna-s-2.1 full", qdtype=jnp.bfloat16,
+            probe(
+                "chunk", out, parent, "laguna-s-2.1 full",
+                qdtype=jnp.bfloat16,
                 c=8 if tiny else 512, **full,
                 starts=[s // k for s in (0, 1024, 4096, 7680)])
-            probe_chunk(
-                out, parent, "laguna-s-2.1 window", qdtype=jnp.bfloat16,
+            probe(
+                "chunk", out, parent, "laguna-s-2.1 window",
+                qdtype=jnp.bfloat16,
                 c=8 if tiny else 512, **ring, wrap=True,
                 starts=[s // k for s in (0, 4096)])
 

@@ -116,20 +116,21 @@ def _rows(t, cache):
 
 
 def _append_rows(c, t, slot, mine):
-    """Per-row O(1) append of one token: row b of the cache `c` reads
-    its ONE position `slot[b]` and writes `t[b]` ([1, ...]) back there
-    only where `mine[b]`; any other row is bit-untouched. (XLA expands
-    this into a loop of one trip a row, four small operations a trip:
-    a third of `gpt2-large`'s window once the cache copies were gone.
-    One scatter that drops the rows that write nothing is measured and
-    waits for the yardstick: PERF.md section 6, PR 30.)"""
-    def row_append(c, t, s, m):
-        at = (s,) + (0,) * (c.ndim - 1)
-        old = lax.dynamic_slice(c, at, t.shape)
-        return lax.dynamic_update_slice(
-            c, jnp.where(m, t.astype(c.dtype), old), at)
-
-    return jax.vmap(row_append)(c, t, slot, mine)
+    """Append of one token to every row of the batch, as ONE scatter:
+    row b of the cache `c` ([B, T, ...], either stored form, any dtype)
+    takes `t[b, 0]` at position `slot[b]` where `mine[b]`. A row that
+    writes nothing is given the index one past the end, `T`, which
+    `mode="drop"` discards: it is bit-untouched, and nothing is read
+    back or selected. A live row at `slot == T - 1` writes the last
+    position. (Spelled as a read, a select and a `dynamic_update_slice`
+    per row under `vmap`, XLA expanded the scatter into a loop of one
+    trip a row and four small operations a trip, which ran whether a
+    row wrote or not: a third of `gpt2-large`'s window. PERF.md section
+    6, PRs 30 and 33.)"""
+    at = jnp.where(mine, slot, c.shape[1])
+    return c.at[np.arange(c.shape[0]), at].set(
+        t[:, 0].astype(c.dtype), mode="drop", unique_indices=True,
+        indices_are_sorted=True)
 
 
 def _splice_rows(cache, tok, src, take_new):
@@ -516,7 +517,11 @@ def make_batched_ring_decode(mesh: Mesh, *, axis: str = meshlib.SEQ_AXIS,
     pos[b]) and `live` is bool [B]: rows with live=False append NOTHING
     — their cache shard is bit-untouched, which is what lets a finished
     serving slot idle through decode windows without corrupting the
-    cache a recycled request will overwrite. The attend/merge algebra is
+    cache a recycled request will overwrite. The append is one scatter
+    over the batch for each of the two caches (`_append_rows`): a live
+    row writes its one position on the shard that owns it, every other
+    row and every other shard an index one past the end, which is
+    dropped; no row is read back. The attend/merge algebra is
     the scalar `make_ring_decode` fold applied row-wise (same einsums,
     same masking, same two-collective softmax merge), and the attend
     stops at the live frontier: each device reads its shard in blocks
@@ -532,7 +537,7 @@ def make_batched_ring_decode(mesh: Mesh, *, axis: str = meshlib.SEQ_AXIS,
 
     Rows where live=False may carry pos == t_max (one past the end, the
     natural "finished" frontier); positions are clamped internally for
-    the attend and the masked append never fires for them. Defaults to
+    the attend and the append drops them. Defaults to
     ``jit=False`` because the intended caller is the engine's fused
     decode window, whose top-level jit owns donation.
 

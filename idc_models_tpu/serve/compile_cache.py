@@ -35,7 +35,11 @@ jax's own persistent compilation cache is a separate layer, placed
 once per process by `idc_models_tpu.runtime.setup_compile_cache`
 (`cli.main` calls it): it caches XLA IR→binary for EVERY jit in the
 process (training steps included), complementing this executable
-store, which skips tracing/lowering too.
+store, which skips tracing/lowering too. What is stored HERE is never
+taken from it (`_compiled_afresh`): an executable jax loaded from its
+own cache serializes again, on jaxlib's CPU backend, without its
+standalone kernels, and the blob then loads and dies at its first
+dispatch ("Function wrapped_iota not found").
 
 Counters (hits/misses/stores/evictions, deserialize + compile seconds)
 feed the `serve_compile_cache_*` gauges (serve/metrics.py) and the
@@ -44,6 +48,7 @@ feed the `serve_compile_cache_*` gauges (serve/metrics.py) and the
 
 from __future__ import annotations
 
+import contextlib
 import hashlib
 import json
 import os
@@ -52,6 +57,24 @@ import time
 from pathlib import Path
 
 import jax
+
+
+@contextlib.contextmanager
+def _compiled_afresh():
+    """jax's persistent compilation cache off for the compiles inside:
+    the flag is read once and remembered, so it takes a reset on both
+    sides. A compile another thread makes meanwhile misses that cache,
+    no more."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
 
 
 class CompileCache:
@@ -89,8 +112,10 @@ class CompileCache:
             # CPU, so they must key out, not load. Schema 3: the window
             # program returns the attention's rows read as one more
             # result. Schema 4: the programs take their caches in the
-            # stored form `ring_decode.cache_shape` declares
-            "schema": 4,
+            # stored form `ring_decode.cache_shape` declares. Schema 5:
+            # the window appends with one scatter; a blob of schema 4
+            # would load and give the same tokens a third slower
+            "schema": 5,
             "jax": jax.__version__,
             "jaxlib": jax.lib.__version__,
             "backend": jax.default_backend(),
@@ -151,7 +176,8 @@ class CompileCache:
         from jax.experimental import serialize_executable as se
 
         t0 = time.perf_counter()
-        exe = lowered.compile()
+        with _compiled_afresh():
+            exe = lowered.compile()
         dt = time.perf_counter() - t0
         self.compile_s += dt
         payload, in_tree, out_tree = se.serialize(exe)

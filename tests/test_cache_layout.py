@@ -93,10 +93,51 @@ def _cache_bytes(n):
     return 2 * BLOCKS * n * T_MAX * HEADS * HEAD_DIM * 2
 
 
+def _append_loops(text, n):
+    """`while` instructions of the compiled program that are a scatter
+    expanded into a loop over a whole cache array: either the loop's
+    `op_name` names a `scatter`, or its state is what such a loop
+    carries, ONE array of a whole cache's elements beside one new row a
+    slot (`n * HEADS * HEAD_DIM` elements). The window's own loop
+    carries every cache and the attend's loop over blocks a layer's
+    two: neither matches."""
+    row = n * HEADS * HEAD_DIM
+    found = []
+    for m in re.finditer(r"^.*? = \((.*?)\) while\(.*$", text, re.M):
+        sizes = [math.prod(int(x) for x in dims.split(","))
+                 for dims in re.findall(r"\w+\[([\d,]+)\]", m.group(1))]
+        if sizes.count(row * T_MAX) and (
+                "scatter" in m.group(0)
+                or (sizes.count(row * T_MAX) == 1 and row in sizes)):
+            found.append(m.group(0)[:200])
+    return found
+
+
 def test_serve_programs_copy_no_cache_and_stage_none(programs):
     """The decode window and the prefill chunk, in ONE test: whichever
     xdist worker runs it is the one process that loads the TPU's
-    compiler."""
+    compiler.
+
+    Since PR 33 it also fails when the append's expansion is back
+    (`_append_loops`). What it matches, in the window compiled at this
+    size from the tree before PR 33: four instructions, one for each of
+    the two layers' K and V caches,
+
+        %while.142 = (s32[], bf16[8,1024,1280], s32[8,3],
+                      bf16[8,1,1280], s32[], s32[3], s32[])
+            while(...), condition=%wide.while_cond.1,
+            body=%wide.while_body.1.sunk,
+            op_name=".../attn_full/vmap(vmap())/scatter"
+
+    the per-row read, select and `dynamic_update_slice` under `vmap`,
+    which XLA turns into a scatter and its scatter expander into a loop
+    of one trip a slot (8 here, 10 in the `gpt2-large` cells) over the
+    whole cache array, four small operations a trip on each of the
+    window's token steps. With the append as one dropping scatter the
+    program holds none: each cache's scatter is one fusion
+    (`kind=kCustom`, `op_name=".../attn_full/scatter"`), and the only
+    loops left that carry a cache are the window's own and the
+    attend's over blocks."""
     cfg, params, caches, sds = programs
     i32 = sds((SLOTS,), jnp.int32)
     win = _engine_fns(cfg, 0).window.lower(
@@ -104,6 +145,7 @@ def test_serve_programs_copy_no_cache_and_stage_none(programs):
         sds((SLOTS, 2), jnp.uint32), i32, i32, i32, (), (), i32,
         WINDOW).compile()
     assert _whole_cache_copies(win.as_text(), SLOTS) == []
+    assert _append_loops(win.as_text(), SLOTS) == []
     temp = prof.program_report(win, name="serve.window").temp_bytes
     assert temp < _cache_bytes(SLOTS) / 10, (temp, _cache_bytes(SLOTS))
     chunk = _serving_fns(cfg).prefill_chunk.lower(

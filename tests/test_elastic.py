@@ -243,6 +243,46 @@ def test_warm_replica_spinup_hits_cache(params, tmp_path):
     router.close()
 
 
+def test_stored_blobs_are_compiled_afresh(tmp_path):
+    """A blob is never made from an executable that jax took out of its
+    OWN persistent cache, nor is its compile put there: serialized
+    again, an executable loaded from that cache loses its standalone
+    kernels on jaxlib's CPU backend, and in a process that has compiled
+    no kernel of the name itself the blob loads and then dies at its
+    first dispatch ("Function wrapped_iota not found": three tests of
+    this file, whenever a loaded machine took over the half second the
+    suite admits a compile to jax's cache at). So: with EVERY compile
+    admitted, the store's compile leaves no entry in jax's cache, runs
+    with that cache off, and leaves it on for everyone else."""
+    import pathlib
+
+    def afresh_probe(x):
+        return x * 3 + 1
+
+    lowered = jax.jit(afresh_probe).lower(jnp.zeros((3,), jnp.float32))
+    during = []
+
+    class Spy:
+        def compile(self):
+            during.append(jax.config.jax_enable_compilation_cache)
+            return lowered.compile()
+
+    jax_cache = pathlib.Path(jax.config.jax_compilation_cache_dir)
+    was = jax.config.jax_persistent_cache_min_compile_time_secs
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    try:
+        cc = CompileCache(tmp_path)
+        key = cc.key(program="afresh_probe", fingerprint={})
+        exe = cc.compile_and_store(key, Spy())
+    finally:
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", was)
+    assert during == [False] and jax.config.jax_enable_compilation_cache
+    assert not list(jax_cache.glob("*afresh_probe*"))
+    np.testing.assert_array_equal(
+        np.asarray(exe(jnp.ones((3,), jnp.float32))), 4.0)
+    assert CompileCache(tmp_path).load(key, devices=DEV0) is not None
+
+
 # -- 3. live slot migration -------------------------------------------------
 
 

@@ -248,3 +248,106 @@ def test_decode_over_the_stored_form_of_each_head_width(devices, h, g, d,
     if wrap:
         got = np.roll(got, -(t % w), axis=1)
     np.testing.assert_array_equal(got, last)
+
+
+def _bits(a):
+    """The stored bits of a cache, whatever its dtype."""
+    a = np.asarray(a)
+    return a.view({1: np.int8, 2: np.uint16, 4: np.uint32}[a.itemsize])
+
+
+@pytest.mark.parametrize("wrap", [False, True], ids=["plain", "wrap"])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.int8],
+                         ids=["bf16", "int8"])
+@pytest.mark.parametrize("d", [64, 128], ids=["merged", "by_head"])
+def test_append_is_one_dropping_scatter(devices, d, dtype, wrap):
+    """The batched fold's append (`_append_rows`) against the plain
+    per-row reference (`ref[b, slot[b]] = t[b, 0]` where row b writes),
+    bit for bit, over both stored forms, both cache dtypes, a contiguous
+    cache and a wrapped ring: dead rows untouched, a live row at
+    `slot == T - 1` written (the dropped index is T, not T - 1), dead
+    rows at `pos == t_max` harmless, all rows dead = the cache as it
+    was; then the same through `make_batched_ring_decode` wherever the
+    fold takes the combination (a wrapped ring has no int8 form), and on
+    a two-device ring, where only the owner's shard changes."""
+    from idc_models_tpu import ring_decode as rd
+
+    b, t, g = 5, 8, 2
+    int8 = dtype == jnp.int8
+    rng = np.random.default_rng(d + 7 * int8 + wrap)
+    shape = rd.cache_shape(b, t, g, d)
+    assert len(shape) == (3 if d == 64 else 4)
+
+    def cache():
+        if int8:
+            return jnp.asarray(rng.integers(-127, 128, shape), jnp.int8)
+        return jnp.asarray(rng.normal(0, 1, shape), jnp.bfloat16)
+
+    def token():
+        return jnp.asarray(rng.normal(0, 1, (b, 1, g, d)), jnp.float32)
+
+    def reference(c, rows, pos, live):
+        # the fold's own slot arithmetic, spelled per row on the host
+        ref = np.array(c)
+        for r in range(b):
+            if live[r]:
+                ref[r, pos[r] % t] = np.asarray(rows)[r, 0]
+        return ref
+
+    # positions: a live row at the last row, live rows elsewhere, dead
+    # rows at the finished frontier (t_max) and inside the cache
+    base = 2 * t if wrap else 0
+    pos = np.array([base + 1, base + t - 1, t, base + 4, 3], np.int32)
+    live = np.array([True, True, False, True, False])
+    slot = jnp.asarray((np.maximum(pos, 0) if wrap
+                        else np.clip(pos, 0, t - 1)) % t, jnp.int32)
+
+    # 1. the append itself
+    c, tok = cache(), token()
+    rows = rd._rows(tok * (40 if int8 else 1), c).astype(dtype)
+    append = jax.jit(rd._append_rows)
+    got = append(c, rows, slot, jnp.asarray(live))
+    assert got.dtype == c.dtype and got.shape == c.shape
+    np.testing.assert_array_equal(_bits(got),
+                                  _bits(reference(c, rows, pos, live)))
+    assert (_bits(got)[1, t - 1] == _bits(rows)[1, 0]).all()
+    for dead in (2, 4):
+        np.testing.assert_array_equal(_bits(got)[dead], _bits(c)[dead])
+    none = append(c, rows, slot, jnp.zeros(b, bool))
+    np.testing.assert_array_equal(_bits(none), _bits(c))
+
+    if wrap and int8:
+        return
+    # 2. through the fold, on one device and on a ring of two
+    for n_dev in ((1,) if wrap else (1, 2)):
+        mesh = meshlib.seq_mesh(n_dev)
+        place = lambda a: jax.device_put(a, cache_sharding(mesh))
+        kc0, vc0 = cache(), cache()
+        q, kt, vt = token(), token(), token()
+        scales = ()
+        if int8:
+            scales = tuple(jnp.asarray(rng.uniform(0.02, 0.05, (b, g)),
+                                       jnp.float32) for _ in range(2))
+
+        def stored(tok, c, scale=None):
+            if not int8:
+                return rd._rows(tok, c).astype(dtype)
+            qz = np.clip(np.round(np.asarray(tok)
+                                  / np.asarray(scale)[:, None, :, None]),
+                         -127, 127)
+            return rd._rows(jnp.asarray(qz), c).astype(dtype)
+
+        fold = jax.jit(rd.make_batched_ring_decode(mesh, quantized=int8,
+                                                   wrap=wrap))
+        _, kc, vc = fold(place(kc0), place(vc0), q, kt, vt, pos, live,
+                         *scales)
+        for new, old, tok, sc in ((kc, kc0, kt, scales[:1]),
+                                  (vc, vc0, vt, scales[1:])):
+            want = reference(old, stored(tok, old, *sc), pos, live)
+            np.testing.assert_array_equal(_bits(new), _bits(want))
+        assert kc.sharding.is_equivalent_to(cache_sharding(mesh), kc.ndim)
+        _, kc, vc = fold(place(kc0), place(vc0), q, kt, vt,
+                         np.full(b, t, np.int32), np.zeros(b, bool),
+                         *scales)
+        np.testing.assert_array_equal(_bits(kc), _bits(kc0))
+        np.testing.assert_array_equal(_bits(vc), _bits(vc0))
