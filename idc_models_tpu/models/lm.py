@@ -152,6 +152,18 @@ class Rotary(NamedTuple):
     attention_factor: float = 1.0
 
 
+class Indexer(NamedTuple):
+    """A layer's learned choice of the positions it attends: `heads`
+    index queries of `dim` over ONE shared index key a position (cached
+    beside K/V), scored ``sum_j w_j relu(q_j . k)``; a query attends the
+    `topk` best-scoring positions at or before its own, all of them
+    while there are no more than that."""
+    heads: int
+    dim: int
+    topk: int
+    rotary: Rotary | None = None    # on index query and index key
+
+
 class LayerSpec(NamedTuple):
     """One block of the served model, as data."""
     heads: int                      # query heads
@@ -162,6 +174,8 @@ class LayerSpec(NamedTuple):
     rotary: Rotary | None = None
     ffn: str = "gelu"               # "gelu" (biased MLP) | "swiglu" | "experts"
     experts: moe.Experts | None = None
+    qk_norm: bool = False           # RMSNorm over each head of q and k
+    indexer: Indexer | None = None  # None: attends every visible position
 
 
 class ModelSpec(NamedTuple):
@@ -185,7 +199,8 @@ class ModelSpec(NamedTuple):
         return (self.norm == "layernorm" and self.learned_pos
                 and all(l.heads == l.kv_heads and l.window is None
                         and not l.gate and l.rotary is None
-                        and l.ffn == "gelu"
+                        and l.ffn == "gelu" and not l.qk_norm
+                        and l.indexer is None
                         and l.heads * l.head_dim == self.embed_dim
                         for l in self.layers))
 
@@ -195,9 +210,26 @@ class ModelSpec(NamedTuple):
                 f"{mechanism} cannot serve this layer spec: it holds one "
                 f"cache class of one head count at learned positions "
                 f"(attention_lm's block); window layers, grouped-query "
-                f"heads, rotary positions and expert layers run on the "
-                f"contiguous chunked-prefill engine only (ROADMAP "
-                f"queue B)")
+                f"heads, rotary positions, expert layers and an "
+                f"indexer's choice of positions run on the contiguous "
+                f"chunked-prefill engine only (ROADMAP queue B)")
+
+    @property
+    def sparse(self) -> bool:
+        """True when some layer has an indexer: its cache holds index
+        keys beside K/V, and the engine prefills such a model into the
+        reserved slot's own rows."""
+        return any(l.indexer is not None for l in self.layers)
+
+    def require_dense(self, program: str) -> None:
+        """The serial `Generator`'s programs (`_serving_fns`) hold a
+        request's OWN cache row of two arrays a layer and know no
+        indexer: it refuses such a spec at its door, by name."""
+        if self.sparse:
+            raise ValueError(
+                f"{program} serves no layer with an indexer: such a "
+                f"model runs on the slot engine's window and in-place "
+                f"chunk programs only (serve.LMServer, serve.SlotEngine)")
 
     def cache_len(self, i: int, t_max: int) -> int:
         """Rows of layer i's cache: t_max, or a window layer's ring."""
@@ -273,6 +305,48 @@ def laguna_spec(config: dict, *, held: tuple | None = None,
                      param_dtype=param_dtype)
 
 
+def keye_spec(config: dict, *, held: tuple | None = None,
+              param_dtype: str = "bfloat16") -> ModelSpec:
+    """The spec of the language model of a `model_type: KeyeVL2`
+    checkpoint from its `config.json` keys (Kwai-Keye/Keye-VL-2.0-
+    30B-A3B): identical layers of RMSNorm, grouped-query heads with a
+    per-head RMSNorm on q and k, plain rotary over the whole head (text
+    positions make `mrope_section` plain), `sa_config`'s indexer, and
+    softmax-routed SwiGLU experts with no shared one. `held = (first,
+    count)` is this chip's share of each expert layer (default: all
+    `num_experts`). What `config.json` does not state, and this takes as
+    read: q and k are normed per head, the indexer reads the normed
+    hidden state, its key is LayerNormed, and index query and key are
+    rotated over their whole width at the model's theta."""
+    for key, want in (("norm_topk_prob", True), ("attention_bias", False),
+                      ("mlp_only_layers", []), ("decoder_sparse_step", 1),
+                      ("use_sliding_window", False)):
+        if config.get(key, want) != want:
+            raise ValueError(f"keye_spec knows {key}={want!r} alone, "
+                             f"the config states {config[key]!r}")
+    sa = config["sa_config"]
+    if sa.get("indexer_num_kv_heads", 1) != 1:
+        raise ValueError("keye_spec knows one shared index key alone")
+    d, n = config["head_dim"], config["num_experts"]
+    first, count = held if held is not None else (0, n)
+    if not 0 <= first <= first + count <= n:
+        raise ValueError(f"held experts [{first}, {first + count}) lie "
+                         f"outside the router's {n}")
+    theta = float(config["rope_theta"])
+    layer = LayerSpec(
+        config["num_attention_heads"], config["num_key_value_heads"], d,
+        rotary=Rotary(theta, d), ffn="experts", qk_norm=True,
+        experts=moe.Experts(n, config["num_experts_per_tok"], first, count,
+                            routed_scale=1.0, shared=False),
+        indexer=Indexer(sa["indexer_num_heads"], sa["indexer_head_dim"],
+                        sa["topk"],
+                        rotary=Rotary(theta, sa["indexer_head_dim"])))
+    return ModelSpec(config["hidden_size"],
+                     (layer,) * config["num_hidden_layers"], norm="rmsnorm",
+                     norm_eps=config["rms_norm_eps"], learned_pos=False,
+                     param_dtype=param_dtype)
+
+
 def init_params(spec: ModelSpec, vocab_size: int, rng, *,
                 seq_len: int = 0, mlp_dim: int = 0,
                 expert_dim: int = 0):
@@ -286,13 +360,18 @@ def init_params(spec: ModelSpec, vocab_size: int, rng, *,
     dt = jnp.dtype(spec.param_dtype)
     e = spec.embed_dim
     keys = iter(jax.random.split(rng, 16 * len(spec.layers) + 8))
+    if any(l.qk_norm or l.indexer for l in spec.layers):
+        # a stream of its own for what those layers add, so that every
+        # other spec draws the keys it always drew
+        more = iter(jax.random.split(jax.random.fold_in(rng, 1),
+                                     8 * len(spec.layers)))
 
-    def mat(*shape):
-        return (jax.random.normal(next(keys), shape, jnp.float32)
+    def mat(*shape, of=None):
+        return (jax.random.normal(next(of or keys), shape, jnp.float32)
                 / np.sqrt(shape[-2])).astype(dt)
 
-    def near(value, n):
-        return (value + 0.02 * jax.random.normal(next(keys), (n,),
+    def near(value, n, of=None):
+        return (value + 0.02 * jax.random.normal(next(of or keys), (n,),
                                                  jnp.float32)).astype(dt)
 
     def norm():
@@ -317,6 +396,16 @@ def init_params(spec: ModelSpec, vocab_size: int, rng, *,
         if l.gate:
             mha["wg"] = mat(e, l.heads)
         block = {"ln1": norm(), "mha": mha, "ln2": norm()}
+        if l.qk_norm:
+            mha["q_norm"] = near(1.0, l.head_dim, of=more)
+            mha["k_norm"] = near(1.0, l.head_dim, of=more)
+        if l.indexer is not None:
+            x = l.indexer
+            block["idx"] = {
+                "wq": mat(e, x.heads * x.dim, of=more),
+                "wk": mat(e, x.dim, of=more), "ww": mat(e, x.heads, of=more),
+                "k_norm": {"scale": near(1.0, x.dim, of=more),
+                           "bias": near(0.0, x.dim, of=more)}}
         if l.ffn == "gelu":
             mha["bo"] = near(0.0, e)
             block["fc1"] = {"kernel": mat(e, mlp_dim),
@@ -583,10 +672,15 @@ def _norm(spec: ModelSpec, p, x):
     if spec.norm == "layernorm":
         return core.layer_norm(spec.embed_dim,
                                eps=spec.norm_eps).apply(p, {}, x)[0]
+    return _head_norm(x, p["scale"], spec.norm_eps)
+
+
+def _head_norm(x, scale, eps):
+    """RMSNorm over the last axis (one head's width), statistics in
+    float32: the per-head norm of q and k."""
     xf = x.astype(jnp.float32)
-    y = xf * lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
-                       + spec.norm_eps)
-    return (y * p["scale"].astype(jnp.float32)).astype(x.dtype)
+    y = xf * lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True) + eps)
+    return (y * scale.astype(jnp.float32)).astype(x.dtype)
 
 
 def _project_qkv(spec: ModelSpec, l: LayerSpec, p, h, seq_shape: tuple,
@@ -606,6 +700,9 @@ def _project_qkv(spec: ModelSpec, l: LayerSpec, p, h, seq_shape: tuple,
     q = split(a @ p["mha"]["wq"].astype(a.dtype), l.heads)
     k = split(a @ p["mha"]["wk"].astype(a.dtype), l.kv_heads)
     v = split(a @ p["mha"]["wv"].astype(a.dtype), l.kv_heads)
+    if l.qk_norm:
+        q = _head_norm(q, p["mha"]["q_norm"], spec.norm_eps)
+        k = _head_norm(k, p["mha"]["k_norm"], spec.norm_eps)
     if l.rotary is not None:
         q, k = _rope(q, pos, l.rotary), _rope(k, pos, l.rotary)
     gate = None
@@ -614,6 +711,27 @@ def _project_qkv(spec: ModelSpec, l: LayerSpec, p, h, seq_shape: tuple,
             (a @ p["mha"]["wg"].astype(a.dtype)).astype(jnp.float32))
         gate = gate.reshape(b, *seq_shape, l.heads, 1)
     return q, k, v, gate
+
+
+def _project_index(spec: ModelSpec, l: LayerSpec, p, h, seq_shape: tuple,
+                   pos):
+    """The indexer's projections of one block, from the same normed
+    state q/k/v read: index queries [.., J, DI] and the one index key
+    [.., 1, DI] (LayerNormed, statistics in float32), both rotated at
+    `pos`, and the heads' weights [.., J] in float32. One definition
+    for the decode step and the prefill chunk."""
+    x = l.indexer
+    b = h.shape[0]
+    a = _norm(spec, p["ln1"], h)
+    qi = (a @ p["idx"]["wq"].astype(a.dtype)).reshape(
+        b, *seq_shape, x.heads, x.dim)
+    ki = core.layer_norm(x.dim, eps=1e-6).apply(
+        p["idx"]["k_norm"], {}, a @ p["idx"]["wk"].astype(a.dtype))[0]
+    ki = ki.reshape(b, *seq_shape, 1, x.dim)
+    if x.rotary is not None:
+        qi, ki = _rope(qi, pos, x.rotary), _rope(ki, pos, x.rotary)
+    w = (a @ p["idx"]["ww"].astype(a.dtype)).astype(jnp.float32)
+    return qi, ki, w.reshape(b, *seq_shape, x.heads)
 
 
 def _attn_residual(p, h, o, gate=None):
@@ -650,18 +768,52 @@ def _ffn_residual(spec: ModelSpec, l: LayerSpec, p, h, live=None,
 def _layer_forward(cfg, l: LayerSpec, p, h, seq_shape, pos, attend,
                    live=None):
     """One block on h [B, E] (seq_shape (1,)) or [B, C, E] ((C,)):
-    projection, `attend(q, k, v) -> (o, kc, vc)` (the cache fold,
-    closed over the layer's cache), the two residuals. Returns
-    (h, kc, vc, stats)."""
+    projection, the cache fold `attend` (closed over the layer's
+    caches), the two residuals. `attend(q, k, v) -> (o, *caches)`; for a
+    layer with an indexer `attend(q, k, v, index) -> (o, *caches,
+    account)`, `index` its projections (`_project_index`) and `account`
+    what the fold says of its selection, which joins the layer's
+    statistics. Returns (h, caches, stats)."""
     spec = cfg.spec
-    with jax.named_scope("attn_full" if l.window is None
-                         else "attn_window"):
-        q, k, v, gate = _project_qkv(spec, l, p, h, seq_shape, pos)
-        o, kc, vc = attend(q, k, v)
-        h = _attn_residual(p, h, o, gate)
+    if l.indexer is None:
+        account = None
+        with jax.named_scope("attn_full" if l.window is None
+                             else "attn_window"):
+            q, k, v, gate = _project_qkv(spec, l, p, h, seq_shape, pos)
+            o, *caches = attend(q, k, v)
+            h = _attn_residual(p, h, o, gate)
+    else:
+        # the fold names its own scopes: dsa_index, dsa_select and,
+        # like the projections around it, attn_sparse
+        with jax.named_scope("attn_sparse"):
+            q, k, v, gate = _project_qkv(spec, l, p, h, seq_shape, pos)
+        with jax.named_scope("dsa_index"):
+            index = _project_index(spec, l, p, h, seq_shape, pos)
+        o, *caches, account = attend(q, k, v, index)
+        with jax.named_scope("attn_sparse"):
+            h = _attn_residual(p, h, o, gate)
     h, stats = _ffn_residual(spec, l, p, h, live,
                              meshlib.pallas_interpret(cfg.mesh))
-    return h, kc, vc, stats
+    if account is not None:
+        stats = {**(stats or {}), **account}
+    return h, tuple(caches), stats
+
+
+def sparse_window_stats(stats):
+    """A decode window's account of its indexer layers, from the
+    per-step records a scan stacked ([W, ...] leaves): ``dsa_selected``
+    [W, layers, S, topk] the positions every slot attended (-1 where it
+    saw fewer), ``dsa_share_sum`` the sum over live (step, slot) pairs
+    of selected over visible positions, ``dsa_rows`` how many pairs
+    (both of the first such layer: they all select alike). {} without
+    such layers."""
+    stats = [st for st in stats if "selected" in st]
+    if not stats:
+        return {}
+    return {"dsa_selected": jnp.stack([st["selected"] for st in stats],
+                                      axis=1),
+            "dsa_share_sum": jnp.sum(stats[0]["sel_share"]),
+            "dsa_rows": jnp.sum(stats[0]["sel_rows"])}
 
 
 def _final_logits(spec: ModelSpec, params, h):
@@ -729,7 +881,9 @@ def _token_forward(cfg: _ServeConfig, params, caches, tok, pos, fold,
     per-slot positions) — the position-table gather and the rotary
     angles broadcast either way.
     `fold(block_idx, kc, vc, q, k, v) -> (o, kc, vc)` supplies the
-    cache fold, so the serial scalar-pos path and the engine's masked
+    cache fold (for a layer with an indexer: `fold(block_idx, kc, vc,
+    ic, q, k, v, index) -> (o, kc, vc, ic, account)`, see
+    `_layer_forward`), so the serial scalar-pos path and the engine's masked
     per-row path share every other op bit-for-bit. The fold contract
     is deliberately cache-layout-agnostic: the PAGED engine passes
     per-block (k_pool, v_pool) pairs and a page-table-indirect fold
@@ -745,12 +899,10 @@ def _token_forward(cfg: _ServeConfig, params, caches, tok, pos, fold,
     rows = jnp.asarray(pos, jnp.int32).reshape(-1, 1)
     new_caches, stats = [], []
     for i, l in enumerate(spec.layers):
-        kc, vc = caches[i]
-        h, kc, vc, st = _layer_forward(
+        h, cache, st = _layer_forward(
             cfg, l, params[f"block{i}"], h, (1,), rows,
-            lambda q, k, v, _i=i, _kc=kc, _vc=vc: fold(_i, _kc, _vc, q, k, v),
-            live)
-        new_caches.append((kc, vc))
+            lambda *qkv, _i=i: fold(_i, *caches[_i], *qkv), live)
+        new_caches.append(cache)
         if st is not None:
             stats.append(st)
     logits = _final_logits(spec, params, h)
@@ -780,13 +932,51 @@ def _chunk_batch_forward(cfg: _ServeConfig, params, caches, toks, pos,
         jnp.clip(idx, 0, params["pos"].shape[0] - 1)])
     new_caches = []
     for i, l in enumerate(spec.layers):
-        kc, vc = caches[i]
-        h, kc, vc, _ = _layer_forward(
+        h, cache, _ = _layer_forward(
             cfg, l, params[f"block{i}"], h, (c,), idx,
-            lambda q, k, v, _i=i, _kc=kc, _vc=vc: fold(_i, _kc, _vc, q, k, v))
-        new_caches.append((kc, vc))
+            lambda q, k, v, _i=i: fold(_i, *caches[_i], q, k, v))
+        new_caches.append(cache)
     logits = _final_logits(spec, params, h)              # [B, C, V]
     return logits, tuple(new_caches)
+
+
+def _chunk_forward(cfg: _ServeConfig, params, caches, tokens, start, p_end,
+                   fold):
+    """One prompt CHUNK of one request through every block: `tokens`
+    [B, C] at positions [start, start + C), of which those below `p_end`
+    are real (both traced). `fold` as in `_token_forward`, closed over
+    whatever else it needs (the chunk's span, the batch row it writes).
+    Returns (logits of the last real position [B, V], caches, the
+    layers' statistics): the one definition behind the chunk program of
+    a request's own cache row (`_serving_fns`) and the engine's chunk
+    program that writes a slot's rows in place."""
+    spec = cfg.spec
+    c = tokens.shape[1]
+    h = _embed(spec, params, tokens,
+               lambda: lax.dynamic_slice_in_dim(params["pos"], start,
+                                                c, axis=0))
+    rows = (start + jnp.arange(c, dtype=jnp.int32))[None, :]
+    new_caches, stats = [], []
+    for i, l in enumerate(spec.layers):
+        h, cache, st = _layer_forward(
+            cfg, l, params[f"block{i}"], h, (c,), rows,
+            lambda *qkv, _i=i: fold(_i, *caches[_i], *qkv))
+        new_caches.append(cache)
+        if st is not None:
+            stats.append(st)
+    # logits of the LAST REAL position in this chunk (p_end is
+    # traced -> dynamic gather); intermediate chunks' logits are
+    # discarded by the caller, the final chunk's seed decode
+    h_last = lax.dynamic_slice_in_dim(h, p_end - start - 1, 1,
+                                      axis=1)[:, 0]
+    return _final_logits(spec, params, h_last), tuple(new_caches), stats
+
+
+def chunk_picks(stats, b: int, c: int):
+    """The router's picks of a chunk [expert layers, B, C, k] from the
+    layers' statistics, () without expert layers."""
+    picks = [st["picks"].reshape(b, c, -1) for st in stats if "picks" in st]
+    return jnp.stack(picks) if picks else ()
 
 
 @functools.lru_cache(maxsize=16)
@@ -895,35 +1085,18 @@ def _serving_fns(cfg: _ServeConfig) -> _ServeFns:
         # result is () or, for a model with expert layers, the router's
         # picks at the chunk's positions, [expert layers, B, C, k].
         b, c = tokens.shape
-        h = _embed(spec, params, tokens,
-                   lambda: lax.dynamic_slice_in_dim(params["pos"], start,
-                                                    c, axis=0))
-        rows = (start + jnp.arange(c, dtype=jnp.int32))[None, :]
-        new_caches, picks = [], []
-        for i, l in enumerate(spec.layers):
-            kc, vc = caches[i]
-            h, kc, vc, st = _layer_forward(
-                cfg, l, params[f"block{i}"], h, (c,), rows,
-                lambda q, k, v, _w=wraps[i], _kc=kc, _vc=vc:
-                chunk_fold[_w](_kc, _vc, q, k, v, start, p_end))
-            new_caches.append((kc, vc))
-            if st is not None:
-                picks.append(st["picks"].reshape(b, c, -1))
-        # logits of the LAST REAL position in this chunk (p_end is
-        # traced -> dynamic gather); intermediate chunks' logits are
-        # discarded by the caller, the final chunk's seed decode
-        h_last = lax.dynamic_slice_in_dim(h, p_end - start - 1, 1,
-                                          axis=1)[:, 0]
-        logits = _final_logits(spec, params, h_last)
+        logits, new_caches, stats = _chunk_forward(
+            cfg, params, caches, tokens, start, p_end,
+            lambda i, kc, vc, q, k, v: chunk_fold[wraps[i]](
+                kc, vc, q, k, v, start, p_end))
         sh = cache_sharding(mesh)
         # pin the outgoing caches to the canonical sharding spelling so
         # chunk -> chunk -> insert chains reuse one jit cache entry per
         # program (same discipline as the engine's pin_state)
         new_caches = tuple(
-            (lax.with_sharding_constraint(kc, sh),
-             lax.with_sharding_constraint(vc, sh))
-            for kc, vc in new_caches)
-        return logits, new_caches, (jnp.stack(picks) if picks else ())
+            tuple(lax.with_sharding_constraint(c_, sh) for c_ in cache)
+            for cache in new_caches)
+        return logits, new_caches, chunk_picks(stats, b, c)
 
     prefill_chunk = jax.jit(chunk_body, donate_argnums=(1,))
 
@@ -1077,6 +1250,7 @@ class Generator:
         if prefill_chunk is None:
             self._cfg.spec.require_classic("the monolithic ring prefill "
                                            "(prefill_chunk=None)")
+        self._cfg.spec.require_dense("the serial Generator")
         self._fns = _serving_fns(self._cfg)
         # partition_rules shard the params over the mesh's weight axes
         # ("model"/"data" — registry.LM_RULES) while the KV caches keep

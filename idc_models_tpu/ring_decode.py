@@ -263,7 +263,7 @@ def _trips(frontier, row0, t_shard: int, blk: int):
 
 
 def _attend_to_frontier(q, kc, vc, see, frontier, blk: int, *, scale,
-                        row0=0, k_scale=None):
+                        row0=0, k_scale=None, row=None):
     """Local attend of q [B, H, D] or [B, C, H, D] over the resident
     shard kc / vc [B, t_shard, ...] (either form of `cache_shape`), read
     in blocks of `blk` rows up to `frontier` (a global row count,
@@ -278,8 +278,16 @@ def _attend_to_frontier(q, kc, vc, see, frontier, blk: int, *, scale,
     it with no loop, whatever the frontier: the fold as it was before
     blocks existed, bit for bit. Otherwise blocks are merged by the
     algebra that merges the shards of a ring: running maximum, both
-    sides rescaled by exp(m - m_new)."""
+    sides rescaled by exp(m - m_new). With `row` (traced) the caches are
+    a batch's and q is ONE request's: only batch row `row` is read,
+    block by block, and never copied out whole."""
     t_shard, d = kc.shape[1], q.shape[-1]
+
+    def take(c, at, n):
+        if row is None:
+            return lax.dynamic_slice_in_dim(c, at, n, axis=1)
+        return lax.dynamic_slice(c, (row, at) + (0,) * (c.ndim - 2),
+                                 (1, n) + c.shape[2:])
 
     def block(kb, vb, g):
         # f32 accumulation by preferred_element_type, NOT astype:
@@ -299,6 +307,8 @@ def _attend_to_frontier(q, kc, vc, see, frontier, blk: int, *, scale,
 
     rows = jnp.arange(blk, dtype=jnp.int32)
     if t_shard <= blk:
+        if row is not None:
+            kc, vc = take(kc, 0, t_shard), take(vc, 0, t_shard)
         return block(kc, vc, row0 + rows)
     if t_shard % blk:
         raise ValueError(f"block of {blk} rows does not divide the "
@@ -307,8 +317,7 @@ def _attend_to_frontier(q, kc, vc, see, frontier, blk: int, *, scale,
 
     def body(j, carry):
         m, l, acc = carry
-        kb = lax.dynamic_slice_in_dim(kc, j * blk, blk, axis=1)
-        vb = lax.dynamic_slice_in_dim(vc, j * blk, blk, axis=1)
+        kb, vb = take(kc, j * blk, blk), take(vc, j * blk, blk)
         mb, lb, ab = block(kb, vb, row0 + j * blk + rows)
         m_new = jnp.maximum(m, mb)
         old, new = jnp.exp(m - m_new), jnp.exp(mb - m_new)
@@ -1444,6 +1453,305 @@ def make_chunk_ring_decode(mesh: Mesh, *, axis: str = meshlib.SEQ_AXIS,
     if not jit:
         return checked
     return jax.jit(checked, donate_argnums=(0, 1))
+
+
+# -- learned sparse attention: an indexer picks the positions a query
+# attends, from index keys cached beside K/V --------------------------
+
+# rows of index keys one block of the indexer's scores reads
+_INDEX_BLOCK = 2048
+
+
+def _one_device(mesh: Mesh, axis: str) -> None:
+    if mesh.shape[axis] > 1:
+        raise ValueError(
+            f"the sparse-attention folds select over a slot's whole "
+            f"cache on one device; the sequence ring on mesh axis "
+            f"{axis!r} has {mesh.shape[axis]}")
+
+
+def index_cache_shape(batch: int, t_max: int, dim: int) -> tuple:
+    """The declared shape of a layer's index-key cache, one key of `dim`
+    a position: `[batch, dim, t_max]`, the POSITIONS in the lanes. An
+    index key is narrower than a tile's 128 lanes (64 in the served
+    model) and there is one a position, so `cache_shape`'s merged rows
+    do not exist for it: stored `[batch, t_max, dim]` the compiler keeps
+    it transposed at every program's edge and re-lays all of it, twice a
+    window and once a token step (read off the window program compiled
+    for a v5e, PERF.md section 6, PR 34). With the positions last the
+    score product reads it as it rests, a block of positions is a block
+    of lanes, and one token's key is a column."""
+    return (batch, dim, t_max)
+
+
+def _take_index(ic, at, n: int, row=None):
+    """`n` positions of an index cache from `at`: of every batch row, or
+    (with `row`, traced) of that one alone, [1, DI, n]."""
+    if row is None:
+        return lax.dynamic_slice_in_dim(ic, at, n, axis=2)
+    return lax.dynamic_slice(ic, (row, 0, at), (1, ic.shape[1], n))
+
+
+def _append_index(ic, kit, slot, mine):
+    """Append of one token's index key to every row of an index cache
+    [B, DI, T]: row b takes kit[b] ([B, 1, 1, DI]) as the COLUMN at
+    position `slot[b]` where `mine[b]`, else keeps what it holds. One
+    small read, select and `dynamic_update_slice` a row, in a loop over
+    the rows: as ONE scatter (`_append_rows`' way) the column is the
+    scatter's window, the compiler wants the window in the lanes, and
+    it re-lays the whole cache to `[B, T, DI]` and back on every token
+    step (read off the compiled window, PERF.md section 6, PR 34). A
+    column is 64 values; the loop's 16 trips a layer cost less than one
+    such copy."""
+    cols = jnp.swapaxes(kit[:, 0], 1, 2).astype(ic.dtype)       # [B, DI, 1]
+
+    def row(b, ic):
+        at = (b, 0, slot[b])
+        new = lax.dynamic_slice_in_dim(cols, b, 1, axis=0)
+        old = lax.dynamic_slice(ic, at, new.shape)
+        return lax.dynamic_update_slice(ic, jnp.where(mine[b], new, old), at)
+
+    return lax.fori_loop(0, ic.shape[0], row, ic)
+
+
+def _index_scores(qi, w, ib):
+    """The indexer's scores of a block of positions, float32: index
+    queries qi [B, J, DI] or [B, C, J, DI], heads' weights w [B, (C,) J]
+    float32 and index keys ib [B, DI, K] -> ``sum_j w_j relu(qi_j . k)``
+    [B, (C,) K]. The weighted sum over the heads is taken elementwise:
+    as a product on the matrix unit it would round the scores to
+    bfloat16."""
+    eq = "bjd,bdk->bjk" if qi.ndim == 3 else "bcjd,bdk->bcjk"
+    s = jnp.einsum(eq, qi, ib, preferred_element_type=jnp.float32)
+    return jnp.sum(jnp.maximum(s, 0.0) * w[..., None], axis=-2)
+
+
+def _scores_to_frontier(qi, w, ic, see, frontier, blk: int, row=None):
+    """Index scores [B, (C,) T] float32 of every position of the index
+    cache ic [B, DI, T], read in blocks of `blk` positions up to `frontier`
+    (traced): -inf where `see(g)` (g [blk] global rows, broadcastable to
+    the block's scores) is False and beyond the last block read. With
+    `row` only that batch row of the cache is read (qi, w are one
+    request's). A cache of one block is one pass, whatever the
+    frontier."""
+    t = ic.shape[2]
+
+    def one(ib, g):
+        return jnp.where(see(g), _index_scores(qi, w, ib), -jnp.inf)
+
+    rows = jnp.arange(blk, dtype=jnp.int32)
+    if t <= blk:
+        return one(_take_index(ic, 0, t, row) if row is not None else ic,
+                   rows)
+
+    def body(j, buf):
+        sc = one(_take_index(ic, j * blk, blk, row), j * blk + rows)
+        return lax.dynamic_update_slice_in_dim(buf, sc, j * blk, axis=-1)
+
+    return lax.fori_loop(
+        0, _trips(frontier, 0, t, blk), body,
+        jnp.full(w.shape[:-1] + (t,), -jnp.inf, jnp.float32))
+
+
+def _sortable(x):
+    """float32 -> uint32 whose unsigned order is the floats' order."""
+    u = lax.bitcast_convert_type(x, jnp.uint32)
+    return jnp.where(u >> 31 == 0, u | jnp.uint32(0x80000000), ~u)
+
+
+def _bisect(count, want, bits: int):
+    """Per row, the largest v < 2**bits (uint32) with ``count(v) >=
+    want`` where `count(v)` [N] falls as v grows and ``count(0) >= want``:
+    found bit by bit, `bits` counting passes and no sort."""
+    def bit(i, cur):
+        cand = cur | (jnp.uint32(1) << (bits - 1 - i).astype(jnp.uint32))
+        return jnp.where(count(cand) >= want, cand, cur)
+
+    return lax.fori_loop(0, bits, bit, jnp.zeros(want.shape, jnp.uint32))
+
+
+def _select_topk(score, k: int, frontier, blk: int):
+    """[N, T] bool: per row of score [N, T] float32 (-inf where a
+    position is not visible, and everywhere at or beyond `frontier`)
+    the k best-scoring visible positions, all of them where there are no
+    more than k; equal scores go to the lower position, as `lax.top_k`
+    breaks them. No sort: the k-th best score is found bit by bit over
+    the scores' sortable keys (32 counting passes over the blocks below
+    `frontier`), and where several positions tie at it, the position
+    of the last one that still fits likewise (log2 T passes)."""
+    n, t = score.shape
+    keys = _sortable(score)
+    pos = jnp.arange(t, dtype=jnp.uint32)
+
+    def count(test):
+        """Per row, how many columns below the frontier pass `test(keys
+        block, positions of the block)`."""
+        if t <= blk:
+            return jnp.sum(test(keys, pos), axis=1, dtype=jnp.int32)
+
+        def body(j, acc):
+            kb = lax.dynamic_slice_in_dim(keys, j * blk, blk, axis=1)
+            pb = lax.dynamic_slice_in_dim(pos, j * blk, blk)
+            return acc + jnp.sum(test(kb, pb), axis=1, dtype=jnp.int32)
+
+        return lax.fori_loop(0, _trips(frontier, 0, t, blk), body,
+                             jnp.zeros(n, jnp.int32))
+
+    want = jnp.full(n, k, jnp.int32)
+    kth = _bisect(lambda v: count(lambda kb, _: kb >= v[:, None]), want, 32)
+    # of the positions that tie at the k-th best score, the lowest
+    # `room` join those that score more: all below the largest position
+    # v that has fewer than `room` ties below it, and v itself
+    room = k - count(lambda kb, _: kb > kth[:, None])
+    last = _bisect(
+        lambda v: -count(lambda kb, pb: (kb == kth[:, None])
+                         & (pb[None, :] < v[:, None])),
+        1 - room, max(1, (t - 1).bit_length()))
+    return (((keys > kth[:, None])
+             | ((keys == kth[:, None]) & (pos[None, :] <= last[:, None])))
+            & (score > -jnp.inf))
+
+
+def pack_bits(mask):
+    """[.., T] bool -> [.., ceil(T / 32)] uint32: bit b of word m is
+    position 32 m + b."""
+    t = mask.shape[-1]
+    mask = jnp.pad(mask, [(0, 0)] * (mask.ndim - 1) + [(0, -t % 32)])
+    bits = mask.reshape(*mask.shape[:-1], -1, 32).astype(jnp.uint32)
+    return jnp.sum(bits << jnp.arange(32, dtype=jnp.uint32), axis=-1,
+                   dtype=jnp.uint32)
+
+
+def make_sparse_decode(mesh: Mesh, *, topk: int,
+                       axis: str = meshlib.SEQ_AXIS,
+                       scale: float | None = None):
+    """Per-slot decode fold of a layer whose indexer picks the positions
+    a query attends: ``fn(k_cache, v_cache, i_cache, q_t, k_t, v_t,
+    index, pos, live) -> (out_t, k_cache, v_cache, i_cache, account)``.
+
+    Beside K/V the layer caches ONE index key a position (`i_cache`, of
+    `index_cache_shape(B, T, DI)`). `index = (qi [B, 1, J, DI], ki
+    [B, 1, 1, DI], w [B, 1, J] float32)` are the new token's index
+    queries, index key and head weights. Per row, as in
+    `make_batched_ring_decode` (own position `pos[b]`, dead rows append
+    nothing):
+
+    1. append the token's key, value and index key at its position;
+    2. score every visible position ``sum_j w_j relu(qi_j . ki[s])``,
+       reading the INDEX cache in blocks up to the furthest live
+       position (named scope `dsa_index`);
+    3. take the `topk` best (`dsa_select`; all visible ones while there
+       are no more than that);
+    4. gather those rows of K and V, and attend over them alone
+       (`attn_sparse`).
+
+    K and V are read at the selected rows only: of a 32k-token slot
+    whose layer caches 2 KiB a position, 4 MiB of index keys and 4 MiB
+    of selected rows instead of 64 MiB. `account` holds ``selected``
+    [B, topk] int32 (the positions, -1 where a row sees fewer),
+    ``sel_share`` [B] float32 (selected over visible, 0 for a dead row)
+    and ``sel_rows`` [B] int32 (1 for a live row). One device only."""
+    _one_device(mesh, axis)
+
+    def fn(kc, vc, ic, q, kt, vt, index, pos, live):
+        qi, kit, w = index
+        t, d = kc.shape[1], q.shape[-1]
+        scale_ = scale if scale is not None else d ** -0.5
+        pos = jnp.asarray(pos, jnp.int32)
+        live = jnp.asarray(live, jnp.bool_)
+        posc = jnp.clip(pos, 0, t - 1)
+        with jax.named_scope("dsa_index"):
+            ic = _append_index(ic, kit, posc, live)
+            score = _scores_to_frontier(
+                qi[:, 0], w[:, 0], ic,
+                lambda g: g[None, :] <= posc[:, None],
+                _decode_frontier(posc, live), _fold_block(t, _INDEX_BLOCK))
+        with jax.named_scope("dsa_select"):
+            top, idx = lax.top_k(score, min(topk, t))
+            valid = top > -jnp.inf
+        with jax.named_scope("attn_sparse"):
+            kc = _append_rows(kc, _rows(kt, kc), posc, live)
+            vc = _append_rows(vc, _rows(vt, vc), posc, live)
+            rows = np.arange(kc.shape[0])[:, None]
+            s = _scores(q[:, 0], kc[rows, idx]) * scale_
+            s = jnp.where(valid[:, None, :], s, _MASKED)
+            p = jnp.where(valid[:, None, :],
+                          jnp.exp(s - jnp.max(s, axis=-1, keepdims=True)), 0.0)
+            out = (_weighted(p, vc[rows, idx], d)
+                   / jnp.maximum(jnp.sum(p, axis=-1), 1e-37)[..., None])
+        account = {
+            "selected": jnp.where(valid, idx, -1).astype(jnp.int32),
+            "sel_share": jnp.where(
+                live, jnp.sum(valid, axis=-1) / (posc + 1.0), 0.0
+            ).astype(jnp.float32),
+            "sel_rows": live.astype(jnp.int32)}
+        return out[:, None].astype(q.dtype), kc, vc, ic, account
+
+    return fn
+
+
+def make_sparse_chunk_decode(mesh: Mesh, *, topk: int,
+                             axis: str = meshlib.SEQ_AXIS,
+                             scale: float | None = None):
+    """Prefill-chunk fold of ONE request into row `row` of a BATCH's
+    caches, in place: ``fn(k_cache, v_cache, i_cache, q, k, v, index,
+    start, row) -> (out, k_cache, v_cache, i_cache, account)`` with
+    q/k/v [1, C, H, D] the chunk's projections at positions
+    [start, start + C) and `index = (qi [1, C, J, DI], ki [1, C, 1, DI],
+    w [1, C, J])`.
+
+    The chunk's C keys, values and index keys are written over the
+    row's positions [start, start + C) (one `dynamic_update_slice`
+    each: no other row and no other position is touched, and nothing is
+    read back); a ragged last chunk writes its padding too, which lies
+    beyond the request's frontier, where no fold reads before a decode
+    step has written. Each of the C queries then scores the row's index
+    keys up to the chunk's end (`dsa_index`), marks its own `topk` best
+    (`dsa_select`: counting passes, no sort; `_select_topk`) and attends
+    the positions marked, by a mask over block-wise
+    dense scores (`attn_sparse`): the same function as gathering them,
+    at a chunk's arithmetic intensity. ``account["selected_bits"]``
+    [C, ceil(T / 32)] uint32 holds each query's choice (`pack_bits`).
+    Requires start + C <= T. One device only."""
+    _one_device(mesh, axis)
+
+    def fn(kc, vc, ic, q, kt, vt, index, start, row):
+        t, c, d = kc.shape[1], q.shape[1], q.shape[-1]
+        scale_ = scale if scale is not None else d ** -0.5
+        start = jnp.asarray(start, jnp.int32)
+        row = jnp.asarray(row, jnp.int32)
+
+        def write(cache, new):
+            return lax.dynamic_update_slice(
+                cache, _rows(new, cache).astype(cache.dtype),
+                (row, start) + (0,) * (cache.ndim - 2))
+
+        qpos = start + jnp.arange(c, dtype=jnp.int32)
+        causal = lambda g: g[None, :] <= qpos[:, None]          # [C, blk]
+        qi, kit, w = index
+        blk = _fold_block(t, _INDEX_BLOCK)
+        with jax.named_scope("dsa_index"):
+            ic = lax.dynamic_update_slice(
+                ic, jnp.swapaxes(kit[:, :, 0], 1, 2).astype(ic.dtype),
+                (row, 0, start))
+            score = _scores_to_frontier(
+                qi, w, ic, lambda g: causal(g)[None], start + c, blk,
+                row=row)[0]                                      # [C, T]
+        with jax.named_scope("dsa_select"):
+            chosen = _select_topk(score, min(topk, t), start + c, blk)
+            account = {"selected_bits": pack_bits(chosen)}
+        see = lambda g: lax.dynamic_slice_in_dim(
+            chosen, g[0], g.shape[0], axis=1)[None, None]
+        with jax.named_scope("attn_sparse"):
+            kc, vc = write(kc, kt), write(vc, vt)
+            _, l, acc = _attend_to_frontier(
+                q, kc, vc, see, start + c, _fold_block(t, _CHUNK_BLOCK),
+                scale=scale_, row=row)
+            out = acc / jnp.maximum(l, 1e-37)[..., None]        # [1,H,C,D]
+        return (jnp.moveaxis(out, 1, 2).astype(q.dtype), kc, vc, ic,
+                account)
+
+    return fn
 
 
 def prefill(mesh: Mesh, k_prompt, v_prompt, t_max: int, *,

@@ -69,17 +69,19 @@ from idc_models_tpu import mesh as meshlib
 from idc_models_tpu.models import moe
 from idc_models_tpu.observe import trace
 from idc_models_tpu.models.lm import (
-    _attn_residual, _chunk_batch_forward, _ffn_residual, _final_logits,
-    _make_pick, _place_params, _project_qkv, _serve_config,
-    _serving_fns, _token_forward, check_prefill_chunk,
+    _attn_residual, _chunk_batch_forward, _chunk_forward, _ffn_residual,
+    _final_logits, _make_pick, _place_params, _project_qkv, _serve_config,
+    _serving_fns, _token_forward, check_prefill_chunk, chunk_picks,
     make_adapter_head_hook, prefill_bucket, prefill_buckets,
+    sparse_window_stats,
 )
 from idc_models_tpu.ring_decode import (
-    cache_shape, decode_rows_read, grow_cache,
+    cache_shape, decode_rows_read, grow_cache, index_cache_shape,
     make_batched_chunk_ring_decode,
     make_batched_ring_decode,
     make_paged_batched_chunk_ring_decode, make_paged_batched_ring_decode,
-    make_paged_chunk_ring_decode,
+    make_paged_chunk_ring_decode, make_sparse_chunk_decode,
+    make_sparse_decode,
 )
 from idc_models_tpu.serve.pages import PageAllocator, PageExhausted
 
@@ -200,6 +202,11 @@ class _EngineFns(NamedTuple):
     page_row: object = None
     stamp_scales: object = None
     prefill_chunk: object = None
+    # in-place mode (a contiguous engine whose spec has an indexer): the
+    # chunk program above writes the reserved slot's own rows, `insert`
+    # scatters scalars and logits alone, and `kill` zeroes one slot's
+    # device budget before its rows are written
+    kill: object = None
 
 
 # a last-token logit past this magnitude is corruption, not a model
@@ -219,9 +226,11 @@ def _window_core(cfg, pick, pad_id, params, caches, logits, kd, pos,
     differs), so paged token streams are bit-identical to contiguous
     ones by construction rather than by parallel maintenance.
 
-    `step_fn` returns (logits, caches, expert-layer statistics of the
-    live rows); the window's last result but one is their sum over its
-    steps (`moe.window_stats`), () for a model without expert layers.
+    `step_fn` returns (logits, caches, the layers' statistics of the
+    live rows); the window's last result but one is the expert layers'
+    sum over its steps (`moe.window_stats`) joined with the indexer
+    layers' account (`lm.sparse_window_stats`), () for a model with
+    neither.
     The last is the cache rows the window's attention read, summed over
     its steps: `rows_read(pos, live)` counts one step's (the contiguous
     engine's `ring_decode.decode_rows_read`); () without it.
@@ -269,9 +278,14 @@ def _window_core(cfg, pick, pad_id, params, caches, logits, kd, pos,
         length=n_steps)
     caches, logits = pin_state(caches, logits)
     # the expert layers' account of the window (() without them): what
-    # each held expert was sent, summed over the steps on the device
+    # each held expert was sent, summed over the steps on the device;
+    # and the indexer layers' (nothing without them): what was selected
+    account = moe.window_stats(tuple(st for st in stats if "held" in st))
+    selected = sparse_window_stats(stats)
+    if selected:
+        account = {**dict(account or {}), **selected}
     return (jnp.moveaxis(toks, 0, 1), caches, logits, kd, pos,
-            remaining, moe.window_stats(stats),
+            remaining, account,
             jnp.sum(rows) if rows_read else ())
 
 
@@ -403,6 +417,10 @@ def _engine_fns(cfg, pad_id: int, quant: bool = False,
     wraps = [l.window is not None for l in spec.layers]
     folds = {w: make_batched_ring_decode(mesh, jit=False, quantized=quant,
                                          wrap=w) for w in set(wraps)}
+    # a layer with an indexer folds over three caches (K, V, index keys)
+    sparse_folds = {i: make_sparse_decode(mesh, topk=l.indexer.topk)
+                    for i, l in enumerate(spec.layers)
+                    if l.indexer is not None}
     pick = _make_pick(cfg)
     # the TRAILING-NONE-FREE spelling of the ring cache layout: jit
     # normalizes trailing Nones out of output PartitionSpecs, and the
@@ -420,9 +438,8 @@ def _engine_fns(cfg, pad_id: int, quant: bool = False,
         # insert-output caches a different cache key than init_cache's
         # and recompile the window once per producer (observed)
         caches = tuple(
-            (lax.with_sharding_constraint(kc, cache_sh),
-             lax.with_sharding_constraint(vc, cache_sh))
-            for kc, vc in caches)
+            tuple(lax.with_sharding_constraint(c, cache_sh) for c in cache)
+            for cache in caches)
         return caches, lax.with_sharding_constraint(logits, rep)
 
     def init_caches(n_slots: int):
@@ -439,7 +456,15 @@ def _engine_fns(cfg, pad_id: int, quant: bool = False,
                          jnp.int8 if quant
                          else jnp.dtype(cfg.cache_dtype)), cache_sh)
 
+        def mk_index(l):
+            return meshlib.put_with_sharding(
+                np.zeros(index_cache_shape(n_slots, t_max, l.indexer.dim),
+                         jnp.dtype(cfg.cache_dtype)), cache_sh)
+
+        # beside K and V, an indexer's layer caches one index key a
+        # position
         return tuple((mk(i, l), mk(i, l))
+                     + ((mk_index(l),) if l.indexer else ())
                      for i, l in enumerate(spec.layers))
 
     def init_scales(n_slots: int):
@@ -455,7 +480,11 @@ def _engine_fns(cfg, pad_id: int, quant: bool = False,
         return tuple((mk(), mk()) for _ in range(cfg.num_blocks))
 
     def masked_step(params, caches, tok, pos, live, scales):
-        def block_fold(i, kc, vc, q, k, v):
+        def block_fold(i, *args):
+            if i in sparse_folds:
+                *cache, q, k, v, index = args
+                return sparse_folds[i](*cache, q, k, v, index, pos, live)
+            kc, vc, q, k, v = args
             extra = (scales[i] if quant else ())
             return folds[wraps[i]](kc, vc, q, k, v, pos, live, *extra)
 
@@ -463,8 +492,9 @@ def _engine_fns(cfg, pad_id: int, quant: bool = False,
                               live)
 
     # what one token step's attention reads of a full layer's cache (the
-    # full layers all read alike; a window layer's ring is read whole)
-    rows_read = (None if all(wraps) else functools.partial(
+    # full layers all read alike; a window layer's ring is read whole, and
+    # a layer with an indexer reads its selection, not up to a frontier)
+    rows_read = (None if all(wraps) or spec.sparse else functools.partial(
         decode_rows_read, mesh, t_max))
 
     def window_body(params, caches, logits, kd, pos, remaining, eos,
@@ -545,6 +575,46 @@ def _engine_fns(cfg, pad_id: int, quant: bool = False,
     insert = jax.jit(insert_body,
                      donate_argnums=(0, 1, 2, 3, 4, 5, 6, 7))
 
+    prefill_chunk = kill = None
+    if spec.sparse:
+        # IN-PLACE admission: a request's chunks are written straight
+        # into the reserved slot's rows of the batch caches (the slot is
+        # out of `free_slots`, its device budget is 0, and no fold reads
+        # a row beyond its slot's frontier), so no pending prefill owns
+        # a cache row of its own and the insert moves no cache at all
+        if any(wraps) or quant or not all(l.indexer for l in spec.layers):
+            raise ValueError(
+                "a spec with an indexer prefills into the slot's own "
+                "rows: every layer has one, over full-length float "
+                "caches, no window layer's ring and no int8 rows")
+        chunk_folds = [make_sparse_chunk_decode(mesh, topk=l.indexer.topk)
+                       for l in spec.layers]
+
+        def chunk_body(params, caches, slot, tokens, start, p_end):
+            # `slot` is traced: one executable serves every slot and
+            # every chunk, the ragged last one included. Results: the
+            # last real position's logits, the caches, the router's
+            # picks (() without expert layers) and each query's selected
+            # positions as bits, [indexer layers, C, t_max / 32]
+            logits, caches, stats = _chunk_forward(
+                cfg, params, caches, tokens, start, p_end,
+                lambda i, *cache_and_chunk: chunk_folds[i](
+                    *cache_and_chunk, start, slot))
+            caches, logits = pin_state(caches, logits)
+            bits = [st["selected_bits"] for st in stats
+                    if "selected_bits" in st]
+            return (logits, caches, chunk_picks(stats, *tokens.shape),
+                    jnp.stack(bits))
+
+        prefill_chunk = jax.jit(chunk_body, donate_argnums=(1,))
+        insert = jax.jit(_insert_scalars(rep),
+                         donate_argnums=(0, 1, 2, 3, 4, 5))
+        # a slot released with device budget left (a deadline cancel)
+        # would ride along appending at its old position: into rows the
+        # next tenant's chunks are writing. Its budget goes first
+        kill = jax.jit(lambda rem, slot: rem.at[slot].set(0),
+                       donate_argnums=(0,))
+
     def health_body(logits):
         # per-slot fault codes in ONE tiny reduce + fetch ([S] int32):
         # 1 = non-finite logits, 2 = finite but magnitude-blown, 0 = ok.
@@ -596,7 +666,27 @@ def _engine_fns(cfg, pad_id: int, quant: bool = False,
         verify = jax.jit(verify_body, donate_argnums=(1, 2, 3, 4, 5))
 
     return _EngineFns(init_caches, init_scales, window, insert, health,
-                      verify)
+                      verify, prefill_chunk=prefill_chunk, kill=kill)
+
+
+def _insert_scalars(rep):
+    """The admission scatter that touches NO cache state: the prompt's
+    K/V already sits where the windows read it (a paged engine's granted
+    pages, an in-place engine's slot rows), so admitting a request is a
+    handful of scalar/row updates, the slot index traced."""
+    def insert_body(logits, kd, pos, rem, eos, tslot, new_logits, slot,
+                    p_len, budget, eos_id, tid, kd_row):
+        logits = lax.dynamic_update_slice(
+            logits, new_logits.astype(logits.dtype), (slot, 0))
+        kd = lax.dynamic_update_slice(kd, kd_row[None], (slot, 0))
+        pos = pos.at[slot].set(p_len)
+        rem = rem.at[slot].set(budget)
+        eos = eos.at[slot].set(eos_id)
+        tslot = tslot.at[slot].set(tid)
+        return (lax.with_sharding_constraint(logits, rep), kd, pos,
+                rem, eos, tslot)
+
+    return insert_body
 
 
 class _DrafterFns(NamedTuple):
@@ -823,23 +913,9 @@ def _paged_engine_fns(cfg, pad_id: int, quant: bool, draft_k,
     window = jax.jit(window_body, static_argnums=(11,),
                      donate_argnums=(1, 3, 4, 5, 6))
 
-    def insert_body(logits, kd, pos, rem, eos, tslot, new_logits, slot,
-                    p_len, budget, eos_id, tid, kd_row):
-        # the paged admission scatter touches NO cache state: the
-        # prompt's K/V already sits in the slot's granted pages
-        # (written there by the direct-to-pool chunk program), so
-        # admitting a request is a handful of scalar/row updates
-        logits = lax.dynamic_update_slice(
-            logits, new_logits.astype(logits.dtype), (slot, 0))
-        kd = lax.dynamic_update_slice(kd, kd_row[None], (slot, 0))
-        pos = pos.at[slot].set(p_len)
-        rem = rem.at[slot].set(budget)
-        eos = eos.at[slot].set(eos_id)
-        tslot = tslot.at[slot].set(tid)
-        return (lax.with_sharding_constraint(logits, rep), kd, pos,
-                rem, eos, tslot)
-
-    insert = jax.jit(insert_body, donate_argnums=(0, 1, 2, 3, 4, 5))
+    # the prompt's K/V was written into the slot's granted pages by the
+    # direct-to-pool chunk program
+    insert = jax.jit(_insert_scalars(rep), donate_argnums=(0, 1, 2, 3, 4, 5))
 
     def page_row_body(pt, slot, row, rem, kill):
         # one program serves both grant-time rewrites (kill=0) and the
@@ -1175,6 +1251,10 @@ class SlotEngine:
         else:
             self._efns = _engine_fns(self._cfg, int(pad_id),
                                      self.kv_int8, self.draft_k)
+        # a contiguous engine of a spec with an indexer prefills into the
+        # reserved slot's own rows (`_engine_fns`): no pending prefill
+        # owns a cache row
+        self.in_place = not self.paged and self._cfg.spec.sparse
         self._params = _place_params(params, self._cfg.mesh,
                                      rules=partition_rules)
         # kept for hot weight swap (swap_params): a candidate tree is
@@ -1334,6 +1414,17 @@ class SlotEngine:
         self.last_attn_rows = None
         self._rows_pending = None
         self._picks = {"window": None, "prefill": None}
+        # indexer layers' accounts (None without them): `last_dsa` is the
+        # most recently COLLECTED window's {share_sum, rows} (selected
+        # over visible positions, summed over its live (step, slot)
+        # pairs, and their count), read per collect like last_moe; the
+        # positions selected by the last window and the last prefill
+        # chunk stay on the device until `selected_positions` asks
+        self.last_dsa = None
+        self._selected = {"window": None, "prefill": None}
+        # in-place engines: slots released with device budget left,
+        # whose ride-along has to stop before their rows are written
+        self._riding = np.zeros(n_slots, bool)
         # in-progress chunked prefills: slot -> _PendingPrefill. These
         # slots are RESERVED (excluded from free_slots, not yet decoded
         # by windows) until the final chunk lands and insert scatters
@@ -1390,6 +1481,8 @@ class SlotEngine:
         prefix-cache snapshot still holds survive via their
         refcounts."""
         self._occupied[slot] = False
+        if self.in_place and self._rem_h[slot] > 0:
+            self._riding[slot] = True
         self._rem_h[slot] = 0
         if self._dfns is not None:
             # the drafter row's dead K/V stays, like the target row's:
@@ -1661,9 +1754,10 @@ class SlotEngine:
             eos = -1 if eos is None else int(eos)
             kd_row = (_key_data(rng) if rng is not None
                       else np.zeros(2, np.uint32))
-            if self.paged:
-                # the prompt K/V already lives in the slot's pages — the
-                # paged insert is a scalar/row scatter only
+            if self.paged or self.in_place:
+                # the prompt K/V already lives in the slot's pages (or,
+                # in place, in its rows) — the insert is a scalar/row
+                # scatter only
                 (self._logits, self._kd, self._pos, self._rem,
                  self._eos, self._tslot) = self._efns.insert(
                     self._logits, self._kd, self._pos, self._rem,
@@ -1782,6 +1876,18 @@ class SlotEngine:
             if self.paged:
                 self._start_prefill_paged(slot, prompt, max_new_tokens,
                                           rng, eos_id, tag, tid)
+                return
+            if self.in_place:
+                # the chunks will write the slot's own rows: no cache
+                # row is built, on the host or anywhere. A slot that
+                # was released mid-budget stops riding along first
+                if self._riding[slot]:
+                    self._rem = self._efns.kill(self._rem, np.int32(slot))
+                    self._riding[slot] = False
+                self._prefills[slot] = _PendingPrefill(
+                    prompt=prompt, budget=int(max_new_tokens), rng=rng,
+                    eos_id=eos_id, caches=None, logits=None, next_start=0,
+                    tag=tag, tid=tid)
                 return
             start, caches, logits = 0, None, None
             if self.prefix_cache is not None:
@@ -1938,6 +2044,11 @@ class SlotEngine:
                             np.int32(pend.next_start), np.int32(end)))
                     if self.kv_int8:
                         self._scales = new_scales
+                elif self.in_place:
+                    (pend.logits, self._caches, self._picks["prefill"],
+                     self._selected["prefill"]) = self._efns.prefill_chunk(
+                        self._params, self._caches, np.int32(slot),
+                        padded, np.int32(pend.next_start), np.int32(end))
                 else:
                     (pend.logits, pend.caches,
                      self._picks["prefill"]) = self._sfns.prefill_chunk(
@@ -2020,9 +2131,12 @@ class SlotEngine:
                     rows, n_steps * self.n_slots * self.t_max)
             if stats:
                 # handed back with the window's tokens: collect()
-                # fetches the counts, the picks stay where they are
+                # fetches the counts, the picks and the selected
+                # positions stay where they are
                 self._moe_pending = dict(stats)
-                self._picks["window"] = self._moe_pending.pop("picks")
+                self._picks["window"] = self._moe_pending.pop("picks", None)
+                self._selected["window"] = self._moe_pending.pop(
+                    "dsa_selected", None)
         self._pending = (toks, snapshot)
 
     def spec_room(self, slot: int) -> bool:
@@ -2244,6 +2358,7 @@ class SlotEngine:
         # first real cycle's metrics
         self.last_spec = None
         self.last_moe = None
+        self.last_dsa = None
         self.last_attn_rows = None
         if self._pending is None:
             return {}
@@ -2259,7 +2374,11 @@ class SlotEngine:
         with trace.span("device.sync"):
             toks = np.asarray(toks)
             if moe_stats is not None:
-                self.last_moe = jax.device_get(moe_stats)
+                got = jax.device_get(moe_stats)
+                if "dsa_rows" in got:
+                    self.last_dsa = {"share_sum": got.pop("dsa_share_sum"),
+                                     "rows": got.pop("dsa_rows")}
+                self.last_moe = got or None
             if rows is not None:
                 self.last_attn_rows = (int(rows[0]), rows[1])
             if spec:
@@ -2463,6 +2582,10 @@ class SlotEngine:
                 np.int32(0), np.int32(0))
             if self.kv_int8:
                 self._scales = sc
+        elif self.in_place:
+            raise ValueError(
+                "the rollout spot-check runs a chunk into a scratch "
+                "cache row; an engine that prefills in place has none")
         elif self.prefill_chunk is not None:
             c = self.prefill_chunk
             caches1 = self._sfns.init_caches(1)
@@ -2600,7 +2723,15 @@ class SlotEngine:
                     self._params, self._caches, self._logits, self._kd,
                     self._pos, self._rem, self._eos, self._scales,
                     self._adapters, self._tslot, window).compile())
-            if self.prefill_chunk is not None:
+            if self.in_place:
+                c = self.prefill_chunk
+                out["serve.prefill_chunk"] = prof.register_program(
+                    "serve.prefill_chunk",
+                    self._efns_jit.prefill_chunk.lower(
+                        self._params, self._caches, np.int32(0),
+                        np.zeros((1, c), np.int32), np.int32(0),
+                        np.int32(c)).compile())
+            elif self.prefill_chunk is not None:
                 c = self.prefill_chunk
                 caches1 = self._sfns.init_caches(1)
                 out["serve.prefill_chunk"] = prof.register_program(
@@ -2842,6 +2973,11 @@ class SlotEngine:
         dispatches below run through the loaded executables — a warm
         process skips their XLA compiles entirely."""
         if compile_cache is not None:
+            if self.in_place:
+                raise ValueError(
+                    "the AOT compile cache knows the chunk program of a "
+                    "request's own cache row; an engine that prefills in "
+                    "place compiles in process")
             self._warm_aot(n_steps, compile_cache)
         if self.paged:
             # two chunk steps against the live pool with an
@@ -2859,6 +2995,19 @@ class SlotEngine:
                     np.int32(0), np.int32(0))
                 if self.kv_int8:
                     self._scales = sc
+            caches1 = None
+        elif self.in_place:
+            # two chunk steps into the rows of slot 0, which is free
+            # (what they write lies beyond every frontier): the first
+            # consumes init_caches' arrays, the second the chunk
+            # program's own (pinned) outputs; and the budget kill
+            c = self.prefill_chunk
+            for start in (0, c if 2 * c <= self.t_max else 0):
+                logits1, self._caches, _, _ = self._efns.prefill_chunk(
+                    self._params, self._caches, np.int32(0),
+                    np.zeros((1, c), np.int32), np.int32(start),
+                    np.int32(start + c))
+            self._rem = self._efns.kill(self._rem, np.int32(0))
             caches1 = None
         elif self.prefill_chunk is not None:
             c = self.prefill_chunk
@@ -2883,7 +3032,7 @@ class SlotEngine:
         # so the second cycle warms exactly the executables the serve
         # loop reuses forever
         for _ in range(2):
-            if self.paged:
+            if self.paged or self.in_place:
                 (self._logits, self._kd, self._pos, self._rem,
                  self._eos, self._tslot) = self._efns.insert(
                     self._logits, self._kd, self._pos, self._rem,
@@ -2967,8 +3116,8 @@ class SlotEngine:
         if self.paged:
             return self._l_pages * self.kv_page_bytes()
         per = 0
-        for kc, vc in self._caches:
-            per += (kc.nbytes + vc.nbytes) // self.n_slots
+        for cache in self._caches:
+            per += sum(c.nbytes for c in cache) // self.n_slots
         for pair in self._scales:
             for s in pair:
                 per += s.nbytes // self.n_slots
@@ -2977,11 +3126,15 @@ class SlotEngine:
     def kv_bytes_by_kind(self) -> dict:
         """HBM bytes of the cache rows of all slots, by the kind of
         layer that owns them: ``full`` (t_max rows a slot) and
-        ``window`` (a ring of W rows a slot)."""
+        ``window`` (a ring of W rows a slot); and, for a model with
+        indexer layers alone, ``index``: the index keys cached beside
+        K/V."""
         out = {"full": 0, "window": 0}
-        for l, (kc, vc) in zip(self._cfg.spec.layers, self._caches):
+        for l, (kc, vc, *ic) in zip(self._cfg.spec.layers, self._caches):
             out["full" if l.window is None else "window"] += (
                 kc.nbytes + vc.nbytes)
+            if ic:
+                out["index"] = out.get("index", 0) + ic[0].nbytes
         return out
 
     def slot_logits(self, slot: int) -> np.ndarray:
@@ -3001,6 +3154,18 @@ class SlotEngine:
         picks = self._picks[where]
         return (None if picks is None or isinstance(picks, tuple)
                 else np.asarray(picks))
+
+    def selected_positions(self, where: str) -> np.ndarray | None:
+        """The positions the indexer layers chose, for a model with such
+        layers, else None: ``"window"``, the last dispatched window's,
+        [steps, indexer layers, n_slots, topk] int32 (-1 where a slot
+        saw fewer); ``"prefill"``, the last prefill chunk's, as bits
+        (`ring_decode.pack_bits`: bit b of word m is position 32 m + b),
+        [indexer layers, chunk, t_max / 32] uint32 (the rows past the
+        prompt's end are padding's). A device fetch, for checks and
+        debugging, beside `router_picks`."""
+        sel = self._selected[where]
+        return None if sel is None else np.asarray(sel)
 
     def kv_page_bytes(self) -> int:
         """HBM bytes ONE page costs across every block's K + V pools,

@@ -229,6 +229,10 @@ class ServingMetrics:
         self.moe_assigned = 0
         self.moe_touched = 0
         self.moe_layer_steps = 0
+        # indexer layers' selected over visible positions (on_dsa)
+        self._m_dsa_share = None
+        self.dsa_share_sum = 0.0
+        self.dsa_rows = 0
         # decode attention's rows read of the rows there (on_attn_rows)
         self._m_attn_share = None
         self.attn_rows_read = 0
@@ -567,6 +571,23 @@ class ServingMetrics:
         if skew is not None:
             m["skew"].set(skew)
 
+    def on_dsa(self, *, share_sum, rows) -> None:
+        """A decode window of a model with indexer layers was collected
+        (engine.last_dsa, models/lm.sparse_window_stats): `share_sum`
+        the positions a query attended over the positions it could see,
+        summed over the window's `rows` live (step, slot) pairs. The
+        gauge is registered on the first call."""
+        if self._m_dsa_share is None:
+            self._m_dsa_share = self._reg.gauge(
+                "serve_dsa_selected_share",
+                "positions the indexer selected over the positions "
+                "visible, mean over the live slots and steps of the "
+                "decode windows since the server started")
+        self.dsa_share_sum += float(share_sum)
+        self.dsa_rows += int(rows)
+        if self.dsa_rows:
+            self._m_dsa_share.set(self.dsa_share_sum / self.dsa_rows)
+
     def on_attn_rows(self, read: int, whole: int) -> None:
         """A decode window of the contiguous engine was collected
         (engine.last_attn_rows): `read` cache rows of one full layer its
@@ -589,6 +610,11 @@ class ServingMetrics:
         model that has window layers: every other server's exposition
         stays as it was."""
         self.kv_bytes_by_kind = dict(by_kind)
+        if by_kind.get("index"):
+            self._reg.gauge(
+                "serve_index_cache_bytes",
+                "HBM bytes of the index keys the indexer layers cache "
+                "beside K/V, over all slots").set(by_kind["index"])
         if by_kind.get("window"):
             for kind, nbytes in by_kind.items():
                 self._reg.gauge(
@@ -828,6 +854,13 @@ class ServingMetrics:
             # contiguous engine only): 1.0 = every row of every slot
             out["serve_attn_read_share"] = (self.attn_rows_read
                                             / self.attn_rows_whole)
+        if self.dsa_rows:
+            # what the indexer layers kept (additive; a model with such
+            # layers only): 1.0 = every visible position attended
+            out["serve_dsa_selected_share"] = (self.dsa_share_sum
+                                               / self.dsa_rows)
+        if self.kv_bytes_by_kind.get("index"):
+            out["serve_index_cache_bytes"] = self.kv_bytes_by_kind["index"]
         if self.kv_bytes_by_kind.get("window"):
             out["serve_kv_bytes_full"] = self.kv_bytes_by_kind["full"]
             out["serve_kv_bytes_window"] = self.kv_bytes_by_kind["window"]

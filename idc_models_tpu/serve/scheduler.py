@@ -879,6 +879,9 @@ class Scheduler:
                 self.metrics.on_moe(**moe_stats)
             # a collected contiguous window reports how far its
             # attention read (an on-device count, fetched with the tokens)
+            dsa = getattr(self.engine, "last_dsa", None)
+            if dsa is not None and self.metrics:
+                self.metrics.on_dsa(**dsa)
             attn_rows = getattr(self.engine, "last_attn_rows", None)
             if attn_rows is not None and self.metrics:
                 self.metrics.on_attn_rows(*attn_rows)

@@ -152,3 +152,88 @@ def test_serve_programs_copy_no_cache_and_stage_none(programs):
         params, caches(1), sds((1, CHUNK), jnp.int32), sds((), jnp.int32),
         sds((), jnp.int32)).compile()
     assert _whole_cache_copies(chunk.as_text(), 1) == []
+
+
+# a layer with an indexer at the served model's widths (4 KV heads of
+# 128, 16 index heads of 64), under a rehearsal-sized model
+S_T_MAX, S_SLOTS, S_TOPK, S_KV, S_D, S_DI = 4096, 4, 256, 4, 128, 64
+
+
+def _instructions(text):
+    """(name, result type, op, operand names, line) of every
+    instruction of a compiled program's text."""
+    rx = re.compile(r"^\s*(?:ROOT )?(%[\w.\-]+) = (\S+) ([\w\-]+)\((.*)$")
+    for line in text.splitlines():
+        m = rx.match(line)
+        if m:
+            yield (m.group(1), m.group(2), m.group(3),
+                   re.findall(r"%[\w.\-]+", m.group(4).split("),")[0]), line)
+
+
+def test_sparse_window_reads_index_keys_and_selected_rows_alone(chip):
+    """The decode window of a spec with an indexer, compiled for a
+    described v5e: the only operations that take a whole K or V cache as
+    an operand are the append's scatter and the gather of the selected
+    rows (and the plumbing that carries the arrays through the loops);
+    the index cache rests as it is stored, positions in the lanes, and
+    no cache is copied whole, at the program's edges or inside it
+    (stored `[S, T, 64]` the compiler re-laid every index cache twice a
+    window and once a token step: PERF.md section 6, PR 34)."""
+    from idc_models_tpu.models import lm
+
+    rep = NamedSharding(chip, P())
+    layer = lm.LayerSpec(
+        8, S_KV, S_D, rotary=lm.Rotary(1e7, S_D), ffn="swiglu", qk_norm=True,
+        indexer=lm.Indexer(16, S_DI, S_TOPK, rotary=lm.Rotary(1e7, S_DI)))
+    spec = lm.ModelSpec(256, (layer, layer), norm="rmsnorm",
+                        learned_pos=False, param_dtype="bfloat16")
+    shapes = jax.eval_shape(lambda k: lm.init_params(spec, VOCAB, k,
+                                                     mlp_dim=512),
+                            jax.random.key(0))
+
+    def sds(shape, dtype, sh=rep):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+    cfg = _serve_config(shapes, spec=spec, t_max=S_T_MAX, mesh=chip,
+                        cache_dtype=jnp.bfloat16)
+    kv = sds(rd.cache_shape(S_SLOTS, S_T_MAX, S_KV, S_D), jnp.bfloat16,
+             rd.cache_sharding(chip))
+    ix = sds(rd.index_cache_shape(S_SLOTS, S_T_MAX, S_DI), jnp.bfloat16,
+             rd.cache_sharding(chip))
+    assert kv.shape == (S_SLOTS, S_T_MAX, S_KV, S_D)
+    assert ix.shape == (S_SLOTS, S_DI, S_T_MAX)
+    caches = ((kv, kv, ix),) * 2
+    params = jax.tree.map(lambda a: sds(a.shape, a.dtype), shapes)
+    i32 = sds((S_SLOTS,), jnp.int32)
+    efns = _engine_fns(cfg, 0)
+    win = efns.window.lower(
+        params, caches, sds((S_SLOTS, VOCAB), jnp.float32),
+        sds((S_SLOTS, 2), jnp.uint32), i32, i32, i32, (), (), i32,
+        WINDOW).compile().as_text()
+    kv_type = f"bf16[{S_SLOTS},{S_T_MAX},{S_KV},{S_D}]"
+    ix_type = f"bf16[{S_SLOTS},{S_DI},{S_T_MAX}]"
+    ins = list(_instructions(win))
+    typed = {name: t for name, t, *_ in ins}
+    plumbing = {"parameter", "get-tuple-element", "tuple", "while", "call",
+                "conditional", "bitcast", "optimization-barrier"}
+    readers = [(op, line) for _, _, op, operands, line in ins
+               if op not in plumbing
+               and any(typed.get(o, "").startswith(kv_type) for o in operands)]
+    assert readers, "the program names no K/V cache at all"
+    for op, line in readers:
+        name = re.search(r'op_name="([^"]*)"', line)
+        assert name and re.search(r"attn_sparse/(gather|scatter)$",
+                                  name.group(1)), line[:300]
+    assert {op for op, _ in readers} >= {"gather", "scatter"}
+    # whole-cache copies: none, of K/V or of the index keys, in either
+    # program; and the index cache keeps its stored layout throughout
+    chunk = efns.prefill_chunk.lower(
+        params, caches, sds((), jnp.int32), sds((1, CHUNK), jnp.int32),
+        sds((), jnp.int32), sds((), jnp.int32)).compile().as_text()
+    for text in (win, chunk):
+        assert not [l for _, t, op, _, l in _instructions(text)
+                    if op == "copy" and t.startswith((kv_type, ix_type))]
+        layouts = {t.split("{")[1].split(":")[0]
+                   for _, t, *_ in _instructions(text)
+                   if t.startswith(ix_type)}
+        assert layouts == {"2,1,0"}, layouts

@@ -527,8 +527,8 @@ def test_densenet_is_concat_free():
 # would quietly reintroduce the reservation the pool replaced. The scan
 # flags allocation calls (zeros/ones/full/empty) whose shape is a
 # literal tuple of rank >= 3 (KV-shaped — token-id buffers are 2-D) or
-# `ring_decode.cache_shape(...)`, the declared shape of a contiguous
-# cache, and mentions t_max anywhere inside it.
+# `ring_decode.cache_shape(...)` / `index_cache_shape(...)`, the declared
+# shapes of a contiguous cache, and mentions t_max anywhere inside it.
 
 _ALLOC_CALLS = {"zeros", "ones", "full", "empty"}
 
@@ -539,6 +539,10 @@ TMAX_KV_ALLOWLIST = {
         "the CONTIGUOUS-mode constructor: per-slot [t_max] ring rows "
         "are exactly what that mode is — the paged twin "
         "(_paged_engine_fns) allocates the page pool instead",
+    ("idc_models_tpu/serve/engine.py", "_engine_fns.init_caches.mk_index"):
+        "the same constructor's index keys: a layer with an indexer "
+        "caches one key a position beside K/V, and such a spec runs on "
+        "the contiguous engine alone (paged KV refuses it by name)",
     ("idc_models_tpu/serve/engine.py", "_drafter_fns.init_caches.mk"):
         "the learned DRAFTER's ring: the draft LM is deliberately "
         "tiny (a few-MB student), so per-slot [t_max] rows cost "
@@ -568,7 +572,7 @@ def _kv_shaped(node) -> bool:
         return len(node.elts) >= 3
     return (isinstance(node, ast.Call)
             and getattr(node.func, "id", getattr(node.func, "attr", None))
-            == "cache_shape")
+            in ("cache_shape", "index_cache_shape"))
 
 
 def _scan_tmax_kv_allocs(path: Path):
