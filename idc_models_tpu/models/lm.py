@@ -804,16 +804,18 @@ def sparse_window_stats(stats):
     per-step records a scan stacked ([W, ...] leaves): ``dsa_selected``
     [W, layers, S, topk] the positions every slot attended (-1 where it
     saw fewer), ``dsa_share_sum`` the sum over live (step, slot) pairs
-    of selected over visible positions, ``dsa_rows`` how many pairs
-    (both of the first such layer: they all select alike). {} without
-    such layers."""
+    of selected over visible positions, ``dsa_rows`` how many pairs,
+    ``dsa_fold_rows`` the rows the fold selected, gathered and attended
+    for, the live ones in whole groups (all three of the first such
+    layer: they all select alike). {} without such layers."""
     stats = [st for st in stats if "selected" in st]
     if not stats:
         return {}
     return {"dsa_selected": jnp.stack([st["selected"] for st in stats],
                                       axis=1),
             "dsa_share_sum": jnp.sum(stats[0]["sel_share"]),
-            "dsa_rows": jnp.sum(stats[0]["sel_rows"])}
+            "dsa_rows": jnp.sum(stats[0]["sel_rows"]),
+            "dsa_fold_rows": jnp.sum(stats[0]["fold_rows"])}
 
 
 def _final_logits(spec: ModelSpec, params, h):

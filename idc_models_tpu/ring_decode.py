@@ -1461,6 +1461,13 @@ def make_chunk_ring_decode(mesh: Mesh, *, axis: str = meshlib.SEQ_AXIS,
 # rows of index keys one block of the indexer's scores reads
 _INDEX_BLOCK = 2048
 
+# live rows the sparse decode fold selects, gathers and attends for in
+# one trip. On a v5e `lax.top_k` over 32k positions costs the same for
+# 4, 8 and 16 rows and the gather of 4 rows what 8 cost, so trips of 4
+# made a full batch a quarter slower than one pass over all its rows;
+# two trips of 8 cost what that pass did (PERF.md section 6, PR 35)
+_FOLD_GROUP = 8
+
 
 def _one_device(mesh: Mesh, axis: str) -> None:
     if mesh.shape[axis] > 1:
@@ -1645,17 +1652,26 @@ def make_sparse_decode(mesh: Mesh, *, topk: int,
     4. gather those rows of K and V, and attend over them alone
        (`attn_sparse`).
 
+    Steps 3 and 4 cost by the row (a sort of the whole length, 2 x
+    `topk` gathered rows), so they run for the LIVE rows alone: their
+    ids in slot order, `_FOLD_GROUP` at a time, one trip of one loop
+    body a group (none when no row is live). A dead row is never sorted
+    for, gathered for or attended for: its `out_t` is zero.
+
     K and V are read at the selected rows only: of a 32k-token slot
     whose layer caches 2 KiB a position, 4 MiB of index keys and 4 MiB
     of selected rows instead of 64 MiB. `account` holds ``selected``
-    [B, topk] int32 (the positions, -1 where a row sees fewer),
-    ``sel_share`` [B] float32 (selected over visible, 0 for a dead row)
-    and ``sel_rows`` [B] int32 (1 for a live row). One device only."""
+    [B, topk] int32 (the positions, -1 where a row sees fewer and in
+    every dead row), ``sel_share`` [B] float32 (selected over visible,
+    0 for a dead row), ``sel_rows`` [B] int32 (1 for a live row) and
+    ``fold_rows`` int32 (the rows steps 3 and 4 ran for: the trips
+    times the group). One device only."""
     _one_device(mesh, axis)
 
     def fn(kc, vc, ic, q, kt, vt, index, pos, live):
         qi, kit, w = index
-        t, d = kc.shape[1], q.shape[-1]
+        b, t, d = kc.shape[0], kc.shape[1], q.shape[-1]
+        k, grp = min(topk, t), min(_FOLD_GROUP, b)
         scale_ = scale if scale is not None else d ** -0.5
         pos = jnp.asarray(pos, jnp.int32)
         live = jnp.asarray(live, jnp.bool_)
@@ -1666,26 +1682,49 @@ def make_sparse_decode(mesh: Mesh, *, topk: int,
                 qi[:, 0], w[:, 0], ic,
                 lambda g: g[None, :] <= posc[:, None],
                 _decode_frontier(posc, live), _fold_block(t, _INDEX_BLOCK))
-        with jax.named_scope("dsa_select"):
-            top, idx = lax.top_k(score, min(topk, t))
-            valid = top > -jnp.inf
         with jax.named_scope("attn_sparse"):
             kc = _append_rows(kc, _rows(kt, kc), posc, live)
             vc = _append_rows(vc, _rows(vt, vc), posc, live)
-            rows = np.arange(kc.shape[0])[:, None]
-            s = _scores(q[:, 0], kc[rows, idx]) * scale_
-            s = jnp.where(valid[:, None, :], s, _MASKED)
-            p = jnp.where(valid[:, None, :],
-                          jnp.exp(s - jnp.max(s, axis=-1, keepdims=True)), 0.0)
-            out = (_weighted(p, vc[rows, idx], d)
-                   / jnp.maximum(jnp.sum(p, axis=-1), 1e-37)[..., None])
+        # the live rows' ids first, in slot order; behind the last live
+        # one a group is filled up with the index one past the batch,
+        # which reads the last row and writes nothing
+        n = jnp.sum(live, dtype=jnp.int32)
+        order = jnp.pad(
+            jnp.where(jnp.arange(b) < n, jnp.argsort(~live, stable=True), b),
+            (0, -b % grp), constant_values=b).astype(jnp.int32)
+
+        def group(j, carry):
+            out, selected = carry
+            ids = lax.dynamic_slice_in_dim(order, j * grp, grp)
+            at = jnp.minimum(ids, b - 1)
+            with jax.named_scope("dsa_select"):
+                top, idx = lax.top_k(score[at], k)
+                valid = top > -jnp.inf
+            with jax.named_scope("attn_sparse"):
+                s = _scores(q[at, 0], kc[at[:, None], idx]) * scale_
+                s = jnp.where(valid[:, None, :], s, _MASKED)
+                p = jnp.where(
+                    valid[:, None, :],
+                    jnp.exp(s - jnp.max(s, axis=-1, keepdims=True)), 0.0)
+                o = (_weighted(p, vc[at[:, None], idx], d)
+                     / jnp.maximum(jnp.sum(p, axis=-1), 1e-37)[..., None])
+                out = out.at[ids].set(o.astype(out.dtype), mode="drop")
+            return out, selected.at[ids].set(
+                jnp.where(valid, idx, -1).astype(jnp.int32), mode="drop")
+
+        trips = (n + grp - 1) // grp
+        out, selected = lax.fori_loop(
+            0, trips, group,
+            (jnp.zeros(q.shape[:1] + q.shape[2:], q.dtype),
+             jnp.full((b, k), -1, jnp.int32)))
         account = {
-            "selected": jnp.where(valid, idx, -1).astype(jnp.int32),
+            "selected": selected,
             "sel_share": jnp.where(
-                live, jnp.sum(valid, axis=-1) / (posc + 1.0), 0.0
+                live, jnp.sum(selected >= 0, axis=-1) / (posc + 1.0), 0.0
             ).astype(jnp.float32),
-            "sel_rows": live.astype(jnp.int32)}
-        return out[:, None].astype(q.dtype), kc, vc, ic, account
+            "sel_rows": live.astype(jnp.int32),
+            "fold_rows": trips * grp}
+        return out[:, None], kc, vc, ic, account
 
     return fn
 

@@ -1415,9 +1415,10 @@ class SlotEngine:
         self._rows_pending = None
         self._picks = {"window": None, "prefill": None}
         # indexer layers' accounts (None without them): `last_dsa` is the
-        # most recently COLLECTED window's {share_sum, rows} (selected
-        # over visible positions, summed over its live (step, slot)
-        # pairs, and their count), read per collect like last_moe; the
+        # most recently COLLECTED window's {share_sum, rows, fold_rows}
+        # (selected over visible positions, summed over its live (step,
+        # slot) pairs, their count, and the rows the fold ran for),
+        # read per collect like last_moe; the
         # positions selected by the last window and the last prefill
         # chunk stay on the device until `selected_positions` asks
         self.last_dsa = None
@@ -2377,7 +2378,8 @@ class SlotEngine:
                 got = jax.device_get(moe_stats)
                 if "dsa_rows" in got:
                     self.last_dsa = {"share_sum": got.pop("dsa_share_sum"),
-                                     "rows": got.pop("dsa_rows")}
+                                     "rows": got.pop("dsa_rows"),
+                                     "fold_rows": got.pop("dsa_fold_rows")}
                 self.last_moe = got or None
             if rows is not None:
                 self.last_attn_rows = (int(rows[0]), rows[1])

@@ -229,10 +229,13 @@ class ServingMetrics:
         self.moe_assigned = 0
         self.moe_touched = 0
         self.moe_layer_steps = 0
-        # indexer layers' selected over visible positions (on_dsa)
+        # indexer layers' selected over visible positions, and the rows
+        # their decode fold ran for over the live ones (on_dsa)
         self._m_dsa_share = None
+        self._m_dsa_folded = None
         self.dsa_share_sum = 0.0
         self.dsa_rows = 0
+        self.dsa_fold_rows = 0
         # decode attention's rows read of the rows there (on_attn_rows)
         self._m_attn_share = None
         self.attn_rows_read = 0
@@ -571,22 +574,32 @@ class ServingMetrics:
         if skew is not None:
             m["skew"].set(skew)
 
-    def on_dsa(self, *, share_sum, rows) -> None:
+    def on_dsa(self, *, share_sum, rows, fold_rows) -> None:
         """A decode window of a model with indexer layers was collected
         (engine.last_dsa, models/lm.sparse_window_stats): `share_sum`
         the positions a query attended over the positions it could see,
-        summed over the window's `rows` live (step, slot) pairs. The
-        gauge is registered on the first call."""
+        summed over the window's `rows` live (step, slot) pairs, and
+        `fold_rows` the rows the fold sorted, gathered and attended for
+        (the live ones in whole groups). The gauges are registered on
+        the first call."""
         if self._m_dsa_share is None:
             self._m_dsa_share = self._reg.gauge(
                 "serve_dsa_selected_share",
                 "positions the indexer selected over the positions "
                 "visible, mean over the live slots and steps of the "
                 "decode windows since the server started")
+            self._m_dsa_folded = self._reg.gauge(
+                "serve_dsa_folded_over_live",
+                "rows the sparse decode fold sorted, gathered and "
+                "attended for over the live (step, slot) pairs of the "
+                "decode windows since the server started: 1.0 = no "
+                "dead row paid for")
         self.dsa_share_sum += float(share_sum)
         self.dsa_rows += int(rows)
+        self.dsa_fold_rows += int(fold_rows)
         if self.dsa_rows:
             self._m_dsa_share.set(self.dsa_share_sum / self.dsa_rows)
+            self._m_dsa_folded.set(self.dsa_fold_rows / self.dsa_rows)
 
     def on_attn_rows(self, read: int, whole: int) -> None:
         """A decode window of the contiguous engine was collected
@@ -859,6 +872,10 @@ class ServingMetrics:
             # layers only): 1.0 = every visible position attended
             out["serve_dsa_selected_share"] = (self.dsa_share_sum
                                                / self.dsa_rows)
+            # rows their decode fold ran for over the live ones: 1.0 =
+            # no dead row was sorted, gathered or attended for
+            out["serve_dsa_folded_over_live"] = (self.dsa_fold_rows
+                                                 / self.dsa_rows)
         if self.kv_bytes_by_kind.get("index"):
             out["serve_index_cache_bytes"] = self.kv_bytes_by_kind["index"]
         if self.kv_bytes_by_kind.get("window"):

@@ -154,9 +154,12 @@ def test_serve_programs_copy_no_cache_and_stage_none(programs):
     assert _whole_cache_copies(chunk.as_text(), 1) == []
 
 
-# a layer with an indexer at the served model's widths (4 KV heads of
-# 128, 16 index heads of 64), under a rehearsal-sized model
-S_T_MAX, S_SLOTS, S_TOPK, S_KV, S_D, S_DI = 4096, 4, 256, 4, 128, 64
+# a layer with an indexer at the served model's widths
+# (4 KV heads of 128, 16 index heads of 64), under a rehearsal-sized
+# model; two of the decode fold's groups of live rows and one slot
+S_T_MAX, S_TOPK, S_KV, S_D, S_DI = 4096, 256, 4, 128, 64
+S_GROUP = rd._FOLD_GROUP
+S_SLOTS = 2 * S_GROUP + 1
 
 
 def _instructions(text):
@@ -173,12 +176,16 @@ def _instructions(text):
 def test_sparse_window_reads_index_keys_and_selected_rows_alone(chip):
     """The decode window of a spec with an indexer, compiled for a
     described v5e: the only operations that take a whole K or V cache as
-    an operand are the append's scatter and the gather of the selected
-    rows (and the plumbing that carries the arrays through the loops);
-    the index cache rests as it is stored, positions in the lanes, and
-    no cache is copied whole, at the program's edges or inside it
-    (stored `[S, T, 64]` the compiler re-laid every index cache twice a
-    window and once a token step: PERF.md section 6, PR 34)."""
+    an operand are the append's scatter and, inside the loop over the
+    live rows' groups, the gather of the selected rows (and the plumbing
+    that carries the arrays through the loops); the index cache rests as
+    it is stored, positions in the lanes, and no cache is copied whole,
+    at the program's edges or inside it (stored `[S, T, 64]` the
+    compiler re-laid every index cache twice a window and once a token
+    step: PERF.md section 6, PR 34). The sort and the gather are a
+    GROUP of rows tall, not the batch: a fold that sorts and gathers for
+    every slot again, live or not, fails here without a chip (PERF.md
+    section 6, PR 35)."""
     from idc_models_tpu.models import lm
 
     rep = NamedSharding(chip, P())
@@ -216,15 +223,21 @@ def test_sparse_window_reads_index_keys_and_selected_rows_alone(chip):
     typed = {name: t for name, t, *_ in ins}
     plumbing = {"parameter", "get-tuple-element", "tuple", "while", "call",
                 "conditional", "bitcast", "optimization-barrier"}
-    readers = [(op, line) for _, _, op, operands, line in ins
+    readers = [(op, t, line) for _, t, op, operands, line in ins
                if op not in plumbing
                and any(typed.get(o, "").startswith(kv_type) for o in operands)]
     assert readers, "the program names no K/V cache at all"
-    for op, line in readers:
+    for op, _, line in readers:
         name = re.search(r'op_name="([^"]*)"', line)
-        assert name and re.search(r"attn_sparse/(gather|scatter)$",
-                                  name.group(1)), line[:300]
-    assert {op for op, _ in readers} >= {"gather", "scatter"}
+        assert name and re.search(
+            r"(^|/)(attn_sparse/scatter|while/body/attn_sparse/gather)$",
+            name.group(1)), line[:300]
+    assert {op for op, *_ in readers} >= {"gather", "scatter"}
+    # two gathers (K, V) and one sort a layer, each of one group's rows
+    gathered = [t.split("{")[0] for op, t, _ in readers if op == "gather"]
+    assert gathered == [f"bf16[{S_GROUP},{S_TOPK},{S_KV},{S_D}]"] * 4
+    sorts = re.findall(r"= \((f32\[[\d,]+\])\S* .*? sort\(.*dsa_select", win)
+    assert sorts == [f"f32[{S_GROUP},{S_T_MAX}]"] * 2, sorts
     # whole-cache copies: none, of K/V or of the index keys, in either
     # program; and the index cache keeps its stored layout throughout
     chunk = efns.prefill_chunk.lower(

@@ -191,6 +191,84 @@ def test_select_topk_is_lax_top_k_with_ties_to_the_lower_position():
         np.asarray(rd.pack_bits(jnp.asarray(got))), keye_ref.pack_bits(got))
 
 
+GRP = rd._FOLD_GROUP
+LIVE_MASKS = {
+    "none": [],
+    "first slot": [0],
+    "last slot": [2 * GRP],
+    "scattered, fewer than a group": list(range(1, 2 * GRP, 3))[:GRP - 1],
+    "a whole group": list(range(0, 2 * GRP, 2)),
+    "a group and one": list(range(GRP)) + [2 * GRP],
+    "all": list(range(2 * GRP + 1)),
+}
+
+
+@pytest.mark.parametrize("rows", LIVE_MASKS.values(), ids=LIVE_MASKS.keys())
+def test_decode_fold_selects_gathers_and_attends_for_live_rows_alone(rows):
+    """`make_sparse_decode` over a batch of two groups and one slot, the
+    slots at different lengths (below `topk` and far above it), against
+    the fold spelled over ALL rows (append, score, `lax.top_k`, gather,
+    softmax): the live rows select and attend as there, the dead ones
+    report -1, put out zeros and leave their rows of all three caches as
+    they were, and the account says how many rows were folded."""
+    from idc_models_tpu import mesh as meshlib
+
+    b, t, h, g, d, j, di, k = 2 * GRP + 1, 96, 4, 2, 128, 3, 8, 12
+    r = iter(jax.random.split(jax.random.key(5), 9))
+    norm = lambda *shape: jax.random.normal(next(r), shape, jnp.float32)
+    kc, vc, ic = norm(b, t, g, d), norm(b, t, g, d), norm(b, di, t)
+    q, kt, vt = norm(b, 1, h, d), norm(b, 1, g, d), norm(b, 1, g, d)
+    qi, kit, w = norm(b, 1, j, di), norm(b, 1, 1, di), norm(b, 1, j)
+    pos = jnp.asarray((np.arange(b) * 37 + 5) % (t - 1), jnp.int32)
+    assert (np.asarray(pos) < k).any() and (np.asarray(pos) > 4 * k).any()
+    live = np.zeros(b, bool)
+    live[rows] = True
+    fold = jax.jit(rd.make_sparse_decode(meshlib.seq_mesh(1), topk=k))
+    out, kc2, vc2, ic2, account = jax.tree.map(np.asarray, fold(
+        kc, vc, ic, q, kt, vt, (qi, kit, w), pos, jnp.asarray(live)))
+
+    # the fold over every row, as it stood before the live rows' groups
+    at, every = np.asarray(pos), np.arange(b)
+    want_kc = np.asarray(kc).copy()
+    want_vc = np.asarray(vc).copy()
+    want_ic = np.asarray(ic).copy()
+    want_kc[every, at] = np.asarray(kt)[:, 0]
+    want_vc[every, at] = np.asarray(vt)[:, 0]
+    want_ic[every, :, at] = np.asarray(kit)[:, 0, 0]
+    hi = dict(precision="highest")
+    score = jnp.sum(jnp.maximum(jnp.einsum("bjd,bdt->bjt", qi[:, 0], want_ic,
+                                           **hi), 0.0)
+                    * w[:, 0, :, None], axis=1)
+    score = jnp.where(np.arange(t)[None, :] <= at[:, None], score, -jnp.inf)
+    top, idx = jax.lax.top_k(score, k)
+    valid = np.asarray(top) > -np.inf
+    kg, vg = want_kc[every[:, None], idx], want_vc[every[:, None], idx]
+    qh = np.asarray(q)[:, 0].reshape(b, g, h // g, d)
+    s = jnp.einsum("bgrd,bkgd->bgrk", qh, kg, **hi) * d ** -0.5
+    p = jax.nn.softmax(jnp.where(valid[:, None, None, :], s, -jnp.inf), -1)
+    want = np.asarray(jnp.einsum("bgrk,bkgd->bgrd", p, vg, **hi)
+                      ).reshape(b, h, d)
+
+    dead = ~live
+    np.testing.assert_allclose(out[live, 0], want[live], atol=2e-5, rtol=0)
+    assert (out[dead] == 0).all()
+    got = account["selected"]
+    assert got.shape == (b, k) and (got[dead] == -1).all()
+    for row in np.flatnonzero(live):
+        assert (got[row] >= 0).sum() == min(at[row] + 1, k)
+        assert set(got[row][got[row] >= 0]) == set(
+            np.asarray(idx)[row][valid[row]])
+    np.testing.assert_allclose(
+        account["sel_share"],
+        np.where(live, np.minimum(at + 1, k) / (at + 1.0), 0.0), rtol=1e-6)
+    assert (account["sel_rows"] == live).all()
+    assert account["fold_rows"] == -(-live.sum() // GRP) * GRP
+    for new, old, full in ((kc2, kc, want_kc), (vc2, vc, want_vc),
+                           (ic2, ic, want_ic)):
+        assert (new[dead] == np.asarray(old)[dead]).all()
+        assert (new[live] == full[live]).all()
+
+
 def test_in_place_prefill_touches_no_other_slot_and_owns_no_row(model):
     """A request's chunks write the reserved slot's own rows: the other
     slots' rows of every cache (K, V, index keys) stay bit-identical, a
@@ -272,6 +350,28 @@ def test_server_serves_the_spec_and_counts_its_selection(model):
     names = server.metrics._reg.prometheus_text()
     assert "serve_dsa_selected_share" in names
     assert "serve_index_cache_bytes" in names
+
+
+def test_summary_says_how_many_rows_the_fold_ran_for_a_live_one(model):
+    """`serve_dsa_folded_over_live`: rows the decode fold sorted,
+    gathered and attended for over live (step, slot) pairs,
+    ceil(n / group) * group / n with n slots live. One request alone on
+    a server of a group and one slot reads the group; every slot live
+    reads two groups over the slots."""
+    spec, params = model
+    server = LMServer(params, spec=spec, t_max=T_MAX, n_slots=GRP + 1,
+                      window=3, prefill_chunk=CHUNK, cache_dtype=jnp.float32)
+    server.run([(0.0, Request(id="r", prompt=tuple(prompt(30)),
+                              max_new_tokens=7))])
+    assert server.summary()["serve_dsa_folded_over_live"] == GRP
+    assert "serve_dsa_folded_over_live" in (
+        server.metrics._reg.prometheus_text())
+    eng = engine(model, n_slots=GRP + 1)
+    for slot in range(GRP + 1):
+        eng.admit(slot, prompt(20 + slot, slot), 5)
+    eng.step_window(2)
+    assert eng.last_dsa["rows"] == 2 * (GRP + 1)
+    assert eng.last_dsa["fold_rows"] == 2 * 2 * GRP
 
 
 def test_the_eight_shares_add_up_to_the_uncut_layer():
