@@ -68,7 +68,16 @@ class Result:
     whatever clean prefix was generated). `attempts`/`retried` expose
     the retry policy's work: a request recovered from a poisoned slot
     finishes with attempts > 1 and its output bit-identical to an
-    unfaulted run (the engine's serial-parity contract)."""
+    unfaulted run (the engine's serial-parity contract).
+    `queue_ms` + `reserved_ms` + `prefill_ms` = `ttft_ms`, the request's
+    way to its first token on the scheduler's clock: queued (submit ->
+    a slot claimed), reserved (the slot claimed -> its first prefill
+    dispatch: behind the prompts admitted before it; 0 on an unchunked
+    engine) and prefilling (that dispatch -> first token: its own
+    chunks, the cycles between them, the insert and the first window).
+    None from the first phase the request never reached. Which one
+    dominates says what to add: slots (queue), prefill chunks a cycle
+    (reserved), or shorter prompts and chunks (prefill)."""
     id: str
     tokens: list
     status: str
@@ -81,6 +90,10 @@ class Result:
     trace_id: str | None = None
     attempts: int = 1
     retried: bool = False
+    # ttft_ms by phase (see the docstring)
+    queue_ms: float | None = None
+    reserved_ms: float | None = None
+    prefill_ms: float | None = None
 
 
 class LMServer:
@@ -540,12 +553,15 @@ class LMServer:
 
 
 def _to_result(e) -> Result:
+    queue_ms, reserved_ms, prefill_ms = (
+        None if s is None else s * 1e3 for s in e.phases())
     return Result(
         id=e.rid, tokens=list(e.tokens), status=e.status,
         finish_reason=e.finish_reason, error=e.error,
         trace_id=e.trace_id, attempts=e.attempts, retried=e.retried,
         ttft_ms=(None if e.t_first is None
                  else (e.t_first - e.t_submit) * 1e3),
+        queue_ms=queue_ms, reserved_ms=reserved_ms, prefill_ms=prefill_ms,
         latency_ms=(None if e.t_done is None
                     else (e.t_done - e.t_submit) * 1e3))
 

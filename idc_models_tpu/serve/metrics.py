@@ -283,7 +283,6 @@ class ServingMetrics:
         self.occupancies: list[float] = []
         self.cycle_tokens: list[int] = []
         self.cycle_prefill_s: list[float] = []  # per-cycle decode stall
-        self._wait_by_rid: dict = {}
         self._t_first: float | None = None
         self._t_last: float | None = None
 
@@ -310,13 +309,23 @@ class ServingMetrics:
         event type, new keys only: existing serve.jsonl consumers see
         an unchanged record schema for the events they already parse."""
         self.queue_wait_s.append(wait_s)
-        self._wait_by_rid[rid] = wait_s
         if self.slo is not None and self.slo.has("queue_wait"):
             self.slo.observe("queue_wait", wait_s)
         self._log(event="serve_admit", id=rid, queue_wait_ms=wait_s * 1e3)
 
-    def on_first_token(self, rid, ttft_s: float, *,
-                       tenant=None) -> None:
+    def on_first_token(self, rid, ttft_s: float, *, tenant=None,
+                       queue_s: float | None = None,
+                       reserved_s: float | None = None,
+                       prefill_s: float | None = None) -> None:
+        """A request's first decode window landed `ttft_s` seconds after
+        submit, of which `queue_s` queued, `reserved_s` in a reserved
+        slot before its first prefill dispatch and `prefill_s` from that
+        dispatch on (the scheduler's `Entry.phases()`: they add up to
+        `ttft_s`). The event carries all three; since they arrived its
+        `prefill_ms` is the third alone, and what it used to hold, slot
+        claimed -> first token, is `reserved_ms + prefill_ms` (the same
+        number on an unchunked engine), which `summary()`'s
+        `serve_prefill_ms_*` still reports."""
         self._m_ttft.observe(ttft_s)
         if self.slo is not None and self.slo.has("ttft"):
             self.slo.observe("ttft", ttft_s)
@@ -327,21 +336,16 @@ class ServingMetrics:
             self._m_t_ttft.observe(ttft_s, tenant=tenant)
             self.tenant_ttft_s.setdefault(tenant, []).append(ttft_s)
         self.ttft_s.append(ttft_s)
-        wait = self._wait_by_rid.pop(rid, None)
-        prefill = None if wait is None else max(ttft_s - wait, 0.0)
-        if prefill is not None:
-            self.prefill_s.append(prefill)
+        if prefill_s is not None:
+            self.prefill_s.append(reserved_s + prefill_s)
         self._log(event="serve_first_token", id=rid,
-                  ttft_ms=ttft_s * 1e3,
-                  prefill_ms=None if prefill is None else prefill * 1e3)
+                  ttft_ms=ttft_s * 1e3, queue_ms=_r(queue_s, 1e3, 6),
+                  reserved_ms=_r(reserved_s, 1e3, 6),
+                  prefill_ms=_r(prefill_s, 1e3, 6))
 
     def on_finish(self, rid, *, n_tokens: int, ttft_s: float | None,
                   decode_s: float, reason: str, t: float,
                   tenant=None) -> None:
-        # a request cancelled before its first token never reaches
-        # on_first_token — drop its queue-wait entry here too or the
-        # dict grows for the server's lifetime under deadline pressure
-        self._wait_by_rid.pop(rid, None)
         self.finished += 1
         if reason in ("timeout", "deadline"):
             self.timed_out += 1
@@ -756,9 +760,13 @@ class ServingMetrics:
             "serve_prefill_ms_p50": _r(_pct(self.prefill_s, 50), 1e3),
             "serve_prefill_ms_p95": _r(_pct(self.prefill_s, 95), 1e3),
             "serve_token_ms_p50": _r(_pct(self.token_s, 50), 1e3),
-            # decode-side tail: p95 inter-token latency (additive key,
-            # ISSUE 20) — the fleet SLO reads this side of the request,
-            # TTFT the prefill side
+            # decode-side tail (additive key, ISSUE 20): the p95 over
+            # requests of each one's MEAN seconds a token after its
+            # first, which a long request's slow cycles hardly move. Not
+            # the gap between two deliveries a stream feels: that is
+            # the benchmark's `delivery_gap_p95_ms`, off the
+            # `serve.tick` spans. The fleet SLO reads this side of the
+            # request, TTFT the prefill side
             "serve_token_ms_p95": _r(_pct(self.token_s, 95), 1e3),
             "serve_slot_occupancy": (
                 round(float(np.mean(self.occupancies)), 4)
@@ -916,8 +924,8 @@ class ServingMetrics:
             self.logger.log(**record)
 
 
-def _r(v, scale) -> float | None:
-    return None if v is None else round(v * scale, 2)
+def _r(v, scale, digits: int = 2) -> float | None:
+    return None if v is None else round(v * scale, digits)
 
 
 def aggregate_summaries(metrics_list) -> dict:

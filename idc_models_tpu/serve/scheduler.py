@@ -64,6 +64,10 @@ def _next_trace_id() -> str:
     return f"{os.getpid():x}-{next(_TRACE_IDS):x}"
 
 
+def _ms(seconds: float | None) -> float | None:
+    return None if seconds is None else round(seconds * 1e3, 3)
+
+
 @dataclasses.dataclass(eq=False)     # identity eq: prompts are arrays
 class Entry:
     """One request's lifetime record inside the scheduler: identity and
@@ -92,6 +96,13 @@ class Entry:
     deadline: float | None = None
     t_submit: float = 0.0
     t_admit: float | None = None
+    # the clock when the first prefill dispatch was issued for this
+    # admission (`_step_prefills`' first `prefill_step`; an unchunked
+    # engine prefills inside `admit`, so = t_admit) and the prefill
+    # dispatches spent on it: between t_admit and t_chunk0 the slot
+    # stood reserved behind the prompts admitted before it
+    t_chunk0: float | None = None
+    chunks: int = 0
     t_first: float | None = None
     t_done: float | None = None
     slot: int | None = None
@@ -114,6 +125,20 @@ class Entry:
     tenant: str | None = None
     tid: int = 0
     pages_reserved: int = 0
+
+    def phases(self) -> tuple:
+        """(queue_s, reserved_s, prefill_s): submit -> admit, admit ->
+        first prefill dispatch, first prefill dispatch -> first token
+        (its own chunks, the cycles between them, the insert and the
+        first window). One clock, shared instants: they add up to the
+        time to the first token. None from the first phase the request
+        never reached."""
+        marks = (self.t_submit, self.t_admit, self.t_chunk0, self.t_first)
+        out, reached = [], True
+        for a, b in zip(marks, marks[1:]):
+            reached = reached and b is not None
+            out.append(b - a if reached else None)
+        return tuple(out)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -557,15 +582,15 @@ class Scheduler:
                                           tag=e.rid, tid=e.tid)
             else:
                 self._running[slot] = e
+                e.t_chunk0, e.chunks = e.t_admit, 1
                 self.engine.admit(slot, e.prompt, e.budget, rng=e.rng,
                                   eos_id=eos, tag=e.rid, tid=e.tid)
             # recorded only AFTER the engine accepted the request — an
             # admit that raises must not leave a phantom queue-wait
-            # sample (and _wait_by_rid entry) behind
+            # sample behind
             if e.queue_span is not None:
                 e.queue_span.close(
-                    queue_wait_ms=round((e.t_admit - e.t_submit) * 1e3,
-                                        3))
+                    queue_wait_ms=_ms(e.t_admit - e.t_submit))
             if self.metrics:
                 self.metrics.on_admit(e.rid, e.t_admit - e.t_submit)
             admitted += 1
@@ -593,6 +618,10 @@ class Scheduler:
                     self._prefill_error_pending -= 1
                     raise InjectedPrefillError(
                         f"injected prefill-chunk failure (slot {slot})")
+                e = self._prefilling[slot]
+                if e.t_chunk0 is None:
+                    e.t_chunk0 = self.clock()
+                e.chunks += 1
                 finished = self.engine.prefill_step(slot)
             except Exception as exc:
                 if self.retry is None:
@@ -637,7 +666,8 @@ class Scheduler:
             e.attempts += 1
             e.retried = True
             e.tokens = []
-            e.t_first = None
+            e.t_first = e.t_chunk0 = None
+            e.chunks = 0
             e.status = "retrying"
             e.not_before = now + delay
             self._retrying.append(e)
@@ -756,12 +786,19 @@ class Scheduler:
         `serve.prefill_chunk` / `serve.insert` spans nested under the
         two passes. `serve.turnaround` (detached, under the tick) runs
         from collect's return to the next dispatch's return: the host's
-        side of the device's idle time between two windows. ACROSS
+        side of the device's idle time between two windows; it closes
+        with `admitted`, the refill pass's admissions, beside `slots`
+        and `dispatched`. The tick span itself closes with the cycle's
+        record (`_tick`, step 8: `slots`, `decoding`, `prefilling`,
+        `free`, `queue`, `admitted`, `chunk_steps`, `tokens`,
+        `dispatched`), so what a cycle did, and in what state the slots
+        stood, is read off the span, cut to any stretch of time, and
+        not off a second store. ACROSS
         ticks, each request's detached `serve.request` span (opened at
         submit) accumulates its lifecycle chain — see the Entry fields
         above."""
         with trace.span("serve.tick") as tick_span:
-            return self._tick(tick_span.span_id)
+            return self._tick(tick_span)
 
     def quiesce(self) -> list[Entry]:
         """One normal cycle with the end-of-tick window dispatch
@@ -775,11 +812,11 @@ class Scheduler:
         self._skip_dispatch = True
         try:
             with trace.span("serve.tick", quiesce=True) as tick_span:
-                return self._tick(tick_span.span_id)
+                return self._tick(tick_span)
         finally:
             self._skip_dispatch = False
 
-    def _tick(self, tick_span_id=None) -> list[Entry]:
+    def _tick(self, tick_span) -> list[Entry]:
         now = self.clock()
         done: list[Entry] = []
         # 0. declarative fault drills (default-off): stall/crash/
@@ -864,7 +901,7 @@ class Scheduler:
             # the tick and split it; the dispatch, the decision not to
             # dispatch and the failure paths each close it)
             turnaround = (trace.start_span("serve.turnaround",
-                                           parent=tick_span_id)
+                                           parent=tick_span.span_id)
                           if out or spec else None)
             if (out or spec) and self.metrics:
                 self.metrics.on_dispatch("verify" if spec else "window")
@@ -977,7 +1014,11 @@ class Scheduler:
         #    drafter proposed and every running slot has verify room)
         #    ONE draft-and-verify dispatch emitting up to draft_k + 1
         #    tokens per slot
-        occupancy = len(self._running) / self.engine.n_slots
+        n_slots = self.engine.n_slots
+        decoding, prefilling = len(self._running), len(self._prefilling)
+        queued = len(self.queue)
+        occupancy = decoding / n_slots
+        dispatched = 0
         if self._running and not self._skip_dispatch:
             try:
                 proposal = (self._propose_drafts(got) if self._spec
@@ -1007,9 +1048,10 @@ class Scheduler:
                             _wsp.set(rids=[e.rid for e
                                            in self._running.values()])
                         self.engine.begin_window(self.window)
-                self._end_turnaround(turnaround, True)
+                dispatched = 1
+                self._end_turnaround(turnaround, True, admitted=n2)
             except Exception as e:
-                self._end_turnaround(turnaround, False, e)
+                self._end_turnaround(turnaround, False, e, admitted=n2)
                 # entries the just-collected window COMPLETED (EOS/
                 # budget/deadline) are real results, not casualties:
                 # finalize them with their true statuses — plus the
@@ -1021,7 +1063,7 @@ class Scheduler:
                 self._abort_running(e)
                 raise
         else:
-            self._end_turnaround(turnaround, False)
+            self._end_turnaround(turnaround, False, admitted=n2)
         # 7. deferred bookkeeping — runs WHILE the new window computes.
         #    Cycles that only admitted/prefilled (nothing decoding yet —
         #    e.g. a long prompt's chunk-by-chunk admission) STILL record:
@@ -1074,16 +1116,31 @@ class Scheduler:
             sizes = getattr(self.engine, "cache_sizes", None)
             if on_jit is not None and sizes is not None:
                 on_jit(sum(sizes().values()))
+        # 8. the cycle's record, on its span (ints only; a tick that
+        #    raised closed without it): the slots' states and the queue
+        #    as they stood at the dispatch decision, where `occupancy`
+        #    is taken, and the cycle's work. `free` is what neither
+        #    decodes nor is reserved BY THE SCHEDULER'S OWN BOOKS: a
+        #    slot released this cycle while its last window flew counts
+        #    as free here, a cycle before the engine hands it out again
+        tick_span.set(slots=n_slots, decoding=decoding,
+                      prefilling=prefilling,
+                      free=n_slots - decoding - prefilling, queue=queued,
+                      admitted=admitted, chunk_steps=chunk_steps,
+                      tokens=emitted, dispatched=dispatched)
         return done
 
-    def _end_turnaround(self, span, dispatched: bool, error=None) -> None:
+    def _end_turnaround(self, span, dispatched: bool, error=None, *,
+                        admitted: int = 0) -> None:
         """Close a tick's `serve.turnaround` (None: no window was
-        collected, so nothing was opened)."""
+        collected, so nothing was opened). `admitted`: the refill
+        pass's admissions, whose host work lies inside the span."""
         if span is None:
             return
         if error is not None:
             span.set(error=type(error).__name__)
-        span.close(slots=len(self._running), dispatched=dispatched)
+        span.close(slots=len(self._running), dispatched=dispatched,
+                   admitted=admitted)
 
     def _propose_drafts(self, got):
         """The speculative policy pass — pure host work in the
@@ -1306,7 +1363,8 @@ class Scheduler:
         for e in out:
             e.status, e.slot = "pending", None
             e.tokens = []
-            e.t_first = None
+            e.t_first = e.t_chunk0 = None
+            e.chunks = 0
             if e.queue_span is not None:
                 e.queue_span.close(migrated=True)
                 e.queue_span = None
@@ -1336,16 +1394,21 @@ class Scheduler:
         for e, toks in got:
             if toks and e.t_first is None:
                 e.t_first = t_now
+                queue_s, reserved_s, prefill_s = e.phases()
                 trace.point(
                     "serve.first_token",
                     parent=(e.span.span_id if e.span is not None
                             else None),
                     rid=e.rid,
-                    ttft_ms=round((t_now - e.t_submit) * 1e3, 3))
+                    ttft_ms=_ms(t_now - e.t_submit),
+                    queue_ms=_ms(queue_s), reserved_ms=_ms(reserved_s),
+                    prefill_ms=_ms(prefill_s), chunks=e.chunks,
+                    prompt_len=len(e.prompt))
                 if self.metrics:
-                    self.metrics.on_first_token(e.rid,
-                                                t_now - e.t_submit,
-                                                tenant=e.tenant)
+                    self.metrics.on_first_token(
+                        e.rid, t_now - e.t_submit, tenant=e.tenant,
+                        queue_s=queue_s, reserved_s=reserved_s,
+                        prefill_s=prefill_s)
             e.tokens.extend(toks)
             emitted += len(toks)
             if progress is not None and toks:

@@ -387,9 +387,10 @@ def test_turnaround_spans_one_cycle(devices, params, tracer, chunk):
     for t in turns:
         tick = _covering_tick(recs, t)
         assert _inside(t, tick)
-        assert set(t["attrs"]) == {"slots", "dispatched"}
+        assert set(t["attrs"]) == {"slots", "dispatched", "admitted"}
         refills = [r for r in recs["serve.refill"] if r["parent"] == tick["id"]]
         assert len(refills) == 1 and _inside(refills[0], t)
+        assert t["attrs"]["admitted"] == refills[0]["attrs"]["admitted"]
         wins = [w for w in recs.get("serve.window", [])
                 if w["parent"] == tick["id"]]
         assert len(wins) == (1 if t["attrs"]["dispatched"] else 0)

@@ -341,8 +341,10 @@ def test_serving_metrics_jsonl_schema_unchanged(tmp_path):
     assert set(by_event["serve_submit"]) == {"ts", "event", "id"}
     assert set(by_event["serve_admit"]) == {"ts", "event", "id",
                                             "queue_wait_ms"}
+    # ISSUE 36: two additive keys, the phases before `prefill_ms`
     assert set(by_event["serve_first_token"]) == {
-        "ts", "event", "id", "ttft_ms", "prefill_ms"}
+        "ts", "event", "id", "ttft_ms", "prefill_ms", "queue_ms",
+        "reserved_ms"}
     assert set(by_event["serve_finish"]) == {"ts", "event", "id",
                                              "tokens", "reason",
                                              "ttft_ms"}
