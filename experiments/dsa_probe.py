@@ -16,6 +16,10 @@ one row. `window`: the served model's decode window of 8 steps with 1
 to 16 of 16 slots live, through `SlotEngine` of the checkout at ROOT
 (default: this one; a copy of the parent commit reads the other side),
 with `ring_decode._FOLD_GROUP` set to GROUP where that is given.
+`select`, run the same way: the chunk fold's top-k selection alone,
+the counting passes in XLA that the fold ran before against the Pallas
+kernel that replaced them, at three frontiers and 8 to 128 query rows a
+grid cell (this chose `ops.topk_select.ROWS`).
 """
 
 from __future__ import annotations
@@ -116,6 +120,96 @@ def pieces(note):
     note("index scores [16,16,64]x[16,T,64]", timed(isc, qi, ic))
 
 
+def parent_sortable(x):
+    """The sort-free selection of the chunk fold before its kernel
+    (`ring_decode._sortable`, `_bisect`, `_select_topk` and `pack_bits`
+    as they were), the other side of `select`."""
+    u = lax.bitcast_convert_type(x, jnp.uint32)
+    return jnp.where(u >> 31 == 0, u | jnp.uint32(0x80000000), ~u)
+
+
+def parent_bisect(count, want, bits):
+    def bit(i, cur):
+        cand = cur | (jnp.uint32(1) << (bits - 1 - i).astype(jnp.uint32))
+        return jnp.where(count(cand) >= want, cand, cur)
+
+    return lax.fori_loop(0, bits, bit, jnp.zeros(want.shape, jnp.uint32))
+
+
+def parent_select_topk(score, k, frontier, blk):
+    n, t = score.shape
+    keys = parent_sortable(score)
+    pos = jnp.arange(t, dtype=jnp.uint32)
+
+    def count(test):
+        def body(j, acc):
+            kb = lax.dynamic_slice_in_dim(keys, j * blk, blk, axis=1)
+            pb = lax.dynamic_slice_in_dim(pos, j * blk, blk)
+            return acc + jnp.sum(test(kb, pb), axis=1, dtype=jnp.int32)
+
+        trips = (jnp.clip(frontier, 0, t) + blk - 1) // blk
+        return lax.fori_loop(0, trips, body, jnp.zeros(n, jnp.int32))
+
+    want = jnp.full(n, k, jnp.int32)
+    kth = parent_bisect(lambda v: count(lambda kb, _: kb >= v[:, None]),
+                        want, 32)
+    room = k - count(lambda kb, _: kb > kth[:, None])
+    last = parent_bisect(
+        lambda v: -count(lambda kb, pb: (kb == kth[:, None])
+                         & (pb[None, :] < v[:, None])),
+        1 - room, max(1, (t - 1).bit_length()))
+    return (((keys > kth[:, None])
+             | ((keys == kth[:, None]) & (pos[None, :] <= last[:, None])))
+            & (score > -jnp.inf))
+
+
+def parent_pack_bits(mask):
+    t = mask.shape[-1]
+    mask = jnp.pad(mask, [(0, 0)] * (mask.ndim - 1) + [(0, -t % 32)])
+    bits = mask.reshape(*mask.shape[:-1], -1, 32).astype(jnp.uint32)
+    return jnp.sum(bits << jnp.arange(32, dtype=jnp.uint32), axis=-1,
+                   dtype=jnp.uint32)
+
+
+def select(note):
+    """The chunk fold's selection alone: a chunk of C = 512 queries whose
+    last ends at `frontier` (query c sees positions up to frontier - C +
+    c), scores [512, 32,768] float32, the parent's counting passes in
+    XLA (blocks of 2,048, then `pack_bits`) against the kernel
+    (`ops/topk_select.py`) at 8 to 128 rows a grid cell and 16k or 32k
+    keys a trip of a counting pass; `ties`:
+    scores of seven values, so every row runs its tie passes. The two
+    must give the same bits."""
+    from idc_models_tpu.ops import topk_select
+
+    parent = jax.jit(lambda s, f: parent_pack_bits(
+        parent_select_topk(s, K, f, 2048)))
+    for frontier, kind in ((2048, "normal"), (8192, "normal"),
+                           (28672, "normal"), (8192, "ties")):
+        key = jax.random.key(frontier)
+        if kind == "ties":
+            x = jax.random.randint(key, (C, T), -3, 4).astype(jnp.float32)
+        else:
+            x = jax.random.normal(key, (C, T), jnp.float32)
+        see = (jnp.arange(T)[None, :]
+               <= frontier - C + jnp.arange(C)[:, None])
+        x = jnp.where(see, x, -jnp.inf)
+        f = jnp.int32(frontier)
+        want = parent(x, f)
+        note(f"parent passes, frontier {frontier} {kind}",
+             timed(parent, x, f, n=5), frontier=frontier)
+        for rows, span in ((8, 32768), (16, 32768), (32, 32768),
+                           (64, 32768), (128, 32768), (64, 16384)):
+            topk_select.ROWS, topk_select._SPAN = rows, span
+            kern = jax.jit(lambda s, f: topk_select.select_topk_bits(
+                s, K, f))
+            same = bool((kern(x, f) == want).all())
+            note(f"kernel rows {rows} span {span}, frontier {frontier} "
+                 f"{kind}", timed(kern, x, f, n=20), frontier=frontier,
+                 rows=rows, span=span, equal=same)
+        topk_select.ROWS, topk_select._SPAN = 64, 32768
+
+
 def by_rows(note):
     """`lax.top_k` and the gather of the selected rows for the first 4,
     8 and 16 rows of the batch, the caches whole; and 16 x 2,048 rows of
@@ -193,7 +287,7 @@ def main():
     if section == "window":
         window(note, root, int(sys.argv[3]) if len(sys.argv) > 3 else None)
     else:
-        {"pieces": pieces, "rows": by_rows}[section](note)
+        {"pieces": pieces, "rows": by_rows, "select": select}[section](note)
 
 
 if __name__ == "__main__":

@@ -61,6 +61,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from idc_models_tpu import collectives
 from idc_models_tpu import mesh as meshlib
+from idc_models_tpu.ops import topk_select
 
 _MASKED = -1e30  # same finite sentinel as ring_attention._MASKED
 
@@ -1560,73 +1561,16 @@ def _scores_to_frontier(qi, w, ic, see, frontier, blk: int, row=None):
         jnp.full(w.shape[:-1] + (t,), -jnp.inf, jnp.float32))
 
 
-def _sortable(x):
-    """float32 -> uint32 whose unsigned order is the floats' order."""
-    u = lax.bitcast_convert_type(x, jnp.uint32)
-    return jnp.where(u >> 31 == 0, u | jnp.uint32(0x80000000), ~u)
-
-
-def _bisect(count, want, bits: int):
-    """Per row, the largest v < 2**bits (uint32) with ``count(v) >=
-    want`` where `count(v)` [N] falls as v grows and ``count(0) >= want``:
-    found bit by bit, `bits` counting passes and no sort."""
-    def bit(i, cur):
-        cand = cur | (jnp.uint32(1) << (bits - 1 - i).astype(jnp.uint32))
-        return jnp.where(count(cand) >= want, cand, cur)
-
-    return lax.fori_loop(0, bits, bit, jnp.zeros(want.shape, jnp.uint32))
-
-
-def _select_topk(score, k: int, frontier, blk: int):
-    """[N, T] bool: per row of score [N, T] float32 (-inf where a
-    position is not visible, and everywhere at or beyond `frontier`)
-    the k best-scoring visible positions, all of them where there are no
-    more than k; equal scores go to the lower position, as `lax.top_k`
-    breaks them. No sort: the k-th best score is found bit by bit over
-    the scores' sortable keys (32 counting passes over the blocks below
-    `frontier`), and where several positions tie at it, the position
-    of the last one that still fits likewise (log2 T passes)."""
-    n, t = score.shape
-    keys = _sortable(score)
-    pos = jnp.arange(t, dtype=jnp.uint32)
-
-    def count(test):
-        """Per row, how many columns below the frontier pass `test(keys
-        block, positions of the block)`."""
-        if t <= blk:
-            return jnp.sum(test(keys, pos), axis=1, dtype=jnp.int32)
-
-        def body(j, acc):
-            kb = lax.dynamic_slice_in_dim(keys, j * blk, blk, axis=1)
-            pb = lax.dynamic_slice_in_dim(pos, j * blk, blk)
-            return acc + jnp.sum(test(kb, pb), axis=1, dtype=jnp.int32)
-
-        return lax.fori_loop(0, _trips(frontier, 0, t, blk), body,
-                             jnp.zeros(n, jnp.int32))
-
-    want = jnp.full(n, k, jnp.int32)
-    kth = _bisect(lambda v: count(lambda kb, _: kb >= v[:, None]), want, 32)
-    # of the positions that tie at the k-th best score, the lowest
-    # `room` join those that score more: all below the largest position
-    # v that has fewer than `room` ties below it, and v itself
-    room = k - count(lambda kb, _: kb > kth[:, None])
-    last = _bisect(
-        lambda v: -count(lambda kb, pb: (kb == kth[:, None])
-                         & (pb[None, :] < v[:, None])),
-        1 - room, max(1, (t - 1).bit_length()))
-    return (((keys > kth[:, None])
-             | ((keys == kth[:, None]) & (pos[None, :] <= last[:, None])))
-            & (score > -jnp.inf))
-
-
-def pack_bits(mask):
-    """[.., T] bool -> [.., ceil(T / 32)] uint32: bit b of word m is
-    position 32 m + b."""
-    t = mask.shape[-1]
-    mask = jnp.pad(mask, [(0, 0)] * (mask.ndim - 1) + [(0, -t % 32)])
-    bits = mask.reshape(*mask.shape[:-1], -1, 32).astype(jnp.uint32)
-    return jnp.sum(bits << jnp.arange(32, dtype=jnp.uint32), axis=-1,
-                   dtype=jnp.uint32)
+def _bit_block(bits, at, n: int):
+    """[N, n] bool: positions [at, at + n) of packed bits [N, W] (bit b
+    of word m is position 32 m + b). `at` (traced) is 0 or a multiple of
+    n, and a block of n not a multiple of 32 lies within one word."""
+    w = lax.dynamic_slice_in_dim(bits, at // 32, -(-n // 32), axis=1)
+    m = (w[:, :, None] >> jnp.arange(32, dtype=jnp.uint32)) & 1
+    m = m.reshape(w.shape[0], -1).astype(jnp.bool_)
+    if n % 32:
+        m = lax.dynamic_slice_in_dim(m, at % 32, n, axis=1)
+    return m
 
 
 def make_sparse_decode(mesh: Mesh, *, topk: int,
@@ -1746,13 +1690,17 @@ def make_sparse_chunk_decode(mesh: Mesh, *, topk: int,
     beyond the request's frontier, where no fold reads before a decode
     step has written. Each of the C queries then scores the row's index
     keys up to the chunk's end (`dsa_index`), marks its own `topk` best
-    (`dsa_select`: counting passes, no sort; `_select_topk`) and attends
+    (`dsa_select`: one Pallas kernel, `ops.topk_select`: the k-th best by
+    counting passes over the scores held in VMEM, no sort) and attends
     the positions marked, by a mask over block-wise
     dense scores (`attn_sparse`): the same function as gathering them,
     at a chunk's arithmetic intensity. ``account["selected_bits"]``
-    [C, ceil(T / 32)] uint32 holds each query's choice (`pack_bits`).
-    Requires start + C <= T. One device only."""
+    [C, ceil(T / 32)] uint32 holds each query's choice, the kernel's
+    packed bits (bit b of word m is position 32 m + b), which the
+    attention unpacks block by block. Requires start + C <= T. One
+    device only."""
     _one_device(mesh, axis)
+    interpret = meshlib.pallas_interpret(mesh)
 
     def fn(kc, vc, ic, q, kt, vt, index, start, row):
         t, c, d = kc.shape[1], q.shape[1], q.shape[-1]
@@ -1777,10 +1725,9 @@ def make_sparse_chunk_decode(mesh: Mesh, *, topk: int,
                 qi, w, ic, lambda g: causal(g)[None], start + c, blk,
                 row=row)[0]                                      # [C, T]
         with jax.named_scope("dsa_select"):
-            chosen = _select_topk(score, min(topk, t), start + c, blk)
-            account = {"selected_bits": pack_bits(chosen)}
-        see = lambda g: lax.dynamic_slice_in_dim(
-            chosen, g[0], g.shape[0], axis=1)[None, None]
+            bits = topk_select.select_topk_bits(
+                score, min(topk, t), start + c, interpret=interpret)
+        see = lambda g: _bit_block(bits, g[0], g.shape[0])[None, None]
         with jax.named_scope("attn_sparse"):
             kc, vc = write(kc, kt), write(vc, vt)
             _, l, acc = _attend_to_frontier(
@@ -1788,7 +1735,7 @@ def make_sparse_chunk_decode(mesh: Mesh, *, topk: int,
                 scale=scale_, row=row)
             out = acc / jnp.maximum(l, 1e-37)[..., None]        # [1,H,C,D]
         return (jnp.moveaxis(out, 1, 2).astype(q.dtype), kc, vc, ic,
-                account)
+                {"selected_bits": bits})
 
     return fn
 

@@ -3162,7 +3162,7 @@ class SlotEngine:
         layers, else None: ``"window"``, the last dispatched window's,
         [steps, indexer layers, n_slots, topk] int32 (-1 where a slot
         saw fewer); ``"prefill"``, the last prefill chunk's, as bits
-        (`ring_decode.pack_bits`: bit b of word m is position 32 m + b),
+        (bit b of word m is position 32 m + b),
         [indexer layers, chunk, t_max / 32] uint32 (the rows past the
         prompt's end are padding's). A device fetch, for checks and
         debugging, beside `router_picks`."""

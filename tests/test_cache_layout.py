@@ -243,6 +243,21 @@ def test_sparse_window_reads_index_keys_and_selected_rows_alone(chip):
     chunk = efns.prefill_chunk.lower(
         params, caches, sds((), jnp.int32), sds((1, CHUNK), jnp.int32),
         sds((), jnp.int32), sds((), jnp.int32)).compile().as_text()
+    # the chunk's selection is ONE Mosaic kernel a layer, under
+    # `dsa_select`, and its counting passes no longer read a [C, T] array
+    # of sortable keys from HBM (PERF.md section 6): a fold that
+    # brings the XLA passes back fails here without a chip
+    chunk_ins = list(_instructions(chunk))
+    kernels = [l for _, _, op, _, l in chunk_ins if op == "custom-call"
+               and "tpu_custom_call" in l and "dsa_select" in l]
+    assert len(kernels) == 2, [l[:300] for l in kernels]
+    keys = f"u32[{CHUNK},{S_T_MAX}]"
+    chunk_typed = {name: t for name, t, *_ in chunk_ins}
+    assert not [l[:300] for _, t, op, operands, l in chunk_ins
+                if "reduce" in op + l.split(" = ")[0] and any(
+                    chunk_typed.get(o, "").startswith(keys)
+                    for o in operands)]
+    assert not [l[:300] for _, t, *_, l in chunk_ins if t.startswith(keys)]
     for text in (win, chunk):
         assert not [l for _, t, op, _, l in _instructions(text)
                     if op == "copy" and t.startswith((kv_type, ix_type))]

@@ -168,27 +168,59 @@ def test_the_selection_handed_out_is_the_references(model):
     assert np.isinf(deficit).any() and swapped.sum() > 0
 
 
-def test_select_topk_is_lax_top_k_with_ties_to_the_lower_position():
-    """The sort-free selection of the chunk fold against `lax.top_k`, on
-    scores full of exact ties, rows with fewer visible positions than k,
-    and a frontier inside the buffer (blocks of 8)."""
-    rng = np.random.default_rng(0)
-    n, t, k = 12, 64, 10
-    score = rng.integers(-3, 4, (n, t)).astype(np.float32)    # many ties
-    score[:4] = rng.normal(size=(4, t))
-    visible = np.arange(t)[None, :] <= (np.arange(n)[:, None] * 5 + 2)
+# (rows n, positions t, k, frontier, scores): every case also holds
+# rows that see fewer positions than k, and one that sees none
+SELECT_CASES = {
+    "ties-frontier-inside": (12, 64, 10, 57, "ties"),
+    "half-the-rows-see-nothing": (16, 64, 10, 64, "blind"),
+    "frontier-at-the-end": (12, 256, 40, 256, "normal"),
+    "frontier-below-k": (12, 256, 200, 150, "normal"),
+    "tiles-and-groups-ragged": (130, 9000, 300, 8999, "normal"),
+    "ties-at-the-kth": (24, 5000, 64, 4321, "ties"),
+}
+
+
+@pytest.mark.parametrize("case", SELECT_CASES, ids=list(SELECT_CASES))
+def test_select_topk_is_lax_top_k_with_ties_to_the_lower_position(case):
+    """The chunk fold's selection (`ops.topk_select`, the Pallas kernel
+    under the interpreter) against `lax.top_k`: the k best visible
+    positions of each row, ties to the lower position, all visible ones
+    where there are no more than k, packed as `keye_ref.pack_bits`
+    packs them."""
+    from idc_models_tpu.ops import topk_select
+
+    n, t, k, frontier, kind = SELECT_CASES[case]
+    rng = np.random.default_rng(t + k)
+    score = (rng.integers(-3, 4, (n, t)) if kind == "ties"
+             else rng.normal(size=(n, t))).astype(np.float32)
+    ends = rng.integers(0, frontier + 1, n)
+    ends[:2] = frontier, 0
+    if kind == "blind":
+        ends[n // 2:] = 0
+    visible = np.arange(t)[None, :] < ends[:, None]
     score = np.where(visible, score, -np.inf).astype(np.float32)
-    frontier = int(visible.sum(1).max())
-    got = np.asarray(jax.jit(lambda s: rd._select_topk(s, k, frontier, 8))(
-        jnp.asarray(score)))
+    got = np.asarray(jax.jit(lambda s: topk_select.select_topk_bits(
+        s, k, frontier))(jnp.asarray(score)))
     top, idx = jax.lax.top_k(jnp.asarray(score), k)
     want = np.zeros((n, t), bool)
     for r in range(n):
         want[r, np.asarray(idx[r])[np.asarray(top[r]) > -np.inf]] = True
-    assert (got == want).all()
-    assert (got.sum(1) == np.minimum(visible.sum(1), k)).all()
-    np.testing.assert_array_equal(
-        np.asarray(rd.pack_bits(jnp.asarray(got))), keye_ref.pack_bits(got))
+    assert got.shape == (n, -(-t // 32)) and got.dtype == np.uint32
+    np.testing.assert_array_equal(got, keye_ref.pack_bits(want))
+    chosen = np.asarray(keye_ref.unpack_bits(got, t))
+    assert (chosen.sum(1) == np.minimum(visible.sum(1), k)).all()
+
+
+@pytest.mark.parametrize("t,at,n", [(256, 128, 64), (100, 0, 100),
+                                    (72, 40, 8)])
+def test_a_block_of_the_packed_bits_reads_the_mask(t, at, n):
+    """The chunk fold's attention reads the selection block by block
+    out of the packed bits: whole words, the one block of a cache
+    shorter than a word's multiple, and a block inside one word."""
+    mask = np.random.default_rng(t).random((3, t)) < 0.5
+    got = jax.jit(rd._bit_block, static_argnums=2)(
+        jnp.asarray(keye_ref.pack_bits(mask)), jnp.int32(at), n)
+    np.testing.assert_array_equal(np.asarray(got), mask[:, at:at + n])
 
 
 GRP = rd._FOLD_GROUP
