@@ -138,11 +138,12 @@ def next_token_loss(logits: jax.Array, tokens: jax.Array) -> jax.Array:
 
 
 class Rotary(NamedTuple):
-    """Rotary positions of one layer kind: rotate-half pairing over the
-    first `dims` of each head, the rest passed through. `factor` > 1
-    turns on YaRN's frequency blend (`transformers`'
-    `_compute_yarn_parameters`); `attention_factor` multiplies cos and
-    sin both."""
+    """Rotary positions of one layer kind over the first `dims` of each
+    head, the rest passed through: rotate-half pairing (dim i with dim
+    i + dims/2), or with `interleave` adjacent pairs (dim 2i with dim
+    2i + 1, DeepSeek's `rope_interleave`). `factor` > 1 turns on YaRN's
+    frequency blend (`transformers`' `_compute_yarn_parameters`);
+    `attention_factor` multiplies cos and sin both."""
     theta: float
     dims: int
     factor: float = 1.0
@@ -150,6 +151,7 @@ class Rotary(NamedTuple):
     beta_fast: float = 32.0
     beta_slow: float = 1.0
     attention_factor: float = 1.0
+    interleave: bool = False
 
 
 class Indexer(NamedTuple):
@@ -157,11 +159,38 @@ class Indexer(NamedTuple):
     index queries of `dim` over ONE shared index key a position (cached
     beside K/V), scored ``sum_j w_j relu(q_j . k)``; a query attends the
     `topk` best-scoring positions at or before its own, all of them
-    while there are no more than that."""
+    while there are no more than that. The index queries project the
+    normed hidden state (`query` "hidden") or a latent layer's query
+    latent (`query` "q_latent"); key and weights the normed state."""
     heads: int
     dim: int
     topk: int
     rotary: Rotary | None = None    # on index query and index key
+    query: str = "hidden"
+
+
+class Latent(NamedTuple):
+    """Latent attention (DeepSeek-V3's MLA) as data. Queries pass
+    through a latent of `q_rank`; keys and values come from ONE cached
+    row a position: `kv_rank` latent values (normed) beside `rope`
+    rotated key values that every head shares. A head's query and key
+    are `nope` values from the latent beside the `rope` rotated ones;
+    its value is `v` values from the latent."""
+    q_rank: int
+    kv_rank: int
+    nope: int
+    rope: int
+    v: int
+
+    @property
+    def row(self) -> int:
+        """Values of the cached row: the latent and the rotated key."""
+        return self.kv_rank + self.rope
+
+    @property
+    def scale(self) -> float:
+        """The scores' scale, 1 / sqrt(a head's query-key width)."""
+        return (self.nope + self.rope) ** -0.5
 
 
 class LayerSpec(NamedTuple):
@@ -176,6 +205,17 @@ class LayerSpec(NamedTuple):
     experts: moe.Experts | None = None
     qk_norm: bool = False           # RMSNorm over each head of q and k
     indexer: Indexer | None = None  # None: attends every visible position
+    latent: Latent | None = None    # latent attention over one row a position
+    shares: int | None = None       # attends the positions layer `shares`
+    #                                 selected for the same query (a
+    #                                 latent layer with no indexer and no
+    #                                 index cache of its own)
+
+    @property
+    def selects(self) -> bool:
+        """True when the layer attends a selection of positions, its own
+        indexer's or another layer's."""
+        return self.indexer is not None or self.shares is not None
 
 
 class ModelSpec(NamedTuple):
@@ -200,7 +240,7 @@ class ModelSpec(NamedTuple):
                 and all(l.heads == l.kv_heads and l.window is None
                         and not l.gate and l.rotary is None
                         and l.ffn == "gelu" and not l.qk_norm
-                        and l.indexer is None
+                        and not l.selects and l.latent is None
                         and l.heads * l.head_dim == self.embed_dim
                         for l in self.layers))
 
@@ -210,16 +250,18 @@ class ModelSpec(NamedTuple):
                 f"{mechanism} cannot serve this layer spec: it holds one "
                 f"cache class of one head count at learned positions "
                 f"(attention_lm's block); window layers, grouped-query "
-                f"heads, rotary positions, expert layers and an "
-                f"indexer's choice of positions run on the contiguous "
-                f"chunked-prefill engine only (ROADMAP queue B)")
+                f"heads, rotary positions, expert layers, latent "
+                f"attention and an indexer's choice of positions run on "
+                f"the contiguous chunked-prefill engine only (ROADMAP "
+                f"queue B)")
 
     @property
     def sparse(self) -> bool:
-        """True when some layer has an indexer: its cache holds index
-        keys beside K/V, and the engine prefills such a model into the
-        reserved slot's own rows."""
-        return any(l.indexer is not None for l in self.layers)
+        """True when some layer attends a selection of positions (its
+        indexer's, whose keys its cache holds beside K/V, or another
+        layer's): the engine prefills such a model into the reserved
+        slot's own rows."""
+        return any(l.selects for l in self.layers)
 
     def require_dense(self, program: str) -> None:
         """The serial `Generator`'s programs (`_serving_fns`) hold a
@@ -347,6 +389,80 @@ def keye_spec(config: dict, *, held: tuple | None = None,
                      param_dtype=param_dtype)
 
 
+def glm_spec(config: dict, *, held: tuple | None = None,
+             param_dtype: str = "bfloat16") -> ModelSpec:
+    """The spec of a `model_type: glm_moe_dsa` checkpoint from its
+    `config.json` keys (zai-org/GLM-5.2): RMSNorm, latent attention
+    (`Latent`) with interleaved rotary, a DeepSeek-V3.2 indexer on the
+    layers `indexer_types` calls "full", and on the "shared" ones the
+    selection of the nearest "full" layer before it; a dense SwiGLU or
+    sigmoid-routed experts with a correction bias and one shared expert,
+    as `mlp_layer_types` says. `held = (first, count)` is this chip's
+    share of each expert layer (default: all `n_routed_experts`). What
+    `config.json` does not state, and this takes as read: the indexer's
+    queries read the query latent, its key and weights the normed hidden
+    state, its key is LayerNormed, and its rotary covers the first
+    `qk_rope_head_dim` of index query and key."""
+    for key, want in (("n_group", 1), ("topk_group", 1),
+                      ("scoring_func", "sigmoid"), ("topk_method", "noaux_tc"),
+                      ("attention_bias", False), ("norm_topk_prob", True),
+                      ("moe_layer_freq", 1)):
+        if config.get(key, want) != want:
+            raise ValueError(f"glm_spec knows {key}={want!r} alone, "
+                             f"the config states {config[key]!r}")
+    rope = config["rope_parameters"]
+    if rope.get("rope_type", "default") != "default":
+        raise ValueError(f"glm_spec knows rope_type='default' alone, the "
+                         f"config states {rope['rope_type']!r}")
+    if config["n_shared_experts"] not in (0, 1):
+        raise ValueError(f"glm_spec knows at most one shared expert, the "
+                         f"config states {config['n_shared_experts']}")
+    lat = Latent(config["q_lora_rank"], config["kv_lora_rank"],
+                 config["qk_nope_head_dim"], config["qk_rope_head_dim"],
+                 config["v_head_dim"])
+    if config["qk_head_dim"] != lat.nope + lat.rope:
+        raise ValueError(f"qk_head_dim {config['qk_head_dim']} is not "
+                         f"qk_nope_head_dim + qk_rope_head_dim")
+    n = config["n_routed_experts"]
+    first, count = held if held is not None else (0, n)
+    if not 0 <= first <= first + count <= n:
+        raise ValueError(f"held experts [{first}, {first + count}) lie "
+                         f"outside the router's {n}")
+    theta = float(rope["rope_theta"])
+    experts = moe.Experts(n, config["num_experts_per_tok"], first, count,
+                          routed_scale=float(config["routed_scaling_factor"]),
+                          shared=config["n_shared_experts"] == 1,
+                          scoring="sigmoid")
+    indexer = Indexer(config["index_n_heads"], config["index_head_dim"],
+                      config["index_topk"],
+                      rotary=Rotary(theta, lat.rope,
+                                    interleave=config["indexer_rope_interleave"]),
+                      query="q_latent")
+    layers, source = [], None
+    for i in range(config["num_hidden_layers"]):
+        kind = config["indexer_types"][i]
+        if kind not in ("full", "shared"):
+            raise ValueError(f"glm_spec knows indexer_types 'full' and "
+                             f"'shared' alone, layer {i} is {kind!r}")
+        if kind == "full":
+            source = i
+        elif source is None:
+            raise ValueError(f"layer {i} shares a selection, and no layer "
+                             f"before it makes one")
+        sparse = config["mlp_layer_types"][i] == "sparse"
+        layers.append(LayerSpec(
+            config["num_attention_heads"], 1, lat.nope + lat.rope,
+            rotary=Rotary(theta, lat.rope,
+                          interleave=config["rope_interleave"]),
+            ffn="experts" if sparse else "swiglu",
+            experts=experts if sparse else None,
+            indexer=indexer if kind == "full" else None,
+            latent=lat, shares=None if kind == "full" else source))
+    return ModelSpec(config["hidden_size"], tuple(layers), norm="rmsnorm",
+                     norm_eps=config["rms_norm_eps"], learned_pos=False,
+                     param_dtype=param_dtype)
+
+
 def init_params(spec: ModelSpec, vocab_size: int, rng, *,
                 seq_len: int = 0, mlp_dim: int = 0,
                 expert_dim: int = 0):
@@ -365,6 +481,11 @@ def init_params(spec: ModelSpec, vocab_size: int, rng, *,
         # other spec draws the keys it always drew
         more = iter(jax.random.split(jax.random.fold_in(rng, 1),
                                      8 * len(spec.layers)))
+    if any(l.latent or (l.experts and l.experts.scoring == "sigmoid")
+           for l in spec.layers):
+        # and one for latent attention and the router's correction bias
+        lat = iter(jax.random.split(jax.random.fold_in(rng, 2),
+                                    8 * len(spec.layers)))
 
     def mat(*shape, of=None):
         return (jax.random.normal(next(of or keys), shape, jnp.float32)
@@ -390,19 +511,31 @@ def init_params(spec: ModelSpec, vocab_size: int, rng, *,
         params["pos"] = (0.02 * jax.random.normal(
             next(keys), (seq_len, e), jnp.float32)).astype(dt)
     for i, l in enumerate(spec.layers):
-        hd = l.heads * l.head_dim
-        mha = {"wq": mat(e, hd), "wk": mat(e, l.kv_heads * l.head_dim),
-               "wv": mat(e, l.kv_heads * l.head_dim), "wo": mat(hd, e)}
-        if l.gate:
-            mha["wg"] = mat(e, l.heads)
-        block = {"ln1": norm(), "mha": mha, "ln2": norm()}
-        if l.qk_norm:
-            mha["q_norm"] = near(1.0, l.head_dim, of=more)
-            mha["k_norm"] = near(1.0, l.head_dim, of=more)
+        if l.latent is not None:
+            m = l.latent
+            block = {"ln1": norm(), "ln2": norm(), "mla": {
+                "wq_a": mat(e, m.q_rank, of=lat),
+                "q_a_norm": near(1.0, m.q_rank, of=lat),
+                "wq_b": mat(m.q_rank, l.heads * (m.nope + m.rope), of=lat),
+                "wkv_a": mat(e, m.row, of=lat),
+                "kv_a_norm": near(1.0, m.kv_rank, of=lat),
+                "wkv_b": mat(m.kv_rank, l.heads * (m.nope + m.v), of=lat),
+                "wo": mat(l.heads * m.v, e, of=lat)}}
+        else:
+            hd = l.heads * l.head_dim
+            mha = {"wq": mat(e, hd), "wk": mat(e, l.kv_heads * l.head_dim),
+                   "wv": mat(e, l.kv_heads * l.head_dim), "wo": mat(hd, e)}
+            if l.gate:
+                mha["wg"] = mat(e, l.heads)
+            block = {"ln1": norm(), "mha": mha, "ln2": norm()}
+            if l.qk_norm:
+                mha["q_norm"] = near(1.0, l.head_dim, of=more)
+                mha["k_norm"] = near(1.0, l.head_dim, of=more)
         if l.indexer is not None:
             x = l.indexer
+            q_from = l.latent.q_rank if x.query == "q_latent" else e
             block["idx"] = {
-                "wq": mat(e, x.heads * x.dim, of=more),
+                "wq": mat(q_from, x.heads * x.dim, of=more),
                 "wk": mat(e, x.dim, of=more), "ww": mat(e, x.heads, of=more),
                 "k_norm": {"scale": near(1.0, x.dim, of=more),
                            "bias": near(0.0, x.dim, of=more)}}
@@ -419,6 +552,10 @@ def init_params(spec: ModelSpec, vocab_size: int, rng, *,
                             "experts": swiglu(expert_dim, x.count)}
             if x.shared:
                 block["moe"]["shared"] = swiglu(expert_dim)
+            if x.scoring == "sigmoid":
+                # the correction bias stays float32 whatever the weights
+                block["moe"]["bias"] = 0.02 * jax.random.normal(
+                    next(lat), (x.n_experts,), jnp.float32)
         params[f"block{i}"] = block
     params["ln_f"] = norm()
     params["head"] = {"kernel": mat(e, vocab_size)}
@@ -514,6 +651,14 @@ def _serve_config(params, *, embed_dim=None, num_heads=None,
         if (l.ffn == "experts") != (l.experts is not None):
             raise ValueError("ffn='experts' comes with an Experts shape, "
                              "and nothing else does")
+    for i, l in enumerate(spec.layers):
+        if l.shares is not None and not (
+                0 <= l.shares < i and l.indexer is None
+                and l.latent is not None
+                and spec.layers[l.shares].indexer is not None):
+            raise ValueError(f"layer {i} shares the selection of layer "
+                             f"{l.shares}: a latent layer without an "
+                             f"indexer shares an earlier layer's")
     if spec.learned_pos and params["pos"].shape[0] < t_max:
         raise ValueError(
             f"cache t_max {t_max} exceeds the trained position table "
@@ -650,8 +795,9 @@ def rotary_inv_freq(rot: Rotary) -> np.ndarray:
 
 def _rope(x, pos, rot: Rotary):
     """Rotate x [..., H, D] at integer positions `pos` (broadcastable to
-    x's leading axes): rotate-half over the first `rot.dims` of each
-    head, in float32, the remaining dims passed through."""
+    x's leading axes): rotate-half (or, `rot.interleave`, adjacent
+    pairs) over the first `rot.dims` of each head, in float32, the
+    remaining dims passed through."""
     r, half = rot.dims, rot.dims // 2
     inv = jnp.asarray(rotary_inv_freq(rot), jnp.float32)
     ang = (jnp.broadcast_to(pos, x.shape[:-2]).astype(jnp.float32)[..., None]
@@ -659,9 +805,14 @@ def _rope(x, pos, rot: Rotary):
     cos = (jnp.cos(ang) * rot.attention_factor)[..., None, :]
     sin = (jnp.sin(ang) * rot.attention_factor)[..., None, :]
     xf = x[..., :r].astype(jnp.float32)
-    x1, x2 = xf[..., :half], xf[..., half:]
-    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                          axis=-1).astype(x.dtype)
+    if rot.interleave:
+        x1, x2 = xf[..., 0::2], xf[..., 1::2]
+        out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                        axis=-1).reshape(xf.shape).astype(x.dtype)
+    else:
+        x1, x2 = xf[..., :half], xf[..., half:]
+        out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                              axis=-1).astype(x.dtype)
     return jnp.concatenate([out, x[..., r:]], axis=-1) if r < x.shape[-1] \
         else out
 
@@ -714,16 +865,18 @@ def _project_qkv(spec: ModelSpec, l: LayerSpec, p, h, seq_shape: tuple,
 
 
 def _project_index(spec: ModelSpec, l: LayerSpec, p, h, seq_shape: tuple,
-                   pos):
+                   pos, c_q=None):
     """The indexer's projections of one block, from the same normed
-    state q/k/v read: index queries [.., J, DI] and the one index key
-    [.., 1, DI] (LayerNormed, statistics in float32), both rotated at
-    `pos`, and the heads' weights [.., J] in float32. One definition
-    for the decode step and the prefill chunk."""
+    state q/k/v read (the index queries from the query latent `c_q`
+    where the indexer says so): index queries [.., J, DI] and the one
+    index key [.., 1, DI] (LayerNormed, statistics in float32), both
+    rotated at `pos`, and the heads' weights [.., J] in float32. One
+    definition for the decode step and the prefill chunk."""
     x = l.indexer
     b = h.shape[0]
     a = _norm(spec, p["ln1"], h)
-    qi = (a @ p["idx"]["wq"].astype(a.dtype)).reshape(
+    qi = ((a if x.query == "hidden" else c_q)
+          @ p["idx"]["wq"].astype(a.dtype)).reshape(
         b, *seq_shape, x.heads, x.dim)
     ki = core.layer_norm(x.dim, eps=1e-6).apply(
         p["idx"]["k_norm"], {}, a @ p["idx"]["wk"].astype(a.dtype))[0]
@@ -732,6 +885,65 @@ def _project_index(spec: ModelSpec, l: LayerSpec, p, h, seq_shape: tuple,
         qi, ki = _rope(qi, pos, x.rotary), _rope(ki, pos, x.rotary)
     w = (a @ p["idx"]["ww"].astype(a.dtype)).astype(jnp.float32)
     return qi, ki, w.reshape(b, *seq_shape, x.heads)
+
+
+def _project_latent(spec: ModelSpec, l: LayerSpec, p, h, seq_shape: tuple,
+                    pos):
+    """A latent layer's down-projections, norms and rotary (scope
+    `mla_latent`), one definition for the decode step and the prefill
+    chunk: the query latent c_q [.., q_rank] = RMSNorm(a W_DQ), the
+    heads' query parts q_nope [.., H, nope] and q_pe [.., H, rope]
+    (rotated) from c_q W_UQ, and the row the cache keeps for this
+    position [.., 1, kv_rank + rope]: RMSNorm of the KV latent beside
+    the rotated key part that every head shares."""
+    m = l.latent
+    b = h.shape[0]
+    a = _norm(spec, p["ln1"], h)
+    c_q = _head_norm(a @ p["mla"]["wq_a"].astype(a.dtype),
+                     p["mla"]["q_a_norm"], spec.norm_eps)
+    q = (c_q @ p["mla"]["wq_b"].astype(a.dtype)).reshape(
+        b, *seq_shape, l.heads, m.nope + m.rope)
+    kv = a @ p["mla"]["wkv_a"].astype(a.dtype)
+    c_kv = _head_norm(kv[..., :m.kv_rank], p["mla"]["kv_a_norm"],
+                      spec.norm_eps)
+    k_pe = _rope(kv[..., m.kv_rank:].reshape(b, *seq_shape, 1, m.rope), pos,
+                 l.rotary)
+    row = jnp.concatenate(
+        [c_kv.reshape(b, *seq_shape, 1, m.kv_rank), k_pe], axis=-1)
+    return c_q, q[..., :m.nope], _rope(q[..., m.nope:], pos, l.rotary), row
+
+
+def _latent_attention(spec: ModelSpec, l: LayerSpec, p, h, seq_shape, pos,
+                      attend, taken):
+    """A latent layer's attention and residual in ABSORBED form: each
+    head's nope query is taken into the latent, q_lat = q_nope W_UK^T
+    [.., H, kv_rank], and beside q_pe it meets the cached rows as they
+    are, one key of kv_rank + rope values that every head shares;
+    the weighted sum of the rows' latent values leaves through W_UV and
+    W_O. `attend(q, row, index) -> (o, *caches, account)` is the cache
+    fold (it names dsa_index, dsa_select and attn_latent), `index` the
+    indexer's projections, or for a layer that shares another's
+    selection, that layer's account `taken`."""
+    m = l.latent
+    with jax.named_scope("mla_latent"):
+        c_q, q_nope, q_pe, row = _project_latent(spec, l, p, h, seq_shape,
+                                                 pos)
+    with jax.named_scope("attn_latent"):
+        w_kv = p["mla"]["wkv_b"].reshape(m.kv_rank, l.heads, m.nope + m.v)
+        q_lat = jnp.einsum("...hn,rhn->...hr", q_nope,
+                           w_kv[..., :m.nope].astype(q_nope.dtype),
+                           preferred_element_type=jnp.float32)
+        q = jnp.concatenate([q_lat.astype(q_pe.dtype), q_pe], axis=-1)
+    index = taken
+    if l.indexer is not None:
+        with jax.named_scope("dsa_index"):
+            index = _project_index(spec, l, p, h, seq_shape, pos, c_q)
+    o, *caches, account = attend(q, row, index)
+    with jax.named_scope("attn_latent"):
+        o = jnp.einsum("...hr,rhv->...hv", o[..., :m.kv_rank],
+                       w_kv[..., m.nope:].astype(o.dtype))
+        h = _attn_residual({"mha": p["mla"]}, h, o)
+    return h, caches, account
 
 
 def _attn_residual(p, h, o, gate=None):
@@ -766,16 +978,22 @@ def _ffn_residual(spec: ModelSpec, l: LayerSpec, p, h, live=None,
 
 
 def _layer_forward(cfg, l: LayerSpec, p, h, seq_shape, pos, attend,
-                   live=None):
+                   live=None, taken=None):
     """One block on h [B, E] (seq_shape (1,)) or [B, C, E] ((C,)):
     projection, the cache fold `attend` (closed over the layer's
     caches), the two residuals. `attend(q, k, v) -> (o, *caches)`; for a
     layer with an indexer `attend(q, k, v, index) -> (o, *caches,
     account)`, `index` its projections (`_project_index`) and `account`
     what the fold says of its selection, which joins the layer's
-    statistics. Returns (h, caches, stats)."""
+    statistics. A latent layer's fold is `attend(q, row, index)`
+    (`_latent_attention`), and a layer that shares another's selection
+    is handed that layer's statistics as `taken`. Returns (h, caches,
+    stats)."""
     spec = cfg.spec
-    if l.indexer is None:
+    if l.latent is not None:
+        h, caches, account = _latent_attention(spec, l, p, h, seq_shape, pos,
+                                               attend, taken)
+    elif l.indexer is None:
         account = None
         with jax.named_scope("attn_full" if l.window is None
                              else "attn_window"):
@@ -899,14 +1117,16 @@ def _token_forward(cfg: _ServeConfig, params, caches, tok, pos, fold,
     spec = cfg.spec
     h = _embed(spec, params, tok, lambda: params["pos"][pos])   # [B, E]
     rows = jnp.asarray(pos, jnp.int32).reshape(-1, 1)
-    new_caches, stats = [], []
+    new_caches, stats, by_layer = [], [], {}
     for i, l in enumerate(spec.layers):
         h, cache, st = _layer_forward(
             cfg, l, params[f"block{i}"], h, (1,), rows,
-            lambda *qkv, _i=i: fold(_i, *caches[_i], *qkv), live)
+            lambda *qkv, _i=i: fold(_i, *caches[_i], *qkv), live,
+            by_layer.get(l.shares))
         new_caches.append(cache)
         if st is not None:
             stats.append(st)
+            by_layer[i] = st
     logits = _final_logits(spec, params, h)
     return logits, tuple(new_caches), tuple(stats)
 
@@ -958,14 +1178,16 @@ def _chunk_forward(cfg: _ServeConfig, params, caches, tokens, start, p_end,
                lambda: lax.dynamic_slice_in_dim(params["pos"], start,
                                                 c, axis=0))
     rows = (start + jnp.arange(c, dtype=jnp.int32))[None, :]
-    new_caches, stats = [], []
+    new_caches, stats, by_layer = [], [], {}
     for i, l in enumerate(spec.layers):
         h, cache, st = _layer_forward(
             cfg, l, params[f"block{i}"], h, (c,), rows,
-            lambda *qkv, _i=i: fold(_i, *caches[_i], *qkv))
+            lambda *qkv, _i=i: fold(_i, *caches[_i], *qkv),
+            taken=by_layer.get(l.shares))
         new_caches.append(cache)
         if st is not None:
             stats.append(st)
+            by_layer[i] = st
     # logits of the LAST REAL position in this chunk (p_end is
     # traced -> dynamic gather); intermediate chunks' logits are
     # discarded by the caller, the final chunk's seed decode

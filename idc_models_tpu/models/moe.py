@@ -4,8 +4,9 @@ A deployment divides a layer's routed experts over the chips that share
 the layer (expert parallelism); this chip holds the contiguous range
 ``[first, first + count)`` of the ``n_experts`` the router scores. The
 layer routes over ALL experts at the published width — softmax over
-every router output in float32, the `top_k` largest, their weights
-renormalised and scaled — and computes only its own experts' terms for
+every router output in float32 (or a sigmoid of each, picked with a
+correction bias), the `top_k` largest, their weights renormalised and
+scaled — and computes only its own experts' terms for
 the tokens routed to them. What an absent expert would have added is
 left out; nothing stands in for the other chips or their exchange, and
 the partial sum is what goes on to the next layer (with every expert
@@ -36,6 +37,10 @@ class Experts(NamedTuple):
     count: int
     routed_scale: float = 1.0   # multiplies the renormalised weights
     shared: bool = True         # one always-on SwiGLU expert beside them
+    # "softmax" over the router's outputs, or "sigmoid" of each, picked
+    # by score plus a learned per-expert correction bias (DeepSeek-V3's
+    # `noaux_tc`, the float32 parameter `bias` beside `router`)
+    scoring: str = "softmax"
 
 
 def swiglu(p, x):
@@ -45,14 +50,21 @@ def swiglu(p, x):
     return (jax.nn.silu(g) * u) @ p["w_down"].astype(x.dtype)
 
 
-def route(x, w_router, e: Experts):
+def route(x, w_router, e: Experts, bias=None):
     """Router over all `n_experts`: x [N, E] -> (weights [N, k] float32,
     experts [N, k] int32). Scores are a float32 softmax over every
-    output; the k largest are renormalised to sum to 1 and scaled."""
+    output, or with `e.scoring` "sigmoid" a float32 sigmoid of each,
+    the k picked by score plus `bias` [n_experts]; the picks' scores
+    (unbiased) are renormalised to sum to 1 and scaled."""
     logits = jnp.dot(x.astype(jnp.float32), w_router.astype(jnp.float32),
                      preferred_element_type=jnp.float32)
-    scores = jax.nn.softmax(logits, axis=-1)
-    top, idx = jax.lax.top_k(scores, e.top_k)
+    if e.scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        idx = jax.lax.top_k(scores + bias.astype(jnp.float32), e.top_k)[1]
+        top = jnp.take_along_axis(scores, idx, axis=-1)
+    else:
+        scores = jax.nn.softmax(logits, axis=-1)
+        top, idx = jax.lax.top_k(scores, e.top_k)
     weights = e.routed_scale * top / jnp.sum(top, axis=-1, keepdims=True)
     return weights, idx.astype(jnp.int32)
 
@@ -89,7 +101,7 @@ def expert_ffn(p, x, e: Experts, live=None, *, interpret: bool = False):
     interpreter (`mesh.pallas_interpret`: off the TPU)."""
     n, k = x.shape[0], e.top_k
     with jax.named_scope("moe_router"):
-        weights, picks = route(x, p["router"], e)
+        weights, picks = route(x, p["router"], e, p.get("bias"))
         local = picks - e.first
         mine = (local >= 0) & (local < e.count)
         # absent experts sort behind every held one and join no group

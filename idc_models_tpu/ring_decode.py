@@ -1492,6 +1492,36 @@ def index_cache_shape(batch: int, t_max: int, dim: int) -> tuple:
     return (batch, dim, t_max)
 
 
+def latent_cache_shape(batch: int, t_max: int, width: int) -> tuple:
+    """The declared shape of a latent-attention layer's cache, ONE row a
+    position of `width` values (the normed KV latent beside the rotated
+    key part every head shares): `[batch, t_max, lanes]`, the positions
+    in the rows and a row in the lanes, `cache_shape`'s merged rows of
+    one head, the row zero-padded to whole tiles of 128 lanes (576 ->
+    640 at the published widths). A decode step appends a row and the
+    fold gathers whole rows by position, each read as key and as value
+    at once (one joined row, not two caches: a row of 2 KiB gathers in
+    the time of one of 1 KiB, PERF.md section 7); the chunk fold reads
+    blocks of rows. Rows of 576 lanes the compiler keeps with the
+    POSITIONS minor at every program's edge (padding nothing there) and
+    re-lays all of every layer's cache into rows and back around each
+    window, 3.5 GB of temporaries; rows of whole tiles are kept as
+    declared and the compiled window copies nothing
+    (tests/test_cache_layout.py). The padding lanes hold zeros, and
+    the folds pad queries and new rows to the same lanes."""
+    return (batch, t_max, -(-width // _LANES) * _LANES)
+
+
+def _to_lanes(q, new, lanes: int):
+    """A latent layer's queries [.., H, width] and new rows [.., 1, width]
+    zero-padded to the `lanes` of its cache's rows (`latent_cache_shape`):
+    zeros on both sides of every product change no score, and the values
+    come out as wide as a row, of which the caller keeps its own."""
+    pad = lambda x: jnp.pad(x, ((0, 0),) * (x.ndim - 1)
+                            + ((0, lanes - x.shape[-1]),))
+    return pad(q), tuple(pad(x) for x in new)
+
+
 def _take_index(ic, at, n: int, row=None):
     """`n` positions of an index cache from `at`: of every batch row, or
     (with `row`, traced) of that one alone, [1, DI, n]."""
@@ -1575,7 +1605,8 @@ def _bit_block(bits, at, n: int):
 
 def make_sparse_decode(mesh: Mesh, *, topk: int,
                        axis: str = meshlib.SEQ_AXIS,
-                       scale: float | None = None):
+                       scale: float | None = None, latent: bool = False,
+                       shared: bool = False):
     """Per-slot decode fold of a layer whose indexer picks the positions
     a query attends: ``fn(k_cache, v_cache, i_cache, q_t, k_t, v_t,
     index, pos, live) -> (out_t, k_cache, v_cache, i_cache, account)``.
@@ -1609,26 +1640,45 @@ def make_sparse_decode(mesh: Mesh, *, topk: int,
     every dead row), ``sel_share`` [B] float32 (selected over visible,
     0 for a dead row), ``sel_rows`` [B] int32 (1 for a live row) and
     ``fold_rows`` int32 (the rows steps 3 and 4 ran for: the trips
-    times the group). One device only."""
-    _one_device(mesh, axis)
+    times the group). One device only.
 
-    def fn(kc, vc, ic, q, kt, vt, index, pos, live):
-        qi, kit, w = index
-        b, t, d = kc.shape[0], kc.shape[1], q.shape[-1]
-        k, grp = min(topk, t), min(_FOLD_GROUP, b)
+    `latent`: a latent-attention layer, whose keys and values are ONE
+    cache of rows (`latent_cache_shape`), each gathered row its key and,
+    in its first lanes, its value: ``fn(l_cache, i_cache, q_t, row_t,
+    index, pos, live) -> (out_t, l_cache, i_cache, account)`` with q_t
+    [B, 1, H, width] (absorbed queries) and row_t [B, 1, 1, width];
+    out_t is as wide as a row, of which the caller keeps the value
+    lanes; the attention's scope is `attn_latent`. `shared` (with
+    `latent`): the layer attends what another layer selected for the
+    same query, and has no index keys: ``fn(l_cache, q_t, row_t, taken,
+    pos, live)``, `taken` that layer's account, whose ``selected`` it
+    takes (no scores, no top-k). Its account is the same, of the
+    positions it attended."""
+    _one_device(mesh, axis)
+    scope = "attn_latent" if latent else "attn_sparse"
+
+    def fold(kv, ic, q, new, index, pos, live):
+        if latent:
+            q, new = _to_lanes(q, new, kv[0].shape[-1])
+        b, t, d = kv[0].shape[0], kv[0].shape[1], q.shape[-1]
+        k = min(topk, t) if ic is not None else index["selected"].shape[1]
+        grp = min(_FOLD_GROUP, b)
         scale_ = scale if scale is not None else d ** -0.5
         pos = jnp.asarray(pos, jnp.int32)
         live = jnp.asarray(live, jnp.bool_)
         posc = jnp.clip(pos, 0, t - 1)
-        with jax.named_scope("dsa_index"):
-            ic = _append_index(ic, kit, posc, live)
-            score = _scores_to_frontier(
-                qi[:, 0], w[:, 0], ic,
-                lambda g: g[None, :] <= posc[:, None],
-                _decode_frontier(posc, live), _fold_block(t, _INDEX_BLOCK))
-        with jax.named_scope("attn_sparse"):
-            kc = _append_rows(kc, _rows(kt, kc), posc, live)
-            vc = _append_rows(vc, _rows(vt, vc), posc, live)
+        if ic is not None:
+            qi, kit, w = index
+            with jax.named_scope("dsa_index"):
+                ic = _append_index(ic, kit, posc, live)
+                score = _scores_to_frontier(
+                    qi[:, 0], w[:, 0], ic,
+                    lambda g: g[None, :] <= posc[:, None],
+                    _decode_frontier(posc, live),
+                    _fold_block(t, _INDEX_BLOCK))
+        with jax.named_scope(scope):
+            kv = tuple(_append_rows(c, _rows(x, c), posc, live)
+                       for c, x in zip(kv, new))
         # the live rows' ids first, in slot order; behind the last live
         # one a group is filled up with the index one past the batch,
         # which reads the last row and writes nothing
@@ -1641,16 +1691,20 @@ def make_sparse_decode(mesh: Mesh, *, topk: int,
             out, selected = carry
             ids = lax.dynamic_slice_in_dim(order, j * grp, grp)
             at = jnp.minimum(ids, b - 1)
-            with jax.named_scope("dsa_select"):
-                top, idx = lax.top_k(score[at], k)
-                valid = top > -jnp.inf
-            with jax.named_scope("attn_sparse"):
-                s = _scores(q[at, 0], kc[at[:, None], idx]) * scale_
+            if ic is not None:
+                with jax.named_scope("dsa_select"):
+                    top, idx = lax.top_k(score[at], k)
+                    valid = top > -jnp.inf
+            else:
+                taken = index["selected"][at]
+                valid, idx = taken >= 0, jnp.maximum(taken, 0)
+            with jax.named_scope(scope):
+                s = _scores(q[at, 0], kv[0][at[:, None], idx]) * scale_
                 s = jnp.where(valid[:, None, :], s, _MASKED)
                 p = jnp.where(
                     valid[:, None, :],
                     jnp.exp(s - jnp.max(s, axis=-1, keepdims=True)), 0.0)
-                o = (_weighted(p, vc[at[:, None], idx], d)
+                o = (_weighted(p, kv[-1][at[:, None], idx], d)
                      / jnp.maximum(jnp.sum(p, axis=-1), 1e-37)[..., None])
                 out = out.at[ids].set(o.astype(out.dtype), mode="drop")
             return out, selected.at[ids].set(
@@ -1668,14 +1722,22 @@ def make_sparse_decode(mesh: Mesh, *, topk: int,
             ).astype(jnp.float32),
             "sel_rows": live.astype(jnp.int32),
             "fold_rows": trips * grp}
-        return out[:, None], kc, vc, ic, account
+        return (out[:, None], *kv, *(() if ic is None else (ic,)), account)
 
-    return fn
+    if latent and shared:
+        return lambda lc, q, row, taken, pos, live: fold(
+            (lc,), None, q, (row,), taken, pos, live)
+    if latent:
+        return lambda lc, ic, q, row, index, pos, live: fold(
+            (lc,), ic, q, (row,), index, pos, live)
+    return lambda kc, vc, ic, q, kt, vt, index, pos, live: fold(
+        (kc, vc), ic, q, (kt, vt), index, pos, live)
 
 
 def make_sparse_chunk_decode(mesh: Mesh, *, topk: int,
                              axis: str = meshlib.SEQ_AXIS,
-                             scale: float | None = None):
+                             scale: float | None = None,
+                             latent: bool = False, shared: bool = False):
     """Prefill-chunk fold of ONE request into row `row` of a BATCH's
     caches, in place: ``fn(k_cache, v_cache, i_cache, q, k, v, index,
     start, row) -> (out, k_cache, v_cache, i_cache, account)`` with
@@ -1698,12 +1760,21 @@ def make_sparse_chunk_decode(mesh: Mesh, *, topk: int,
     [C, ceil(T / 32)] uint32 holds each query's choice, the kernel's
     packed bits (bit b of word m is position 32 m + b), which the
     attention unpacks block by block. Requires start + C <= T. One
-    device only."""
+    device only.
+
+    `latent` and `shared` as in `make_sparse_decode`: ONE cache of rows
+    read as keys and values (``fn(l_cache, i_cache, q, row, index,
+    start, row_id)``, scope `attn_latent`), and the bits another layer
+    selected taken from its account (``fn(l_cache, q, row, taken, start,
+    row_id)``), handed on as this layer's account."""
     _one_device(mesh, axis)
     interpret = meshlib.pallas_interpret(mesh)
+    scope = "attn_latent" if latent else "attn_sparse"
 
-    def fn(kc, vc, ic, q, kt, vt, index, start, row):
-        t, c, d = kc.shape[1], q.shape[1], q.shape[-1]
+    def fold(kv, ic, q, new, index, start, row):
+        if latent:
+            q, new = _to_lanes(q, new, kv[0].shape[-1])
+        t, c, d = kv[0].shape[1], q.shape[1], q.shape[-1]
         scale_ = scale if scale is not None else d ** -0.5
         start = jnp.asarray(start, jnp.int32)
         row = jnp.asarray(row, jnp.int32)
@@ -1715,29 +1786,39 @@ def make_sparse_chunk_decode(mesh: Mesh, *, topk: int,
 
         qpos = start + jnp.arange(c, dtype=jnp.int32)
         causal = lambda g: g[None, :] <= qpos[:, None]          # [C, blk]
-        qi, kit, w = index
-        blk = _fold_block(t, _INDEX_BLOCK)
-        with jax.named_scope("dsa_index"):
-            ic = lax.dynamic_update_slice(
-                ic, jnp.swapaxes(kit[:, :, 0], 1, 2).astype(ic.dtype),
-                (row, 0, start))
-            score = _scores_to_frontier(
-                qi, w, ic, lambda g: causal(g)[None], start + c, blk,
-                row=row)[0]                                      # [C, T]
-        with jax.named_scope("dsa_select"):
-            bits = topk_select.select_topk_bits(
-                score, min(topk, t), start + c, interpret=interpret)
+        if ic is not None:
+            qi, kit, w = index
+            blk = _fold_block(t, _INDEX_BLOCK)
+            with jax.named_scope("dsa_index"):
+                ic = lax.dynamic_update_slice(
+                    ic, jnp.swapaxes(kit[:, :, 0], 1, 2).astype(ic.dtype),
+                    (row, 0, start))
+                score = _scores_to_frontier(
+                    qi, w, ic, lambda g: causal(g)[None], start + c, blk,
+                    row=row)[0]                                  # [C, T]
+            with jax.named_scope("dsa_select"):
+                bits = topk_select.select_topk_bits(
+                    score, min(topk, t), start + c, interpret=interpret)
+        else:
+            bits = index["selected_bits"]
         see = lambda g: _bit_block(bits, g[0], g.shape[0])[None, None]
-        with jax.named_scope("attn_sparse"):
-            kc, vc = write(kc, kt), write(vc, vt)
+        with jax.named_scope(scope):
+            kv = tuple(write(cc, x) for cc, x in zip(kv, new))
             _, l, acc = _attend_to_frontier(
-                q, kc, vc, see, start + c, _fold_block(t, _CHUNK_BLOCK),
-                scale=scale_, row=row)
+                q, kv[0], kv[-1], see, start + c,
+                _fold_block(t, _CHUNK_BLOCK), scale=scale_, row=row)
             out = acc / jnp.maximum(l, 1e-37)[..., None]        # [1,H,C,D]
-        return (jnp.moveaxis(out, 1, 2).astype(q.dtype), kc, vc, ic,
-                {"selected_bits": bits})
+        return (jnp.moveaxis(out, 1, 2).astype(q.dtype), *kv,
+                *(() if ic is None else (ic,)), {"selected_bits": bits})
 
-    return fn
+    if latent and shared:
+        return lambda lc, q, row_t, taken, start, row: fold(
+            (lc,), None, q, (row_t,), taken, start, row)
+    if latent:
+        return lambda lc, ic, q, row_t, index, start, row: fold(
+            (lc,), ic, q, (row_t,), index, start, row)
+    return lambda kc, vc, ic, q, kt, vt, index, start, row: fold(
+        (kc, vc), ic, q, (kt, vt), index, start, row)
 
 
 def prefill(mesh: Mesh, k_prompt, v_prompt, t_max: int, *,

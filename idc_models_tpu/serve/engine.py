@@ -77,7 +77,7 @@ from idc_models_tpu.models.lm import (
 )
 from idc_models_tpu.ring_decode import (
     cache_shape, decode_rows_read, grow_cache, index_cache_shape,
-    make_batched_chunk_ring_decode,
+    latent_cache_shape, make_batched_chunk_ring_decode,
     make_batched_ring_decode,
     make_paged_batched_chunk_ring_decode, make_paged_batched_ring_decode,
     make_paged_chunk_ring_decode, make_sparse_chunk_decode,
@@ -400,6 +400,19 @@ def _verify_core(cfg, pick, pad_id, K, t_max, params, caches, logits,
             caches, logits, kd, pos, remaining)
 
 
+def _sparse_kw(spec, l) -> dict:
+    """The sparse folds' shape of a layer that attends a selection: the
+    `topk` of the indexer that selects for it, and for a latent layer
+    the latent form and its scale; `shared` where another layer
+    selects."""
+    kw = {"topk": (l.indexer or spec.layers[l.shares].indexer).topk}
+    if l.latent is not None:
+        kw.update(latent=True, scale=l.latent.scale)
+    if l.shares is not None:
+        kw["shared"] = True
+    return kw
+
+
 @functools.lru_cache(maxsize=16)
 def _engine_fns(cfg, pad_id: int, quant: bool = False,
                 draft_k: int | None = None) -> _EngineFns:
@@ -417,10 +430,10 @@ def _engine_fns(cfg, pad_id: int, quant: bool = False,
     wraps = [l.window is not None for l in spec.layers]
     folds = {w: make_batched_ring_decode(mesh, jit=False, quantized=quant,
                                          wrap=w) for w in set(wraps)}
-    # a layer with an indexer folds over three caches (K, V, index keys)
-    sparse_folds = {i: make_sparse_decode(mesh, topk=l.indexer.topk)
-                    for i, l in enumerate(spec.layers)
-                    if l.indexer is not None}
+    # a layer with an indexer folds over three caches (K, V, index keys),
+    # a latent one over its rows (and index keys, where it selects)
+    sparse_folds = {i: make_sparse_decode(mesh, **_sparse_kw(spec, l))
+                    for i, l in enumerate(spec.layers) if l.selects}
     pick = _make_pick(cfg)
     # the TRAILING-NONE-FREE spelling of the ring cache layout: jit
     # normalizes trailing Nones out of output PartitionSpecs, and the
@@ -461,9 +474,14 @@ def _engine_fns(cfg, pad_id: int, quant: bool = False,
                 np.zeros(index_cache_shape(n_slots, t_max, l.indexer.dim),
                          jnp.dtype(cfg.cache_dtype)), cache_sh)
 
-        # beside K and V, an indexer's layer caches one index key a
-        # position
-        return tuple((mk(i, l), mk(i, l))
+        def mk_latent(l):
+            return meshlib.put_with_sharding(
+                np.zeros(latent_cache_shape(n_slots, t_max, l.latent.row),
+                         jnp.dtype(cfg.cache_dtype)), cache_sh)
+
+        # beside K and V (a latent layer: its one row a position), an
+        # indexer's layer caches one index key a position
+        return tuple(((mk_latent(l),) if l.latent else (mk(i, l), mk(i, l)))
                      + ((mk_index(l),) if l.indexer else ())
                      for i, l in enumerate(spec.layers))
 
@@ -482,8 +500,9 @@ def _engine_fns(cfg, pad_id: int, quant: bool = False,
     def masked_step(params, caches, tok, pos, live, scales):
         def block_fold(i, *args):
             if i in sparse_folds:
-                *cache, q, k, v, index = args
-                return sparse_folds[i](*cache, q, k, v, index, pos, live)
+                # (caches..., q, k, v, index) or a latent layer's
+                # (caches..., q, row, index)
+                return sparse_folds[i](*args, pos, live)
             kc, vc, q, k, v = args
             extra = (scales[i] if quant else ())
             return folds[wraps[i]](kc, vc, q, k, v, pos, live, *extra)
@@ -582,12 +601,13 @@ def _engine_fns(cfg, pad_id: int, quant: bool = False,
         # out of `free_slots`, its device budget is 0, and no fold reads
         # a row beyond its slot's frontier), so no pending prefill owns
         # a cache row of its own and the insert moves no cache at all
-        if any(wraps) or quant or not all(l.indexer for l in spec.layers):
+        if any(wraps) or quant or not all(l.selects for l in spec.layers):
             raise ValueError(
                 "a spec with an indexer prefills into the slot's own "
-                "rows: every layer has one, over full-length float "
-                "caches, no window layer's ring and no int8 rows")
-        chunk_folds = [make_sparse_chunk_decode(mesh, topk=l.indexer.topk)
+                "rows: every layer attends a selection (its own or "
+                "another layer's), over full-length float caches, no "
+                "window layer's ring and no int8 rows")
+        chunk_folds = [make_sparse_chunk_decode(mesh, **_sparse_kw(spec, l))
                        for l in spec.layers]
 
         def chunk_body(params, caches, slot, tokens, start, p_end):
@@ -3129,14 +3149,18 @@ class SlotEngine:
         """HBM bytes of the cache rows of all slots, by the kind of
         layer that owns them: ``full`` (t_max rows a slot) and
         ``window`` (a ring of W rows a slot); and, for a model with
-        indexer layers alone, ``index``: the index keys cached beside
-        K/V."""
+        such layers alone, ``index``: the index keys cached beside K/V,
+        and ``latent``: the latent-attention layers' rows (one a
+        position, in place of K and V)."""
         out = {"full": 0, "window": 0}
-        for l, (kc, vc, *ic) in zip(self._cfg.spec.layers, self._caches):
-            out["full" if l.window is None else "window"] += (
-                kc.nbytes + vc.nbytes)
-            if ic:
-                out["index"] = out.get("index", 0) + ic[0].nbytes
+        for l, cache in zip(self._cfg.spec.layers, self._caches):
+            if l.latent is not None:
+                out["latent"] = out.get("latent", 0) + cache[0].nbytes
+            else:
+                out["full" if l.window is None else "window"] += (
+                    cache[0].nbytes + cache[1].nbytes)
+            if l.indexer is not None:
+                out["index"] = out.get("index", 0) + cache[-1].nbytes
         return out
 
     def slot_logits(self, slot: int) -> np.ndarray:

@@ -632,6 +632,12 @@ class ServingMetrics:
                 "serve_index_cache_bytes",
                 "HBM bytes of the index keys the indexer layers cache "
                 "beside K/V, over all slots").set(by_kind["index"])
+        if by_kind.get("latent"):
+            self._reg.gauge(
+                "serve_latent_cache_bytes",
+                "HBM bytes of the rows the latent-attention layers cache "
+                "(one a position, in place of K and V), over all "
+                "slots").set(by_kind["latent"])
         if by_kind.get("window"):
             for kind, nbytes in by_kind.items():
                 self._reg.gauge(
@@ -886,6 +892,8 @@ class ServingMetrics:
                                                  / self.dsa_rows)
         if self.kv_bytes_by_kind.get("index"):
             out["serve_index_cache_bytes"] = self.kv_bytes_by_kind["index"]
+        if self.kv_bytes_by_kind.get("latent"):
+            out["serve_latent_cache_bytes"] = self.kv_bytes_by_kind["latent"]
         if self.kv_bytes_by_kind.get("window"):
             out["serve_kv_bytes_full"] = self.kv_bytes_by_kind["full"]
             out["serve_kv_bytes_window"] = self.kv_bytes_by_kind["window"]

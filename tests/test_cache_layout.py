@@ -265,3 +265,70 @@ def test_sparse_window_reads_index_keys_and_selected_rows_alone(chip):
                    for _, t, *_ in _instructions(text)
                    if t.startswith(ix_type)}
         assert layouts == {"2,1,0"}, layouts
+
+
+def test_latent_window_keeps_its_rows_as_stored_and_copies_none(chip):
+    """The decode window and the in-place chunk of a spec of latent
+    attention at the published row (a latent of 512 beside 64 rotated
+    key values, 576 values padded to 640 lanes by
+    `ring_decode.latent_cache_shape`), one layer selecting and one
+    sharing its selection, compiled for a described v5e: no latent cache
+    is copied whole or kept in another layout, and the decode fold
+    gathers ONE row a selected position for a group of live rows (key
+    and value at once). Declared as rows of 576 lanes the compiler kept
+    the caches with the positions minor at the program's edges and
+    re-laid every one into rows and back around each window, 3.5 GB of
+    temporaries at the served size (PERF.md section 4)."""
+    from idc_models_tpu.models import lm
+
+    rep = NamedSharding(chip, P())
+    config = {
+        "num_hidden_layers": 2, "hidden_size": 256, "num_attention_heads": 8,
+        "q_lora_rank": 64, "kv_lora_rank": 512, "qk_nope_head_dim": 32,
+        "qk_rope_head_dim": 64, "qk_head_dim": 96, "v_head_dim": 32,
+        "index_n_heads": 4, "index_head_dim": 128, "index_topk": S_TOPK,
+        "indexer_types": ["full", "shared"],
+        "mlp_layer_types": ["dense", "dense"], "n_routed_experts": 8,
+        "num_experts_per_tok": 2, "n_shared_experts": 1,
+        "routed_scaling_factor": 2.5, "rms_norm_eps": 1e-5,
+        "rope_parameters": {"rope_theta": 8e6, "rope_type": "default"},
+        "rope_interleave": True, "indexer_rope_interleave": True}
+    spec = lm.glm_spec(config)
+    shapes = jax.eval_shape(lambda k: lm.init_params(spec, VOCAB, k,
+                                                     mlp_dim=512),
+                            jax.random.key(0))
+
+    def sds(shape, dtype, sh=rep):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+    cfg = _serve_config(shapes, spec=spec, t_max=S_T_MAX, mesh=chip,
+                        cache_dtype=jnp.bfloat16)
+    lat = sds(rd.latent_cache_shape(S_SLOTS, S_T_MAX, 576), jnp.bfloat16,
+              rd.cache_sharding(chip))
+    ix = sds(rd.index_cache_shape(S_SLOTS, S_T_MAX, 128), jnp.bfloat16,
+             rd.cache_sharding(chip))
+    assert lat.shape == (S_SLOTS, S_T_MAX, 640)
+    caches = ((lat, ix), (lat,))
+    params = jax.tree.map(lambda a: sds(a.shape, a.dtype), shapes)
+    i32 = sds((S_SLOTS,), jnp.int32)
+    efns = _engine_fns(cfg, 0)
+    win = efns.window.lower(
+        params, caches, sds((S_SLOTS, VOCAB), jnp.float32),
+        sds((S_SLOTS, 2), jnp.uint32), i32, i32, i32, (), (), i32,
+        WINDOW).compile()
+    chunk = efns.prefill_chunk.lower(
+        params, caches, sds((), jnp.int32), sds((1, CHUNK), jnp.int32),
+        sds((), jnp.int32), sds((), jnp.int32)).compile()
+    lat_type = f"bf16[{S_SLOTS},{S_T_MAX},640]"
+    for text in (win.as_text(), chunk.as_text()):
+        ins = list(_instructions(text))
+        assert not [l[:300] for _, t, op, _, l in ins
+                    if op == "copy" and t.startswith(lat_type)]
+        layouts = {t.split("{")[1].split(":")[0] for _, t, *_ in ins
+                   if t.startswith(lat_type)}
+        assert layouts == {"2,1,0"}, layouts
+    gathered = [t.split("{")[0] for _, t, op, _, _ in _instructions(win.as_text())
+                if op == "gather" and t.startswith(f"bf16[{S_GROUP},{S_TOPK},")]
+    assert gathered == [f"bf16[{S_GROUP},{S_TOPK},640]"] * 2, gathered
+    temp = prof.program_report(win, name="serve.window").temp_bytes
+    assert temp < 2 * math.prod(lat.shape) * 2 / 10, temp
