@@ -8,6 +8,7 @@ chip belongs to one process at a time):
     chiprun --chips 1 -- python experiments/dsa_probe.py pieces
     chiprun --chips 1 -- python experiments/dsa_probe.py rows
     chiprun --chips 1 -- python experiments/dsa_probe.py window [ROOT [GROUP]]
+    python experiments/dsa_probe.py decode_select
 
 `pieces` (PR 34): which top-k, which gather. `rows` (PR 35): how
 `lax.top_k` and the row gather scale at 4, 8 and 16 rows (this chose
@@ -19,7 +20,10 @@ with `ring_decode._FOLD_GROUP` set to GROUP where that is given.
 `select`, run the same way: the chunk fold's top-k selection alone,
 the counting passes in XLA that the fold ran before against the Pallas
 kernel that replaced them, at three frontiers and 8 to 128 query rows a
-grid cell (this chose `ops.topk_select.ROWS`).
+grid cell (this chose `ops.topk_select.ROWS`). `decode_select`: the
+decode fold's selection for a trip of 8 rows, `lax.top_k` against the
+kernel with its choice compacted to positions (this chose how the
+decode fold gets its positions).
 """
 
 from __future__ import annotations
@@ -210,6 +214,95 @@ def select(note):
         topk_select.ROWS, topk_select._SPAN = 64, 32768
 
 
+def positions_by_gather(words, k):
+    """`topk_select._positions` with XLA's gathers in place of its
+    one-hot products (the other side of `decode_select`)."""
+    pop = lax.population_count(words).astype(jnp.int32)
+    ends = jnp.cumsum(pop, axis=1)
+    j = jnp.arange(k, dtype=jnp.int32)
+    word = jax.vmap(lambda e: jnp.searchsorted(e, j, side="right"))(ends)
+    at = jnp.minimum(word, words.shape[1] - 1)
+    x = jnp.take_along_axis(words, at, axis=1)
+    rank = j - jnp.take_along_axis(ends - pop, at, axis=1)
+    bit = jnp.zeros_like(rank)
+    for half in (16, 8, 4, 2, 1):
+        below = lax.population_count(x & ((1 << half) - 1)).astype(jnp.int32)
+        up = rank >= below
+        rank = jnp.where(up, rank - below, rank)
+        x = jnp.where(up, x >> half, x)
+        bit = jnp.where(up, bit + half, bit)
+    return jnp.where(j < ends[:, -1:], 32 * word + bit, -1)
+
+
+def decode_select(note):
+    """The decode fold's selection for ONE trip of 8 live rows at the
+    served length, ms a trip: 64 trips in one `fori_loop`, each taking
+    8 of 16 rows of [16, 32,768] float32 scores (`score[at]`, as the
+    fold does; the rows end at random positions below the frontier, one
+    of them at it) and selecting 2,048 of each: `lax.top_k` (what the
+    fold ran) against the kernel with its choice compacted to positions
+    (`ops.topk_select.select_topk_positions`: its words compacted in
+    XLA by one-hot products), beside
+    the row gather alone, the kernel's bits alone, the compaction by
+    XLA's gathers instead of one-hot products, and the compaction alone
+    (from words computed once); `zeros`: scores of a relu times a head
+    weight of either sign, so that the tie passes run. The positions
+    must equal `lax.top_k`'s, sorted."""
+    from idc_models_tpu.ops import topk_select as ts
+
+    trips = 64
+
+    def looped(select):
+        def run(x, f):
+            def body(i, acc):
+                at = (i * 8 + jnp.arange(8)) % S
+                return acc + jnp.sum(select(x[at], f, at))
+            return lax.fori_loop(0, trips, body, jnp.int32(0))
+        return jax.jit(run)
+
+    variants = {name: looped(fn) for name, fn in {
+        "row gather alone": lambda s, f, at: (s > 0).astype(jnp.int32),
+        "lax.top_k": lambda s, f, at: lax.top_k(s, K)[1],
+        "kernel bits": lambda s, f, at: ts.select_topk_bits(s, K, f)
+        .astype(jnp.int32),
+        "kernel + positions": lambda s, f, at: ts.select_topk_positions(
+            s, K, f),
+        "kernel + positions by XLA gathers": lambda s, f, at:
+            positions_by_gather(ts._select_words(s, K, f, None, "probe")
+                                [:, :T // 32], K),
+    }.items()}
+    compact = looped(lambda words, f, at: ts._positions(words, K))
+    words_of = jax.jit(lambda s, f: ts._select_words(s, K, f, None, "probe"))
+    # frontiers 2,048 / 8,192 / 16,384 / 28,672 at the served length
+    for frontier, kind in ((T // 16, "normal"), (T // 4, "normal"),
+                           (T // 2, "normal"), (T * 7 // 8, "normal"),
+                           (T // 2, "zeros")):
+        key = jax.random.key(frontier)
+        x = jax.random.normal(key, (S, T), jnp.float32)
+        if kind == "zeros":
+            x = jnp.maximum(x, 0) * jnp.where(
+                jax.random.bernoulli(jax.random.key(1), 0.5, (S, T)), 1.0, -1.0)
+        ends = jax.random.randint(jax.random.key(2), (S,), frontier // 2,
+                                  frontier).at[3].set(frontier - 1)
+        x = jnp.where(jnp.arange(T)[None, :] <= ends[:, None], x, -jnp.inf)
+        f = jnp.int32(frontier)
+        at = jnp.arange(8)
+        got = np.asarray(jax.jit(lambda s, f: ts.select_topk_positions(
+            s, K, f))(x[at], f))
+        top, idx = lax.top_k(x[at], K)
+        want = np.where(np.asarray(top) > -np.inf, np.asarray(idx), T + 1)
+        want = np.sort(want, axis=1)
+        want = np.where(want > T, -1, want)
+        same = bool((got == want).all())
+        for name, fn in variants.items():
+            note(f"{name}, frontier {frontier} {kind}",
+                 timed(fn, x, f, n=5) / trips, frontier=frontier,
+                 kind=kind, equal=same)
+        note(f"positions alone, frontier {frontier} {kind}",
+             timed(compact, words_of(x, f), f, n=5) / trips,
+             frontier=frontier, kind=kind)
+
+
 def by_rows(note):
     """`lax.top_k` and the gather of the selected rows for the first 4,
     8 and 16 rows of the batch, the caches whole; and 16 x 2,048 rows of
@@ -287,7 +380,8 @@ def main():
     if section == "window":
         window(note, root, int(sys.argv[3]) if len(sys.argv) > 3 else None)
     else:
-        {"pieces": pieces, "rows": by_rows, "select": select}[section](note)
+        {"pieces": pieces, "rows": by_rows, "select": select,
+         "decode_select": decode_select}[section](note)
 
 
 if __name__ == "__main__":

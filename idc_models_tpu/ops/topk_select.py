@@ -1,5 +1,6 @@
 """Pallas TPU kernel: each row's k best positions of a score buffer, as
-packed bits, every counting pass over the row's keys in VMEM.
+packed bits or as the positions themselves, every counting pass over
+the row's keys in VMEM.
 
 A prefill chunk of a layer with an indexer (`ring_decode.
 make_sparse_chunk_decode`) scores each of its C queries against every
@@ -26,6 +27,18 @@ choice, in bfloat16, times a constant [4,096, 256] matrix of powers of
 two on the matrix unit gives each of its 128 words in two exact halves
 of 16 bits. On a v5e the probe (`experiments/dsa_probe.py select`)
 chose 64 rows a cell and 32k keys a trip (`PERF.md` section 6).
+
+A decode step (`ring_decode.make_sparse_decode`) selects for a trip of
+8 live rows and gathers K/V rows by position, so it needs the positions,
+not a mask: `select_topk_positions` runs the same kernel (one cell of 8
+rows, traced as `decode_topk` beside the chunk's `chunk_topk`) and
+compacts its [8, T / 32] words into [8, k] ascending positions in XLA
+(`_positions`): which word holds a row's j-th position is a count of
+word ends at or below j, taken on the matrix unit; the word and the
+count before it are picked by a one-hot product, and the bit by five
+halvings: no sort, no XLA gather and no [8, T] mask. On a v5e the trip
+costs 25-38 us at frontiers of 2k-28k, the compaction 13 of them, where
+`lax.top_k` sorted all 32,768 positions in 200 (`PERF.md` section 6).
 
 Testing contract (`ops/fused_conv.py`'s): `interpret=None` runs the
 same kernel body under the Pallas interpreter off the TPU and through
@@ -194,15 +207,9 @@ def _kernel(front_ref, score_hbm, out_ref, keys_ref, pack_ref, sems, *, k,
     lax.fori_loop(0, groups, group, 0)
 
 
-def select_topk_bits(score, k: int, frontier, *, interpret=None):
-    """[N, ceil(T / 32)] uint32, bit b of word m being position 32 m + b:
-    per row of score [N, T] float32 (-inf where a position is not
-    visible, and everywhere at or beyond `frontier`, traced) the k best
-    visible positions, all of them where there are no more than k;
-    equal scores go to the lower position, as `lax.top_k` breaks them.
-    Exact: integer comparisons of the scores' own bits. `ROWS` query
-    rows a grid cell, fewer where N is smaller; `interpret=None` follows
-    `mesh.pallas_interpret`."""
+def _select_words(score, k: int, frontier, interpret, name: str):
+    """The kernel's packed choice for score [N, T], [N_pad, T_pad / 32]
+    uint32 (rows and positions padded to whole cells and groups)."""
     if interpret is None:
         interpret = meshlib.pallas_interpret()
     n, t = score.shape
@@ -216,7 +223,7 @@ def select_topk_bits(score, k: int, frontier, *, interpret=None):
                                slices=max(1, _SPAN // (rows * _LANES)),
                                pos_bits=max(1, (t - 1).bit_length()))
     vmem = rows * t_pad * 4 + _GROUP * 2 * _LANES * 2 + rows * t_pad // 4
-    words = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
@@ -231,6 +238,101 @@ def select_topk_bits(score, k: int, frontier, *, interpret=None):
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=vmem + (4 << 20)),
         interpret=interpret,
-        name="chunk_topk",
+        name=name,
     )(front, score)
+
+
+def select_topk_bits(score, k: int, frontier, *, interpret=None):
+    """[N, ceil(T / 32)] uint32, bit b of word m being position 32 m + b:
+    per row of score [N, T] float32 (-inf where a position is not
+    visible, and everywhere at or beyond `frontier`, traced) the k best
+    visible positions, all of them where there are no more than k;
+    equal scores go to the lower position, as `lax.top_k` breaks them.
+    Exact: integer comparisons of the scores' own bits. `ROWS` query
+    rows a grid cell, fewer where N is smaller; `interpret=None` follows
+    `mesh.pallas_interpret`."""
+    n, t = score.shape
+    words = _select_words(score, k, frontier, interpret, "chunk_topk")
     return words[:n, :-(-t // 32)]
+
+
+@functools.partial(jax.jit, static_argnames=("k", "interpret"))
+def select_topk_positions(score, k: int, frontier, *, interpret=None):
+    """[N, k] int32: the positions `select_topk_bits` chooses (the same
+    set, exactly) in ascending order, -1 after the last where a row sees
+    fewer than k. The kernel is traced as `decode_topk`. Jitted, so that
+    the calls of a program's layers share ONE lowering of the kernel:
+    lowered once a call, a decode window of eight selecting layers took
+    5 s longer to trace and lower on the host (`PERF.md` section 6)."""
+    n = score.shape[0]
+    return _positions(_select_words(score, k, frontier, interpret,
+                                    "decode_topk")[:n], k)
+
+
+# outputs a block of `_positions`' word count: the thermometer's width
+_OUT_BLOCK = 64
+
+
+def _positions(words, k: int):
+    """[N, W] uint32 packed choice, W a multiple of 128 -> [N, k] int32:
+    each row's chosen positions ascending, -1 beyond its count. Exact
+    integer arithmetic throughout: every product takes 0/1 and integer
+    operands under 2^24 and sums them in float32 to values under 2^24.
+    Running sums are products too (XLA's own takes 13 us a trip for
+    1,024 words on a v5e, `PERF.md` section 6)."""
+    n, w = words.shape
+    hi = dict(precision=lax.Precision.HIGHEST,
+              preferred_element_type=jnp.float32)
+    lanes = jnp.arange(_LANES)
+    pop = lax.population_count(words).astype(jnp.int32)
+    # positions chosen up to each word's end: within a tile of 128 words
+    # by a triangle of ones, across tiles by the tiles' running sum
+    inside = jnp.einsum(
+        "ngl,lm->ngm", pop.reshape(n, -1, _LANES).astype(jnp.bfloat16),
+        (lanes[:, None] <= lanes).astype(jnp.bfloat16),
+        preferred_element_type=jnp.float32)
+    tiles = inside[..., -1]
+    ends = (inside + (jnp.cumsum(tiles, axis=1) - tiles)[..., None]
+            ).reshape(n, w).astype(jnp.int32)
+    j = jnp.arange(k, dtype=jnp.int32)
+    # the j-th position lies in word #{m : ends[m] <= j}; for j = 64 a + b
+    # that is the words ending in an earlier block of 64 outputs plus
+    # those ending in block a at or below b: a one-hot of the block times
+    # a thermometer of the rest, counted on the matrix unit
+    blocks = -(-k // _OUT_BLOCK)
+    onehot = ends[..., None] // _OUT_BLOCK == jnp.arange(blocks)
+    therm = ends[..., None] % _OUT_BLOCK <= jnp.arange(_OUT_BLOCK)
+    inside = jnp.einsum("nma,nmb->nab", onehot.astype(jnp.bfloat16),
+                        therm.astype(jnp.bfloat16),
+                        preferred_element_type=jnp.float32)
+    full = inside[..., -1]
+    word = (jnp.cumsum(full, axis=1) - full)[..., None] + inside
+    word = word.reshape(n, -1)[:, :k].astype(jnp.int32)
+    # that word and the positions chosen before it, as two planes of 24
+    # bits (a half of the word, a byte of the count), picked by a
+    # one-hot of its column of 32 words, then of its place there
+    cols = w // 32
+    before = (ends - pop).astype(jnp.uint32)
+    planes = jnp.stack([(words & 0xFFFF) | ((before & 0xFF) << 16),
+                        (words >> 16) | ((before >> 8) << 16)], axis=1)
+    planes = planes.reshape(n, 2, cols, 32).transpose(0, 2, 1, 3)
+    picked = jnp.einsum(
+        "njc,ncx->njx",
+        (word[..., None] // 32 == jnp.arange(cols)).astype(jnp.float32),
+        planes.reshape(n, cols, 64).astype(jnp.float32), **hi)
+    place = word[..., None] % 32 == jnp.arange(32)
+    low, high = (jnp.sum(jnp.where(place, picked[..., 32 * i:32 * i + 32],
+                                   0.0), axis=-1).astype(jnp.int32)
+                 for i in range(2))
+    # the bit: the (j - before)-th set bit of the word, by five halvings
+    x = lax.bitcast_convert_type((low & 0xFFFF) | (high << 16), jnp.uint32)
+    rank = j - ((low >> 16) | ((high >> 16) << 8))
+    bit = jnp.zeros_like(rank)
+    for half in (16, 8, 4, 2, 1):
+        below = lax.population_count(x & ((1 << half) - 1)).astype(
+            jnp.int32)
+        up = rank >= below
+        rank = jnp.where(up, rank - below, rank)
+        x = jnp.where(up, x >> half, x)
+        bit = jnp.where(up, bit + half, bit)
+    return jnp.where(j < ends[:, -1:], 32 * word + bit, -1)

@@ -1463,10 +1463,13 @@ def make_chunk_ring_decode(mesh: Mesh, *, axis: str = meshlib.SEQ_AXIS,
 _INDEX_BLOCK = 2048
 
 # live rows the sparse decode fold selects, gathers and attends for in
-# one trip. On a v5e `lax.top_k` over 32k positions costs the same for
-# 4, 8 and 16 rows and the gather of 4 rows what 8 cost, so trips of 4
-# made a full batch a quarter slower than one pass over all its rows;
-# two trips of 8 cost what that pass did (PERF.md section 6, PR 35)
+# one trip: one 8-row cell of the selection kernel. Chosen while the fold
+# sorted: on a v5e `lax.top_k` over 32k positions cost the same for 4, 8
+# and 16 rows and the gather of 4 rows what 8 cost, so trips of 4 made a
+# full batch a quarter slower than one pass over all its rows. The
+# kernel and its positions take 25-38 us a trip of 8 at frontiers of 2k
+# to 28k, the sort took 200 (PERF.md section 6); whether a smaller
+# group now pays is for a probe of its own
 _FOLD_GROUP = 8
 
 
@@ -1622,25 +1625,28 @@ def make_sparse_decode(mesh: Mesh, *, topk: int,
     2. score every visible position ``sum_j w_j relu(qi_j . ki[s])``,
        reading the INDEX cache in blocks up to the furthest live
        position (named scope `dsa_index`);
-    3. take the `topk` best (`dsa_select`; all visible ones while there
-       are no more than that);
+    3. take the `topk` best (`dsa_select`: one cell of the Pallas
+       kernel of `ops.topk_select`, counting passes up to the trip's
+       own frontier, and its choice compacted to positions, no sort;
+       all visible ones while there are no more than `topk`);
     4. gather those rows of K and V, and attend over them alone
        (`attn_sparse`).
 
-    Steps 3 and 4 cost by the row (a sort of the whole length, 2 x
+    Steps 3 and 4 cost by the row (passes over the whole length, 2 x
     `topk` gathered rows), so they run for the LIVE rows alone: their
     ids in slot order, `_FOLD_GROUP` at a time, one trip of one loop
-    body a group (none when no row is live). A dead row is never sorted
-    for, gathered for or attended for: its `out_t` is zero.
+    body a group (none when no row is live). A dead row is never
+    selected for, gathered for or attended for: its `out_t` is zero.
 
     K and V are read at the selected rows only: of a 32k-token slot
     whose layer caches 2 KiB a position, 4 MiB of index keys and 4 MiB
     of selected rows instead of 64 MiB. `account` holds ``selected``
-    [B, topk] int32 (the positions, -1 where a row sees fewer and in
-    every dead row), ``sel_share`` [B] float32 (selected over visible,
-    0 for a dead row), ``sel_rows`` [B] int32 (1 for a live row) and
-    ``fold_rows`` int32 (the rows steps 3 and 4 ran for: the trips
-    times the group). One device only.
+    [B, topk] int32 (the positions in ascending order, -1 after the
+    last where a row sees fewer and in every dead row), ``sel_share``
+    [B] float32 (selected over visible, 0 for a dead row), ``sel_rows``
+    [B] int32 (1 for a live row) and ``fold_rows`` int32 (the rows
+    steps 3 and 4 ran for: the trips times the group). One device
+    only.
 
     `latent`: a latent-attention layer, whose keys and values are ONE
     cache of rows (`latent_cache_shape`), each gathered row its key and,
@@ -1655,6 +1661,7 @@ def make_sparse_decode(mesh: Mesh, *, topk: int,
     takes (no scores, no top-k). Its account is the same, of the
     positions it attended."""
     _one_device(mesh, axis)
+    interpret = meshlib.pallas_interpret(mesh)
     scope = "attn_latent" if latent else "attn_sparse"
 
     def fold(kv, ic, q, new, index, pos, live):
@@ -1692,12 +1699,22 @@ def make_sparse_decode(mesh: Mesh, *, topk: int,
             ids = lax.dynamic_slice_in_dim(order, j * grp, grp)
             at = jnp.minimum(ids, b - 1)
             if ic is not None:
+                # the filler rows see nothing; the trip's frontier is
+                # one past its furthest live position
+                mine = ids < b
                 with jax.named_scope("dsa_select"):
-                    top, idx = lax.top_k(score[at], k)
-                    valid = top > -jnp.inf
+                    taken = topk_select.select_topk_positions(
+                        jnp.where(mine[:, None], score[at], -jnp.inf), k,
+                        jnp.max(jnp.where(mine, posc[at], -1)) + 1,
+                        interpret=interpret)
             else:
                 taken = index["selected"][at]
-                valid, idx = taken >= 0, jnp.maximum(taken, 0)
+            # an unused entry (-1: a filler row's, or past a short row's
+            # last) gathers a row of its own, masked below: a gather that
+            # reads one row over and over costs a quarter more on a v5e
+            # (PERF.md section 6)
+            valid = taken >= 0
+            idx = jnp.where(valid, taken, jnp.arange(k, dtype=jnp.int32))
             with jax.named_scope(scope):
                 s = _scores(q[at, 0], kv[0][at[:, None], idx]) * scale_
                 s = jnp.where(valid[:, None, :], s, _MASKED)
@@ -1707,8 +1724,7 @@ def make_sparse_decode(mesh: Mesh, *, topk: int,
                 o = (_weighted(p, kv[-1][at[:, None], idx], d)
                      / jnp.maximum(jnp.sum(p, axis=-1), 1e-37)[..., None])
                 out = out.at[ids].set(o.astype(out.dtype), mode="drop")
-            return out, selected.at[ids].set(
-                jnp.where(valid, idx, -1).astype(jnp.int32), mode="drop")
+            return out, selected.at[ids].set(taken, mode="drop")
 
         trips = (n + grp - 1) // grp
         out, selected = lax.fori_loop(

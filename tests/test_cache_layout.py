@@ -182,10 +182,11 @@ def test_sparse_window_reads_index_keys_and_selected_rows_alone(chip):
     it is stored, positions in the lanes, and no cache is copied whole,
     at the program's edges or inside it (stored `[S, T, 64]` the
     compiler re-laid every index cache twice a window and once a token
-    step: PERF.md section 6, PR 34). The sort and the gather are a
-    GROUP of rows tall, not the batch: a fold that sorts and gathers for
+    step: PERF.md section 6). The selection and the gather are a GROUP
+    of rows tall, not the batch: a fold that selects and gathers for
     every slot again, live or not, fails here without a chip (PERF.md
-    section 6, PR 35)."""
+    section 6); and the selection is the Pallas kernel: nothing under
+    `dsa_select` sorts."""
     from idc_models_tpu.models import lm
 
     rep = NamedSharding(chip, P())
@@ -233,11 +234,15 @@ def test_sparse_window_reads_index_keys_and_selected_rows_alone(chip):
             r"(^|/)(attn_sparse/scatter|while/body/attn_sparse/gather)$",
             name.group(1)), line[:300]
     assert {op for op, *_ in readers} >= {"gather", "scatter"}
-    # two gathers (K, V) and one sort a layer, each of one group's rows
+    # two gathers (K, V) a layer, each of one group's rows, and one
+    # selection kernel (`decode_topk`) a layer over one group's scores
     gathered = [t.split("{")[0] for op, t, _ in readers if op == "gather"]
     assert gathered == [f"bf16[{S_GROUP},{S_TOPK},{S_KV},{S_D}]"] * 4
-    sorts = re.findall(r"= \((f32\[[\d,]+\])\S* .*? sort\(.*dsa_select", win)
-    assert sorts == [f"f32[{S_GROUP},{S_T_MAX}]"] * 2, sorts
+    assert not re.findall(r" sort\(.*dsa_select", win)
+    selects = [[typed[o].split("{")[0] for o in operands]
+               for _, t, op, operands, l in ins if op == "custom-call"
+               and "decode_topk" in l and "dsa_select" in l]
+    assert selects == [["s32[1]", f"f32[{S_GROUP},{S_T_MAX}]"]] * 2, selects
     # whole-cache copies: none, of K/V or of the index keys, in either
     # program; and the index cache keeps its stored layout throughout
     chunk = efns.prefill_chunk.lower(

@@ -255,6 +255,46 @@ def test_a_shared_layer_attends_its_sources_selection_alone(model):
         np.testing.assert_allclose(out[b, 0, :, :ROW], want, atol=1e-5)
 
 
+@pytest.mark.parametrize("pos", [(3, 40), (TOPK - 1, 100), (100, T_MAX - 1)])
+def test_a_sharing_decode_fold_attends_the_set_its_source_selected(pos):
+    """A selecting latent layer's decode fold hands its account to a
+    sharing layer's: the positions it took (the selection kernel's,
+    ascending, -1 after the last where a row sees fewer than `topk`)
+    are the set `lax.top_k` takes of the same index scores, and the
+    sharing layer attends exactly that set."""
+    mesh = meshlib.seq_mesh(1)
+    b, h, di, j = 2, 4, 8, 2
+    ks = iter(jax.random.split(jax.random.key(9), 8))
+    norm = lambda *shape: jax.random.normal(next(ks), shape, jnp.float32)
+    lc = norm(b, T_MAX, LANES).at[..., ROW:].set(0)
+    ic = norm(b, di, T_MAX)
+    q, row = norm(b, 1, h, ROW), norm(b, 1, 1, ROW)
+    qi, kit, w = norm(b, 1, j, di), norm(b, 1, 1, di), norm(b, 1, j)
+    pos, live = jnp.asarray(pos, jnp.int32), jnp.ones(b, bool)
+    source = rd.make_sparse_decode(mesh, topk=TOPK, latent=True, scale=0.5)
+    sharer = rd.make_sparse_decode(mesh, topk=TOPK, latent=True,
+                                   shared=True, scale=0.5)
+    _, lc, ic, taken = source(lc, ic, q, row, (qi, kit, w), pos, live)
+    out, lc, account = sharer(lc, q, row, taken, pos, live)
+    sel = np.asarray(taken["selected"])
+    np.testing.assert_array_equal(account["selected"], sel)
+    score = jnp.sum(jnp.maximum(jnp.einsum(
+        "bjd,bdt->bjt", qi[:, 0], ic, precision="highest"), 0.0)
+        * w[:, 0, :, None], axis=1)
+    score = jnp.where(jnp.arange(T_MAX) <= pos[:, None], score, -jnp.inf)
+    top, idx = jax.lax.top_k(score, TOPK)
+    for r in range(b):
+        mine = sel[r][sel[r] >= 0]
+        assert (sel[r][len(mine):] == -1).all()
+        np.testing.assert_array_equal(mine, np.sort(
+            np.asarray(idx[r])[np.asarray(top[r]) > -np.inf]))
+        keys = np.asarray(lc[r, mine, :ROW])
+        s = np.einsum("hd,kd->hk", np.asarray(q[r, 0]), keys) * 0.5
+        p = np.exp(s - s.max(-1, keepdims=True))
+        want = (p / p.sum(-1, keepdims=True)) @ keys
+        np.testing.assert_allclose(out[r, 0, :, :ROW], want, atol=1e-5)
+
+
 def test_the_bias_changes_the_picks_and_not_the_weights_formula():
     """Sigmoid routing picks by score plus bias and weighs by the picks'
     own scores, renormalised and scaled; a zero bias picks the unbiased

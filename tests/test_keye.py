@@ -169,7 +169,8 @@ def test_the_selection_handed_out_is_the_references(model):
 
 
 # (rows n, positions t, k, frontier, scores): every case also holds
-# rows that see fewer positions than k, and one that sees none
+# rows that see fewer positions than k, and one that sees none; the
+# "decode" cases are a trip of the decode fold, 8 rows
 SELECT_CASES = {
     "ties-frontier-inside": (12, 64, 10, 57, "ties"),
     "half-the-rows-see-nothing": (16, 64, 10, 64, "blind"),
@@ -177,22 +178,32 @@ SELECT_CASES = {
     "frontier-below-k": (12, 256, 200, 150, "normal"),
     "tiles-and-groups-ragged": (130, 9000, 300, 8999, "normal"),
     "ties-at-the-kth": (24, 5000, 64, 4321, "ties"),
+    "decode-ties-at-the-kth": (8, 6000, 96, 5003, "ties"),
+    "decode-zeros-of-both-signs": (8, 3000, 80, 2999, "zeros"),
+    "decode-frontier-ragged": (8, 12288, 512, 5000, "normal"),
+    "decode-k-is-t": (8, 200, 200, 200, "ties"),
 }
 
 
 @pytest.mark.parametrize("case", SELECT_CASES, ids=list(SELECT_CASES))
 def test_select_topk_is_lax_top_k_with_ties_to_the_lower_position(case):
-    """The chunk fold's selection (`ops.topk_select`, the Pallas kernel
-    under the interpreter) against `lax.top_k`: the k best visible
-    positions of each row, ties to the lower position, all visible ones
-    where there are no more than k, packed as `keye_ref.pack_bits`
-    packs them."""
+    """The folds' selection (`ops.topk_select`, the Pallas kernel under
+    the interpreter) against `lax.top_k`: the k best visible positions
+    of each row, ties to the lower position, all visible ones where
+    there are no more than k, packed as `keye_ref.pack_bits` packs them
+    (the chunk fold's bits) and as the positions in ascending order, -1
+    after the last (the decode fold's). Scores of a relu times a
+    negative head weight are -0.0, below +0.0 on both sides."""
     from idc_models_tpu.ops import topk_select
 
     n, t, k, frontier, kind = SELECT_CASES[case]
     rng = np.random.default_rng(t + k)
     score = (rng.integers(-3, 4, (n, t)) if kind == "ties"
              else rng.normal(size=(n, t))).astype(np.float32)
+    if kind == "zeros":
+        score = (np.maximum(score, 0) * rng.choice([-1.0, 1.0], (n, t))
+                 ).astype(np.float32)
+        assert (np.signbit(score) & (score == 0)).any()
     ends = rng.integers(0, frontier + 1, n)
     ends[:2] = frontier, 0
     if kind == "blind":
@@ -209,6 +220,13 @@ def test_select_topk_is_lax_top_k_with_ties_to_the_lower_position(case):
     np.testing.assert_array_equal(got, keye_ref.pack_bits(want))
     chosen = np.asarray(keye_ref.unpack_bits(got, t))
     assert (chosen.sum(1) == np.minimum(visible.sum(1), k)).all()
+    at = np.asarray(jax.jit(lambda s: topk_select.select_topk_positions(
+        s, k, frontier))(jnp.asarray(score)))
+    assert at.shape == (n, k) and at.dtype == np.int32
+    for r in range(n):
+        mine = np.flatnonzero(want[r])
+        np.testing.assert_array_equal(at[r, :len(mine)], mine)
+        assert (at[r, len(mine):] == -1).all()
 
 
 @pytest.mark.parametrize("t,at,n", [(256, 128, 64), (100, 0, 100),
@@ -224,33 +242,42 @@ def test_a_block_of_the_packed_bits_reads_the_mask(t, at, n):
 
 
 GRP = rd._FOLD_GROUP
+# (live slots, index keys, queries and head weights of a few integers,
+# so that most rows' k-th best score is tied and the tie rule decides)
 LIVE_MASKS = {
-    "none": [],
-    "first slot": [0],
-    "last slot": [2 * GRP],
-    "scattered, fewer than a group": list(range(1, 2 * GRP, 3))[:GRP - 1],
-    "a whole group": list(range(0, 2 * GRP, 2)),
-    "a group and one": list(range(GRP)) + [2 * GRP],
-    "all": list(range(2 * GRP + 1)),
+    "none": ([], False),
+    "first slot": ([0], False),
+    "last slot": ([2 * GRP], False),
+    "scattered, fewer than a group": (list(range(1, 2 * GRP, 3))[:GRP - 1],
+                                      False),
+    "a whole group": (list(range(0, 2 * GRP, 2)), False),
+    "a group and one": (list(range(GRP)) + [2 * GRP], False),
+    "all": (list(range(2 * GRP + 1)), False),
+    "all, the scores tied": (list(range(2 * GRP + 1)), True),
 }
 
 
-@pytest.mark.parametrize("rows", LIVE_MASKS.values(), ids=LIVE_MASKS.keys())
-def test_decode_fold_selects_gathers_and_attends_for_live_rows_alone(rows):
+@pytest.mark.parametrize("rows,tied", LIVE_MASKS.values(),
+                         ids=LIVE_MASKS.keys())
+def test_decode_fold_selects_gathers_and_attends_for_live_rows_alone(rows,
+                                                                      tied):
     """`make_sparse_decode` over a batch of two groups and one slot, the
     slots at different lengths (below `topk` and far above it), against
     the fold spelled over ALL rows (append, score, `lax.top_k`, gather,
-    softmax): the live rows select and attend as there, the dead ones
-    report -1, put out zeros and leave their rows of all three caches as
-    they were, and the account says how many rows were folded."""
+    softmax): the live rows select the same set and attend as there,
+    the dead ones report -1, put out zeros and leave their rows of all
+    three caches as they were, and the account says how many rows were
+    folded. A live row's positions come in ascending order."""
     from idc_models_tpu import mesh as meshlib
 
     b, t, h, g, d, j, di, k = 2 * GRP + 1, 96, 4, 2, 128, 3, 8, 12
     r = iter(jax.random.split(jax.random.key(5), 9))
     norm = lambda *shape: jax.random.normal(next(r), shape, jnp.float32)
-    kc, vc, ic = norm(b, t, g, d), norm(b, t, g, d), norm(b, di, t)
+    few = lambda *shape: jnp.round(norm(*shape))
+    index = few if tied else norm
+    kc, vc, ic = norm(b, t, g, d), norm(b, t, g, d), index(b, di, t)
     q, kt, vt = norm(b, 1, h, d), norm(b, 1, g, d), norm(b, 1, g, d)
-    qi, kit, w = norm(b, 1, j, di), norm(b, 1, 1, di), norm(b, 1, j)
+    qi, kit, w = index(b, 1, j, di), index(b, 1, 1, di), index(b, 1, j)
     pos = jnp.asarray((np.arange(b) * 37 + 5) % (t - 1), jnp.int32)
     assert (np.asarray(pos) < k).any() and (np.asarray(pos) > 4 * k).any()
     live = np.zeros(b, bool)
@@ -288,8 +315,14 @@ def test_decode_fold_selects_gathers_and_attends_for_live_rows_alone(rows):
     assert got.shape == (b, k) and (got[dead] == -1).all()
     for row in np.flatnonzero(live):
         assert (got[row] >= 0).sum() == min(at[row] + 1, k)
-        assert set(got[row][got[row] >= 0]) == set(
-            np.asarray(idx)[row][valid[row]])
+        np.testing.assert_array_equal(
+            got[row][got[row] >= 0], np.sort(np.asarray(idx)[row][valid[row]]))
+    if tied:
+        # rows with more positions at or above their k-th best score than
+        # room: the tie rule decides which of them are taken
+        kth = np.take_along_axis(np.asarray(score), np.asarray(idx), 1)[:, -1]
+        decides = (np.asarray(score) >= kth[:, None]).sum(1) > k
+        assert (decides & (at >= k)).sum() >= 4
     np.testing.assert_allclose(
         account["sel_share"],
         np.where(live, np.minimum(at + 1, k) / (at + 1.0), 0.0), rtol=1e-6)
